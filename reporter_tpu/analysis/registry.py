@@ -27,12 +27,8 @@ from typing import Dict, Tuple
 # bench.py (or the C++ runtime) reads. Two-sided with the code
 # (registry_drift KN001) and with README's knob table (KN002).
 ENV_KNOBS: Dict[str, str] = {
-    "REPORTER_TPU_PLATFORM": "cpu|accel|auto backend pin",
-    "REPORTER_TPU_PROBE_TIMEOUT_S": "accelerator probe timeout",
-    "REPORTER_TPU_PROBE_TRIES": "accelerator probe attempts",
-    "REPORTER_TPU_PROBE_CACHE": "probe-verdict cache file",
+    "REPORTER_TPU_PLATFORM": "cpu|tpu backend pin (unset = JAX default)",
     "REPORTER_TPU_VIRTUAL_DEVICES": "virtual CPU device count",
-    "REPORTER_TPU_COMPILE_CACHE": "persistent XLA compile cache dir",
     "REPORTER_TPU_DECODE": "decode backend: scan|assoc|pallas",
     "REPORTER_TPU_DECODE_CHUNK": "traces per decode dispatch",
     "REPORTER_TPU_PIPELINE": "device-lane overlap on/off",

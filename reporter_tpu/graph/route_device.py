@@ -26,8 +26,11 @@ Per chunk the kernel:
 
 A relaxation that fails to converge within the sweep cap raises instead
 of returning a partially-relaxed tensor; so does a chunk whose padded
-(sources x nodes) state would exceed the memory budget — both are
-ordinary circuit failures to the caller.
+(sources x nodes) kernel rows would exceed the memory budget — both are
+ordinary circuit failures to the caller. On a metro graph one sweep's
+(sources x edges) working set outgrows its budget long before the kernel
+rows do (a 32-trace chunk on 22,500 nodes has ~900 sources), so the
+sources relax in fixed-size blocks, one compiled shape, concatenated.
 
 On small graphs (``2 * N * N`` float32 elements within the cache
 budget) the kernel keeps a device-resident node-kernel cache: one
@@ -61,10 +64,16 @@ from .route import UNREACHABLE
 #: sweep cap override; 0/unset = auto (profile hint or the node count)
 ENV_HOPS = "REPORTER_TPU_ROUTE_HOPS"
 
-#: ceiling on the padded relaxation state (sources x max(nodes, edges)
-#: float32 elements, two states) — a chunk that would exceed it raises
-#: (-> host fallback) rather than OOM the device. 64M elements = 512 MB.
+#: ceiling on ONE relaxation block's working set (sources x max(nodes,
+#: edges) float32 elements, two states); sources beyond it relax in
+#: further blocks. 64M elements = 256 MB.
 _STATE_BUDGET_ELEMS = 64 * 1024 * 1024
+
+#: ceiling on a chunk's relaxed kernel rows (padded sources x nodes
+#: float32 elements, distance + time) — a chunk that would exceed it
+#: raises (-> host fallback) rather than OOM the device. 256M elements
+#: = 1 GB; 4,096 sources on a 22,500-node graph.
+_KERNEL_BUDGET_ELEMS = 256 * 1024 * 1024
 
 #: ceiling on the dense (nodes x nodes) node-kernel cache (two float32
 #: states); graphs over it (N > ~2.8k nodes) serve uncached, per-chunk.
@@ -257,7 +266,8 @@ class DeviceRouteKernel:
         flags[self._end_np[edge[:, :T - 1, :][ea_live]]] = True
         srcs = np.flatnonzero(flags).astype(np.int32)
         S = _next_pow2(len(srcs))
-        if S * max(self.n_nodes, self.n_edges) * 2 > _STATE_BUDGET_ELEMS:
+        if 2 * S * self.n_nodes > _KERNEL_BUDGET_ELEMS \
+                or self._block_sources() < 1:
             metrics.count("route.device.budget_exceeded")
             raise RuntimeError(
                 f"route relax state over budget: {len(srcs)} sources x "
@@ -291,10 +301,18 @@ class DeviceRouteKernel:
         DeferredRoutes(route, dev_max, B, T).write_back(out)
         return None
 
+    def _block_sources(self) -> int:
+        """Sources per relaxation block: the largest power of two whose
+        working set fits :data:`_STATE_BUDGET_ELEMS` (0 when not even
+        one source does)."""
+        fit = _STATE_BUDGET_ELEMS // (2 * max(self.n_nodes, self.n_edges))
+        return 1 << (fit.bit_length() - 1) if fit else 0
+
     def _relax(self, srcs: np.ndarray, chunk_bound) -> tuple:
-        """Relax the padded source set at ``chunk_bound``; raises on
-        non-convergence (before any cache commit). Returns the (S, N)
-        distance/time kernels, S = len(srcs) padded to a power of two."""
+        """Relax the padded source set at ``chunk_bound``, in blocks of
+        :meth:`_block_sources`; raises on non-convergence (before any
+        cache commit). Returns the (S, N) distance/time kernels, S =
+        len(srcs) padded to a power of two."""
         import jax
         import jax.numpy as jnp
 
@@ -304,28 +322,36 @@ class DeviceRouteKernel:
         pad = np.empty(S, dtype=np.int32)
         pad[:len(srcs)] = srcs
         pad[len(srcs):] = srcs[0]  # duplicate rows are redundant, not wrong
-
-        src_dev = jnp.asarray(pad)
+        block = min(S, self._block_sources())  # both powers of two
         mesh = self._mesh()
-        if mesh is not None and S % mesh.devices.size == 0:
+        sharding = None
+        if mesh is not None and block % mesh.devices.size == 0:
             from jax.sharding import NamedSharding, PartitionSpec
-            src_dev = jax.device_put(
-                src_dev, NamedSharding(mesh, PartitionSpec("data")))
+            sharding = NamedSharding(mesh, PartitionSpec("data"))
             metrics.count("route.device.sharded_chunks")
-
         cap = self._iter_cap()
-        dist, time, iters, converged = route_relax.relax_csr(
-            self._e_start, self._e_end, self._e_len, self._e_secs,
-            src_dev, jnp.float32(chunk_bound),
-            n_nodes=self.n_nodes, max_iters=cap)
-        if not bool(converged):
-            metrics.count("route.device.nonconverged")
-            raise RuntimeError(
-                f"route relax did not converge within {cap} sweeps "
-                f"(bound {float(chunk_bound):.0f} m)")
-        self.max_iters_seen = max(self.max_iters_seen, int(iters))
+        dists, times = [], []
+        for lo in range(0, S, block):
+            src_dev = jnp.asarray(pad[lo:lo + block])
+            if sharding is not None:
+                src_dev = jax.device_put(src_dev, sharding)
+            dist, time, iters, converged = route_relax.relax_csr(
+                self._e_start, self._e_end, self._e_len, self._e_secs,
+                src_dev, jnp.float32(chunk_bound),
+                n_nodes=self.n_nodes, max_iters=cap)
+            if not bool(converged):
+                metrics.count("route.device.nonconverged")
+                raise RuntimeError(
+                    f"route relax did not converge within {cap} sweeps "
+                    f"(bound {float(chunk_bound):.0f} m)")
+            self.max_iters_seen = max(self.max_iters_seen, int(iters))
+            dists.append(dist)
+            times.append(time)
         self.max_bound_seen = max(self.max_bound_seen, float(chunk_bound))
-        return dist, time
+        if len(dists) == 1:
+            return dists[0], times[0]
+        metrics.count("route.device.relax_blocks", len(dists))
+        return jnp.concatenate(dists), jnp.concatenate(times)
 
     def _kernels_cached(self, srcs: np.ndarray, chunk_bound) -> tuple:
         """Serve (dist_sn, time_sn, node_row) from the node-kernel cache,

@@ -293,33 +293,6 @@ def seed_entries(repo: str) -> List[dict]:
             entries.append(_failed_entry(name, label, "bench",
                                          d.get("tail", "")))
 
-    # developer rounds: heterogeneous; handle each recorded shape
-    dev4 = os.path.join(repo, "BENCH_DEV_r04.json")
-    if os.path.exists(dev4):
-        with open(dev4, encoding="utf-8") as f:
-            d = json.load(f)
-        note = (d.get("note") or "")[:300]
-        if d.get("result"):
-            entries.append(entry_from_bench(
-                d["result"], "BENCH_DEV_r04.json", "dev_r04",
-                "bench_dev", context=note))
-        cont = d.get("continuation_session") or {}
-        if cont.get("result"):
-            entries.append(entry_from_bench(
-                cont["result"], "BENCH_DEV_r04.json", "dev_r04_cont",
-                "bench_dev", context=(cont.get("note") or "")[:300]))
-
-    dev4t = os.path.join(repo, "BENCH_DEV_r04_tpu.json")
-    if os.path.exists(dev4t):
-        with open(dev4t, encoding="utf-8") as f:
-            d = json.load(f)
-        note = (d.get("note") or "")[:300]
-        for leg in ("pre_pipeline", "post_pipeline"):
-            if d.get(leg):
-                entries.append(entry_from_bench(
-                    d[leg], "BENCH_DEV_r04_tpu.json", f"dev_r04_{leg}",
-                    "bench_dev", context=note))
-
     # r06 onward share one shape: {"parsed": <bench artifact>,
     # "serialized_breakdown": {"value", "stages"}, "context": {"box"}}
     # — two entries per file: the pipelined headline and the
@@ -329,8 +302,6 @@ def seed_entries(repo: str) -> List[dict]:
                                               "BENCH_DEV_r*.json"))):
         name = os.path.basename(path)
         label_n = name.replace("BENCH_DEV_", "").replace(".json", "")
-        if label_n in ("r04", "r04_tpu"):
-            continue  # the heterogeneous legacy shapes handled above
         with open(path, encoding="utf-8") as f:
             d = json.load(f)
         box_note = (d.get("context") or {}).get("box")

@@ -41,10 +41,7 @@ is the device-facing half:
 
 Cost discipline: chunk accounting is per *chunk* (hundreds of traces),
 not per trace — a handful of scalar ops and one deque append. The
-compile listener registers once, lazily, on the first dispatch; when
-jax.monitoring is absent the telemetry degrades to the first-call
-timing split (an episode is then inferred from nothing — compile
-counts stay 0 — rather than guessed).
+compile listener registers once, lazily, on the first dispatch.
 """
 from __future__ import annotations
 
@@ -137,13 +134,9 @@ def _ensure_listener() -> None:
     with _lock:
         if _listener_registered:
             return
-        try:
-            import jax.monitoring
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_event_duration)
-        except Exception:  # pragma: no cover - jax is baked in
-            logger.warning("jax.monitoring unavailable; compile "
-                           "telemetry degrades to dispatch timing only")
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_event_duration)
         _listener_registered = True
 
 
@@ -164,12 +157,13 @@ class _DispatchSpan:
     """Times one decode dispatch and attributes compile events to its
     shape; updates the shape table and the decode.* metrics on exit."""
 
-    __slots__ = ("B", "T", "K", "_acc", "_t0")
+    __slots__ = ("B", "T", "K", "wire", "_acc", "_t0")
 
-    def __init__(self, B: int, T: int, K: int):
+    def __init__(self, B: int, T: int, K: int, wire: str):
         self.B = B
         self.T = T
         self.K = K
+        self.wire = wire
 
     def __enter__(self):
         _ensure_listener()
@@ -188,18 +182,20 @@ class _DispatchSpan:
             return False
         calls, compile_s = self._acc
         compiled = calls > 0
-        # the backend AND the mesh width are part of the compiled-shape
-        # identity: switching REPORTER_TPU_DECODE (bench's pallas leg,
-        # an operator A/B) — or a (B, T, K) that recompiles because the
-        # decode mesh changed (device slice, DECODE_SHARD flip) — is a
-        # new shape, not a recompile storm
+        # the backend, the mesh width AND the wire dtype are part of the
+        # compiled-shape identity: switching REPORTER_TPU_DECODE (bench's
+        # pallas leg, an operator A/B), a (B, T, K) that recompiles
+        # because the decode mesh changed (device slice, DECODE_SHARD
+        # flip), or a chunk whose distances overflow the f16 wire
+        # (f32 inputs) is a new shape, not a recompile storm
         try:
             from ..ops import decode_backend, shard_width
             backend = decode_backend(self.T, self.K)
             mesh = shard_width(self.B, self.T, backend)
         except Exception:  # pragma: no cover - ops is always importable
             backend, mesh = "?", 1
-        key = (self.B, self.T, self.K, _platform(), backend, mesh)
+        key = (self.B, self.T, self.K, _platform(), backend, mesh,
+               self.wire)
         global _compile_episodes
         with _lock:
             st = _shapes.get(key)
@@ -207,7 +203,7 @@ class _DispatchSpan:
                 st = _shapes[key] = {
                     "B": self.B, "T": self.T, "K": self.K,
                     "platform": key[3], "backend": backend,
-                    "mesh": mesh,
+                    "mesh": mesh, "wire": self.wire,
                     "dispatches": 0, "compiles": 0,
                     "compile_calls": 0, "compile_s": 0.0,
                     "first_s": elapsed, "steady_n": 0,
@@ -244,9 +240,11 @@ class _DispatchSpan:
         return False
 
 
-def dispatch_span(B: int, T: int, K: int) -> _DispatchSpan:
-    """Wrap one decode dispatch (the matcher's dispatch lane)."""
-    return _DispatchSpan(B, T, K)
+def dispatch_span(B: int, T: int, K: int,
+                  wire: str = "float32") -> _DispatchSpan:
+    """Wrap one decode dispatch (the matcher's dispatch lane); ``wire``
+    is the decode inputs' dtype (f16 and f32 compile separately)."""
+    return _DispatchSpan(B, T, K, wire)
 
 
 # ---- wide events -----------------------------------------------------------
@@ -478,26 +476,34 @@ def _shadow_job(dist, valid, route, gc, case, decoded, n_real: int,
     global _shadow_sampled, _shadow_mismatch, _shadow_pending
     try:
         # lazy: cpu_ref sits under matcher/, which imports this module
-        from ..matcher.cpu_ref import viterbi_decode_numpy
+        from ..matcher.cpu_ref import viterbi_decode_numpy_batch
         from ..matcher.hmm import NORMAL, SKIP, UNREACHABLE_THRESHOLD
+        # host copies, here off the hot path: a device-route chunk's
+        # route tensor is still a device array, and indexing it per
+        # trace would run (and compile) device ops per shape
+        dist, valid, route, gc = (np.asarray(a)[:n_real] for a in
+                                  (dist, valid, route, gc))
+        case = np.asarray(case)[:n_real]
         T = dist.shape[1]
         # native batches carry a dead trailing time row (seq sharding);
         # the oracle's contract is (T-1, K, K)
         route = route[:, :max(T - 1, 0)]
         gc = gc[:, :max(T - 1, 0)]
-        case = np.asarray(case)
+        # the whole chunk in one row-vectorised oracle pass (per-row
+        # identical to the per-trace oracle): a per-trace loop could not
+        # keep up with chunks decoded on a chip
+        oracle_paths, _ = viterbi_decode_numpy_batch(
+            dist, valid, route, gc, case, sigma, beta)
         mismatches = 0
         for b in range(n_real):
             n = int(np.count_nonzero(case[b] != SKIP))
             if n == 0:
                 continue
-            oracle_path, _ = viterbi_decode_numpy(
-                dist[b], valid[b], route[b], gc[b], case[b], sigma, beta)
             s_dev = _path_score_f64(dist[b], route[b], gc[b], case[b],
                                     decoded[b], sigma, beta, n, NORMAL,
                                     UNREACHABLE_THRESHOLD)
             s_np = _path_score_f64(dist[b], route[b], gc[b], case[b],
-                                   oracle_path, sigma, beta, n, NORMAL,
+                                   oracle_paths[b], sigma, beta, n, NORMAL,
                                    UNREACHABLE_THRESHOLD)
             # path QUALITY comparison: a differently-broken exact tie
             # is agreement; a worse-scoring device path is the bug the
@@ -576,6 +582,7 @@ def _shape_view(st: dict) -> dict:
         "platform": st["platform"],
         "backend": st["backend"],
         "mesh": st.get("mesh", 1),
+        "wire": st.get("wire"),
         "dispatches": st["dispatches"],
         "compiles": st["compiles"],
         "compile_calls": st["compile_calls"],

@@ -77,14 +77,20 @@ def shard_batch_data(mesh: Mesh, dist_m, valid, route_m, gc_m, case):
     return (put(dist_m), put(valid), put(route_m), put(gc_m), put(case))
 
 
+def data_decode_jit(mesh: Mesh, kernel):
+    """The jitted data-parallel decode :func:`sharded_data_viterbi`
+    runs: (B, T) paths and (B,) scores stay sharded along ``data``."""
+    out_sharding = (NamedSharding(mesh, P("data")),
+                    NamedSharding(mesh, P("data")))
+    return jax.jit(kernel, out_shardings=out_sharding)
+
+
 def sharded_data_viterbi(mesh: Mesh, kernel):
     """A decode callable running ``kernel`` (an unjitted batch decode —
     scan or assoc) data-parallel over a 1-D ``("data",)`` mesh, with
     sharded in/out specs so the (B, T) paths stay device-sharded until
     the drain lane's d2h gather."""
-    out_sharding = (NamedSharding(mesh, P("data")),
-                    NamedSharding(mesh, P("data")))
-    decode = jax.jit(kernel, out_shardings=out_sharding)
+    decode = data_decode_jit(mesh, kernel)
 
     def run(dist_m, valid, route_m, gc_m, case, sigma, beta):
         args = shard_batch_data(mesh, dist_m, valid, route_m, gc_m, case)

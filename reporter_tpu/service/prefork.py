@@ -33,6 +33,14 @@ multi-writer topology. A restarted worker reuses its slot's id: PR 9's
 committed-epoch markers make the re-emit overwrite byte-identically
 instead of colliding.
 
+One process per chip: a worker's libtpu takes every chip of the host
+when it first touches JAX, so on a TPU a second worker could only fail
+or hang — and the supervisor would restart it in a loop. ``procs > 1``
+is therefore CPU serving only: :func:`serve_prefork` refuses to fork
+unless the platform is pinned to the CPU (``REPORTER_TPU_PLATFORM=cpu``
+or ``JAX_PLATFORMS=cpu``), decided from the environment alone — the
+parent never touches JAX. On a TPU host, serve one process per chip.
+
 Supervision: the parent is a dumb waitpid loop — restart a dead worker
 in the same slot, forever, with exponential backoff against crash
 loops. An rc-137 exit (SIGKILL, or the crash failpoint's ``os._exit
@@ -62,6 +70,16 @@ ENV_PROCS = "REPORTER_TPU_SERVICE_PROCS"
 MAX_BACKOFF_S = 5.0
 #: a worker that lived at least this long resets its slot's crash count
 HEALTHY_AGE_S = 10.0
+
+
+def cpu_pinned() -> bool:
+    """True when this process tree is pinned to the CPU backend by its
+    environment (no JAX import: the pre-fork parent must stay off it)."""
+    from ..utils.runtime import ENV_PLATFORM
+    if (os.environ.get(ENV_PLATFORM) or "").strip().lower() == "cpu":
+        return True
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
 
 
 def writer_id_for_slot(slot: int, base: Optional[str] = None) -> str:
@@ -170,6 +188,14 @@ def serve_prefork(make_service: Callable[[], object], host: str,
     ``max_total_restarts`` bounds the restart budget (tests/CI; None =
     supervise forever). Returns a process exit code."""
     procs = max(1, int(procs))
+    if procs > 1 and not cpu_pinned():
+        msg = (f"--procs {procs} refused: pre-fork workers would share one "
+               "accelerator, and a chip belongs to one process. Serve one "
+               "process per chip, or set REPORTER_TPU_PLATFORM=cpu for "
+               "multi-process CPU serving.")
+        logger.error("prefork: %s", msg)
+        sys.stderr.write(msg + "\n")
+        return 2
     shutting_down = {"flag": False}
 
     def _stop(signum, frame):
@@ -303,4 +329,4 @@ def serve_prefork(make_service: Callable[[], object], host: str,
 
 
 __all__ = ["serve_prefork", "worker_main", "writer_id_for_slot",
-           "ENV_PROCS"]
+           "cpu_pinned", "ENV_PROCS"]

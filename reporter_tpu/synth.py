@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -202,16 +202,23 @@ def generate_trace(net: RoadNetwork, uuid: str, rng: np.random.Generator,
                    noise_m: float = 5.0, sample_period_s: float = 1.0,
                    start_time: int = 1_500_000_000,
                    min_route_edges: int = 6,
-                   max_route_edges: int = 60) -> Optional[SyntheticTrace]:
+                   max_route_edges: int = 60,
+                   endpoints: Optional[Tuple[int, int]] = None
+                   ) -> Optional[SyntheticTrace]:
     """Route between random nodes and synthesise noisy per-second probes.
 
     The vehicle advances along the edge path at each edge's speed; a probe is
     emitted every ``sample_period_s`` with isotropic Gaussian position noise
     of ``noise_m`` meters std (the reference's correlated-walk noise model at
     generate_test_trace.py:77-92 is approximated as iid; accuracy is the
-    95th-percentile circle like generate_test_trace.py:40).
+    95th-percentile circle like generate_test_trace.py:40). ``endpoints``
+    fixes the (src, dst) nodes; nearby endpoints keep the route search
+    local on a metro-size graph.
     """
-    src, dst = rng.integers(0, net.num_nodes, size=2)
+    if endpoints is None:
+        src, dst = rng.integers(0, net.num_nodes, size=2)
+    else:
+        src, dst = endpoints
     if src == dst:
         return None
     path = shortest_path_edges(net, int(src), int(dst))

@@ -24,7 +24,17 @@ import numpy as np
 
 
 def score(net, matcher, traces) -> dict:
-    """Match all traces in one device batch and score two agreements:
+    """Match all traces in one device batch and score them
+    (:func:`score_matches`)."""
+    return score_matches(
+        net, matcher.match_many([tr.request_json() for tr in traces]),
+        traces)
+
+
+def score_matches(net, matches, traces) -> dict:
+    """Score match dicts (``{"segments": [...]}`` — a match_many result
+    or a /report body's ``segment_matcher``) against the traces' ground
+    truth, as two agreements:
 
     - ``point_agreement``: per-probe-point segment-id attribution vs truth
       (strict; counts the inherently ambiguous ±1-point boundary cases)
@@ -35,7 +45,6 @@ def score(net, matcher, traces) -> dict:
       BASELINE.md's >=99% north star is about: clients consume
       (segment_id, next_id, duration) rows, not per-point paths.
     """
-    matches = matcher.match_many([tr.request_json() for tr in traces])
     agree = total = 0
     emitted = spurious = 0
     truth_full = truth_found = 0
@@ -142,8 +151,8 @@ def main(argv=None):
     from ..synth import build_grid_city, generate_trace
     from ..utils.runtime import ensure_backend
 
-    # pin the JAX platform before the first decode (probe + CPU fallback;
-    # REPORTER_TPU_PLATFORM=cpu skips the probe entirely)
+    # pin the JAX platform before the first decode
+    # (REPORTER_TPU_PLATFORM=cpu|tpu, unset = JAX's default)
     ensure_backend()
 
     if args.graph:

@@ -9,6 +9,6 @@ export REPORT_LEVELS=${REPORT_LEVELS:-0,1,2}
 export TRANSITION_LEVELS=${TRANSITION_LEVELS:-0,1,2}
 export THRESHOLD_SEC=${THRESHOLD_SEC:-15}
 # test harnesses never contend for the real chip (conftest's rule, for
-# shell entry points): skip the accelerator probe, run on virtual CPU
+# shell entry points): run on the virtual CPU mesh
 export REPORTER_TPU_PLATFORM=${REPORTER_TPU_PLATFORM:-cpu}
 export REPORTER_TPU_VIRTUAL_DEVICES=${REPORTER_TPU_VIRTUAL_DEVICES:-8}

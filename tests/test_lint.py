@@ -324,7 +324,7 @@ def test_readme_knob_table_parser_reads_full_names():
     # the five knobs PR 6 closed the drift on are all table rows now
     for name in ("REPORTER_TPU_CHAOS_REQUIRE_NATIVE",
                  "REPORTER_TPU_NUM_PROCESSES",
-                 "REPORTER_TPU_PROBE_TRIES",
+                 "REPORTER_TPU_PLATFORM",
                  "REPORTER_TPU_PROCESS_ID",
                  "REPORTER_TPU_ROUTE_CACHE_PAIRS"):
         assert name in table, f"{name} missing from README's knob table"
@@ -782,7 +782,7 @@ def test_contracts_only_guard_is_clean_and_catches_drift(tmp_path):
     assert _lint("--contracts-only").returncode == 0
     readme_path = os.path.join(REPO, "README.md")
     readme = _read(readme_path)
-    target = "| `REPORTER_TPU_PROBE_TRIES` |"
+    target = "| `REPORTER_TPU_VIRTUAL_DEVICES` |"
     assert target in readme, "README knob table drifted; update the test"
     # simulate the drift in-process (the driver reads the real README,
     # so exercise the pass directly on a mutated copy)
@@ -794,7 +794,7 @@ def test_contracts_only_guard_is_clean_and_catches_drift(tmp_path):
                         if not ln.startswith(target))
     findings = registry_drift.run(files, REPO, readme_text=mutated)
     assert any(f.rule == "KN002"
-               and "REPORTER_TPU_PROBE_TRIES" in f.message
+               and "REPORTER_TPU_VIRTUAL_DEVICES" in f.message
                for f in findings)
 
 

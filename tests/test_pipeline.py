@@ -110,6 +110,36 @@ class TestPipelineEndToEnd:
         assert "7,8," not in body
 
 
+    def test_report_workers_never_touch_a_backend(self, tmp_path):
+        """One process per chip: stage 3's spawned workers run after
+        the parent matched on the chip, so they must never initialise a
+        JAX backend. A bogus platform makes any backend init raise in
+        a worker, and its tile would then be missing."""
+        import subprocess
+        import sys
+        match_dir = tmp_path / "matches" / "0_3599" / "0"
+        match_dir.mkdir(parents=True)
+        for tile in ("42", "43"):
+            with open(match_dir / tile, "w") as f:
+                f.write("5,6,10,1,600,0,0,10,src,AUTO\n")
+        dest = tmp_path / "out"
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = ("from reporter_tpu.pipeline.simple_reporter import "
+                "report_tiles\n"
+                f"report_tiles({str(tmp_path / 'matches')!r}, "
+                f"{str(dest)!r}, privacy=1, concurrency=2)\n"
+                "from jax._src import xla_bridge\n"
+                "assert not xla_bridge._backends\n")
+        env = dict(os.environ, PYTHONPATH=repo,
+                   JAX_PLATFORMS="no_such_platform")
+        env.pop("REPORTER_TPU_PLATFORM", None)
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-800:]
+        out_files = [f for _r, _d, fs in os.walk(dest) for f in fs]
+        assert len(out_files) == 2, (out_files, proc.stderr[-800:])
+
+
 class TestLongWindowChunking:
     """Windows beyond the largest padding bucket are chunked with a
     holdback overlap rather than truncated."""

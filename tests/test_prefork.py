@@ -28,6 +28,33 @@ def test_writer_id_per_slot():
     assert len(ids) == 8
 
 
+@pytest.mark.parametrize("env,pinned", [
+    ({"REPORTER_TPU_PLATFORM": "cpu"}, True),
+    ({"JAX_PLATFORMS": "cpu"}, True),
+    ({"REPORTER_TPU_PLATFORM": "tpu"}, False),
+    ({}, False),
+])
+def test_cpu_pinned_reads_env_only(monkeypatch, env, pinned):
+    monkeypatch.delenv("REPORTER_TPU_PLATFORM", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    assert prefork.cpu_pinned() is pinned
+
+
+def test_multi_proc_refused_off_cpu(monkeypatch, capsys):
+    """One process per chip: procs > 1 without a CPU pin refuses before
+    forking anything (no worker dies and restarts in a loop)."""
+    monkeypatch.setenv("REPORTER_TPU_PLATFORM", "tpu")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(prefork.os, "fork",
+                        lambda: pytest.fail("forked despite refusal"))
+    built = []
+    rc = prefork.serve_prefork(lambda: built.append(1), "127.0.0.1", 0, 2)
+    assert rc == 2 and not built
+    assert "one process per chip" in capsys.readouterr().err
+
+
 def test_exit_code_decoding():
     """rc-137 awareness: a SIGKILLed worker and an ``os._exit(137)``
     crash failpoint decode to the same shell-style code."""

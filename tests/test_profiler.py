@@ -278,6 +278,34 @@ def _toy_batch(seed=3):
     return batch, path
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_batched_oracle_equals_per_trace_oracle(seed):
+    """The shadow sampler's row-vectorised oracle is the per-trace
+    oracle, row for row: same paths, same scores, with RESTART, SKIP,
+    invalid candidates and unreachable routes mixed in."""
+    from reporter_tpu.matcher.cpu_ref import (viterbi_decode_numpy,
+                                              viterbi_decode_numpy_batch)
+    from reporter_tpu.matcher.hmm import NORMAL, RESTART, SKIP
+    B, T, K = 9, 24, 4
+    rng = np.random.default_rng(seed)
+    dist = rng.uniform(0, 30, (B, T, K)).astype(np.float16)
+    valid = rng.random((B, T, K)) > 0.1
+    gc = rng.uniform(5, 40, (B, T - 1)).astype(np.float16)
+    route = rng.uniform(5, 80, (B, T - 1, K, K)).astype(np.float16)
+    route[rng.random(route.shape) < 0.1] = np.float16(np.inf)
+    case = rng.choice([NORMAL, NORMAL, NORMAL, RESTART, SKIP],
+                      size=(B, T)).astype(np.int32)
+    case[:, 0] = RESTART
+    case[-1] = SKIP  # an all-filler row
+    paths, scores = viterbi_decode_numpy_batch(dist, valid, route, gc,
+                                               case, 4.07, 3.0)
+    for b in range(B):
+        p1, s1 = viterbi_decode_numpy(dist[b], valid[b], route[b], gc[b],
+                                      case[b], 4.07, 3.0)
+        assert np.array_equal(paths[b], p1), b
+        assert scores[b] == s1 or (np.isnan(scores[b]) and np.isnan(s1))
+
+
 class TestShadowSampling:
     def test_agreeing_decode_has_no_mismatch(self, monkeypatch):
         monkeypatch.setenv(profiler.ENV_SHADOW, "1.0")

@@ -539,6 +539,28 @@ class TestRelaxKernel:
             cands, gc, min_bound_m=2000.0)
         assert np.array_equal(wide, cold)
 
+    def test_blocked_relax_is_exact(self, city, monkeypatch):
+        """Sources over one block's working-set budget relax in blocks
+        (the metro-graph path: ~900 sources per 32-trace chunk on 22,500
+        nodes) — routes byte-identical to one whole relaxation."""
+        from reporter_tpu.graph import route_device
+        from reporter_tpu.utils import metrics
+
+        cands, gc = _crafted(city)
+        whole = route_device.DeviceRouteKernel(city)
+        whole._cache_ok = False
+        want = whole.route_matrices(cands, gc)
+        blocked = route_device.DeviceRouteKernel(city)
+        blocked._cache_ok = False
+        # room for exactly two sources per block
+        monkeypatch.setattr(route_device, "_STATE_BUDGET_ELEMS",
+                            4 * max(city.num_nodes, city.num_edges))
+        assert blocked._block_sources() == 2
+        metrics.default.reset()
+        got = blocked.route_matrices(cands, gc)
+        assert metrics.default.counter("route.device.relax_blocks") > 1
+        assert np.array_equal(got, want)
+
     def test_budget_guard_raises(self, city, kernel, monkeypatch):
         from reporter_tpu.graph import route_device
         monkeypatch.setattr(route_device, "_STATE_BUDGET_ELEMS", 8)

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """CI smoke gate for the bench artifact's stage accounting.
 
-Runs a deliberately tiny CPU-pinned bench (seconds, not minutes — no
-accelerator probes, one repeat) and asserts the JSON contract future
+Runs a deliberately tiny CPU-pinned bench (seconds, not minutes, one
+repeat) and asserts the JSON contract future
 tooling depends on: the artifact parses, carries the ``stages``
 breakdown with the ``prep`` stage and its ``prep_share`` of batch wall
 time, and records whether the chunked overlap path ran (``pipelined``).
@@ -22,7 +22,7 @@ import subprocess
 import sys
 
 REQUIRED_TOP = ("metric", "value", "unit", "vs_baseline", "stages",
-                "report_writers", "baseline", "probe", "query", "routes")
+                "report_writers", "baseline", "device", "query", "routes")
 REQUIRED_STAGES = ("prep", "decode_dispatch", "decode_wait", "assemble",
                    "report", "total", "prep_share", "report_share",
                    "pipelined")

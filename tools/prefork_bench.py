@@ -171,6 +171,8 @@ def run_mode(procs: int, clients: int, duration: float) -> dict:
                    XLA_FLAGS=(env.get("XLA_FLAGS", "") +
                               " --xla_cpu_multi_thread_eigen=false"
                               ).strip())
+    # pre-fork serving is CPU-only (service/prefork.py): pin it
+    env.setdefault("REPORTER_TPU_PLATFORM", "cpu")
     proc = subprocess.run([sys.executable, "-c", script], cwd=REPO,
                           capture_output=True, text=True, timeout=900,
                           env=env)
