@@ -29,6 +29,8 @@ through ``match_many`` on the 4-chip data mesh, then on one chip
 (``REPORTER_TPU_DEVICE_SLICE=0:1``), report bodies compared byte for
 byte. ``--rehearse`` runs on whatever JAX finds (the CPU here, with
 ``--rows``/``--traces`` to shrink it) and never prints the ok line.
+Counters are checked over the warm pass too; the measured pass of each
+serving leg must compile nothing.
 """
 from __future__ import annotations
 
@@ -46,6 +48,8 @@ import urllib.request
 REPO = os.path.dirname(os.path.abspath(__file__))
 FMT = ",sv,\\|,0,1,2,3,4"
 MIN_AGREEMENT = 0.99  # the accuracy gate (tools/accuracy_cli.py, ci.yml)
+CLIENTS = 32           # /report client threads: the dispatcher micro-batches
+STREAM_VEHICLES = 512  # vehicles the streaming leg replays
 
 #: counters that name a fallback, a breaker failure, a quarantine or a
 #: recompile: any non-zero value fails the smoke
@@ -95,24 +99,31 @@ def check(cond: bool, msg: str) -> None:
 
 class CompileClock:
     """Every backend compile in the process (decode, route kernel,
-    incremental advance), from ``jax.monitoring``."""
+    incremental advance), from ``jax.monitoring``: the event fires for
+    a persistent-cache hit too, so a new executable counts either way."""
 
     def __init__(self):
         import jax.monitoring
         self._lock = threading.Lock()
-        self.count = 0
+        self.names = []
         self.seconds = 0.0
         jax.monitoring.register_event_duration_secs_listener(self._on)
 
-    def _on(self, name, dur, **_kw):
+    def _on(self, name, dur, fun_name="?", **_kw):
         if name.endswith("backend_compile_duration"):
             with self._lock:
-                self.count += 1
+                self.names.append(fun_name)
                 self.seconds += dur
 
     def read(self):
         with self._lock:
-            return self.count, self.seconds
+            return len(self.names), self.seconds
+
+    def since(self, n: int) -> dict:
+        """Compiled function name -> count, for compiles after the n-th."""
+        with self._lock:
+            names = self.names[n:]
+        return {f: names.count(f) for f in sorted(set(names))}
 
 
 def counters() -> dict:
@@ -130,7 +141,7 @@ def check_zero(leg: str, d: dict) -> None:
     check(not bad, f"{leg}: fallback counters non-zero: {bad}")
 
 
-def build_traffic(net, rows: int, cols: int, n: int, seed: int) -> list:
+def build_traffic(net, rows: int, n: int, seed: int) -> list:
     """``n`` vehicles between nearby intersections (4-20 blocks apart,
     so each route search stays local on the metro graph), kept when the
     trace has 64-256 points."""
@@ -140,16 +151,16 @@ def build_traffic(net, rows: int, cols: int, n: int, seed: int) -> list:
     rng = np.random.default_rng(seed)
     traces = []
     while len(traces) < n:
-        r, c = (int(x) for x in rng.integers(0, (rows, cols)))
+        r, c = (int(x) for x in rng.integers(0, (rows, rows)))
         d = int(rng.integers(4, 21))
         dr = int(rng.integers(-d, d + 1))
         dc = (d - abs(dr)) * int(rng.choice((-1, 1)))
-        if not (0 <= r + dr < rows and 0 <= c + dc < cols):
+        if not (0 <= r + dr < rows and 0 <= c + dc < rows):
             continue
         tr = generate_trace(net, f"veh-{len(traces)}", rng, noise_m=4.0,
                             min_route_edges=1, max_route_edges=10 ** 6,
-                            endpoints=(r * cols + c,
-                                       (r + dr) * cols + c + dc))
+                            endpoints=(r * rows + c,
+                                       (r + dr) * rows + c + dc))
         if tr is not None and 64 <= len(tr.points) <= 256:
             traces.append(tr)
     return traces
@@ -182,10 +193,59 @@ def post_all(port: int, bodies: list, clients: int) -> list:
     return out
 
 
-def serve_leg(leg: str, conf: dict, bodies: list, clients: int,
+def shape_pools(traces: list) -> dict:
+    """Trace indices by the decode bucket each can land in: its ladder
+    bucket, or the power-of-two sub-bucket the matcher splits a
+    wasteful group into (``SegmentMatcher._split_bucket``)."""
+    from reporter_tpu.matcher.batchpad import bucket_ladder
+    ladder = bucket_ladder()[0]
+    pools = {}
+    for i, tr in enumerate(traces):
+        n = len(tr.points)
+        top = next((b for b in ladder if b >= n), ladder[-1])
+        T = min(max(1 << (n - 1).bit_length(), ladder[0]), top)
+        pools.setdefault(T, []).append(i)
+    return pools
+
+
+def warm_shapes(service, reqs: list, pools: dict) -> int:
+    """Every (rows, T) shape the measured pass can meet: per bucket, one
+    ``report_many`` batch (the service's in-process entry point, one
+    dispatcher batch) of each power-of-two size up to the dispatcher's
+    cap. The ladder is pinned to the pools' buckets with splitting off
+    meanwhile, so a batch decodes at its pool's bucket whatever its size
+    (the default ladder only reaches a sub-bucket by splitting a mixed
+    group, never a lone trace). Returns the batches sent."""
+    from reporter_tpu.matcher.batchpad import ENV_BUCKETS
+    sent = 0
+    ladder = os.environ.get(ENV_BUCKETS)
+    os.environ[ENV_BUCKETS] = ",".join(map(str, sorted(pools))) + "@off"
+    try:
+        for _T, idx in sorted(pools.items()):
+            r = 1
+            while r <= service.dispatcher.max_batch:
+                got = service.report_many([reqs[i] for i in idx[:r]])
+                check(all(g is not None for g in got),
+                      f"warm batch of {r}: {sum(g is None for g in got)} "
+                      "reports failed")
+                sent += 1
+                if r >= len(idx):
+                    break
+                r *= 2
+    finally:
+        if ladder is None:
+            os.environ.pop(ENV_BUCKETS)
+        else:
+            os.environ[ENV_BUCKETS] = ladder
+    return sent
+
+
+def serve_leg(leg: str, conf: dict, bodies: list, reqs: list, pools: dict,
               clock: CompileClock) -> list:
-    """One service instance: warm pass, then the measured pass with
-    every decoded chunk shadow-re-scored. Returns the parsed bodies."""
+    """One service instance: warm pass (every batch shape, then every
+    body over HTTP), then the measured pass with every decoded chunk
+    shadow-re-scored. The fallback counters cover both passes; the
+    measured pass must compile nothing. Returns the parsed bodies."""
     from reporter_tpu.obs import profiler
     from reporter_tpu.service.server import make_server, make_service
 
@@ -195,28 +255,33 @@ def serve_leg(leg: str, conf: dict, bodies: list, clients: int,
     port = httpd.server_address[1]
     max_pending = profiler._SHADOW_MAX_PENDING
     try:
+        start = counters()
         c0, s0 = clock.read()
         t0 = time.perf_counter()
-        warm = post_all(port, bodies, clients)
+        warm_batches = warm_shapes(service, reqs, pools)
+        warm = post_all(port, bodies, CLIENTS)
         warm_s = time.perf_counter() - t0
         bad = [r for r in warm if r[0] != 200]
         check(not bad, f"{leg} warm pass: {len(bad)} non-200 responses "
                        f"(first: {bad[:1]})")
+        check_zero(f"{leg} warm pass", delta(start, counters()))
         c1, s1 = clock.read()
 
         os.environ[profiler.ENV_SHADOW] = "1.0"
         # every chunk re-scored: queue the oracle's backlog instead of
         # shedding it (its one thread gets little of the GIL while 32
-        # clients and the server run in this process; it catches up in
-        # drain_shadow below)
+        # clients and the server run in this process); it catches up in
+        # drain_shadow, timed apart from serving
         profiler._SHADOW_MAX_PENDING = len(bodies)
         before = counters()
         t0 = time.perf_counter()
-        got = post_all(port, bodies, clients)
+        got = post_all(port, bodies, CLIENTS)
         wall = time.perf_counter() - t0
+        t1 = time.perf_counter()
         drained = profiler.drain_shadow(timeout_s=600.0)
+        drain_s = time.perf_counter() - t1
         c2, s2 = clock.read()
-        d = delta(before, counters())
+        after = counters()
     finally:
         os.environ.pop(profiler.ENV_SHADOW, None)
         profiler._SHADOW_MAX_PENDING = max_pending
@@ -224,25 +289,31 @@ def serve_leg(leg: str, conf: dict, bodies: list, clients: int,
         httpd.server_close()
         service.dispatcher.close()
 
+    d = delta(before, after)
     non200 = [r for r in got if r[0] != 200]
     rescored = d.get("decode.shadow.chunks", 0)
     dropped = d.get("decode.shadow.dropped", 0)
-    say(leg, traces=len(bodies), clients=clients,
-        warm_s=warm_s, warm_compiles=c1 - c0, warm_compile_s=s1 - s0,
+    steady_compiles = clock.since(c1)
+    say(leg, traces=len(bodies), clients=CLIENTS,
+        warm_batches=warm_batches, warm_s=warm_s, warm_compiles=c1 - c0,
+        warm_compile_s=s1 - s0,
         wall_s=wall, traces_per_s=len(bodies) / wall,
+        shadow_drain_s=drain_s,
         compiles=c2 - c1, compile_s=s2 - s1,
+        steady_compiled=steady_compiles,
         decode_compile_episodes=d.get("decode.compile.count", 0),
         decode_chunks=d.get("profile.chunks", 0),
         shadow_rescored_chunks=rescored, shadow_dropped_chunks=dropped,
         shadow_traces=d.get("decode.shadow.sampled", 0),
         route_device_chunks=d.get("route.device.chunks", 0),
         route_budget_exceeded=d.get("route.device.budget_exceeded", 0),
-        route_cache_hit_rows=d.get("route.device.cache_hit_rows", 0),
-        route_cache_miss_rows=d.get("route.device.cache_miss_rows", 0),
+        route_relax_blocks=d.get("route.device.relax_blocks", 0),
         non200=len(non200))
     check(not non200, f"{leg}: {len(non200)} non-200 responses (first: "
                       f"{non200[:1]})")
-    check_zero(leg, d)
+    check_zero(leg, delta(start, after))
+    check(c2 == c1, f"{leg}: {c2 - c1} compiles in the steady pass over "
+                    f"the warm pass's traffic: {steady_compiles}")
     check(drained, f"{leg}: shadow oracle did not drain")
     check(rescored > 0 and rescored >= dropped,
           f"{leg}: shadow re-scored {rescored} chunks, dropped {dropped} "
@@ -296,15 +367,18 @@ def segment_ids(body: dict) -> list:
     return [s.get("segment_id") for s in body["segment_matcher"]["segments"]]
 
 
-def one_chip(args, net, graph: str, traces: list, clock, tmp: str) -> None:
+def one_chip(net, graph: str, traces: list, clock, tmp: str) -> None:
     from reporter_tpu.matcher import Configure
     from reporter_tpu.tools.accuracy_cli import score_matches
 
     conf = {"graph": graph}
     Configure(conf)
-    bodies = [json.dumps(tr.request_json()).encode() for tr in traces]
+    reqs = [tr.request_json() for tr in traces]
+    bodies = [json.dumps(r).encode() for r in reqs]
+    pools = shape_pools(traces)
+    say("buckets", **{str(T): len(idx) for T, idx in sorted(pools.items())})
 
-    host = serve_leg("serve", conf, bodies, args.clients, clock)
+    host = serve_leg("serve", conf, bodies, reqs, pools, clock)
     acc = score_matches(net, [b["segment_matcher"] for b in host], traces)
     say("accuracy", **acc)
     check(acc["agreement"] >= MIN_AGREEMENT,
@@ -312,7 +386,7 @@ def one_chip(args, net, graph: str, traces: list, clock, tmp: str) -> None:
 
     os.environ["REPORTER_TPU_ROUTE_DEVICE"] = "1"
     try:
-        dev = serve_leg("route", conf, bodies, args.clients, clock)
+        dev = serve_leg("route", conf, bodies, reqs, pools, clock)
     finally:
         os.environ.pop("REPORTER_TPU_ROUTE_DEVICE")
     differ = sum(segment_ids(a) != segment_ids(b)
@@ -323,7 +397,7 @@ def one_chip(args, net, graph: str, traces: list, clock, tmp: str) -> None:
     check(counters().get("route.device.chunks", 0) > 0,
           "route: the device route kernel served no chunk")
 
-    stream_leg(graph, traces[:args.stream_vehicles], tmp)
+    stream_leg(graph, traces[:STREAM_VEHICLES], tmp)
 
     from reporter_tpu.obs import profiler
     check(profiler.drain_shadow(timeout_s=600.0), "shadow did not drain")
@@ -398,6 +472,7 @@ def four_chips(net, traces: list, clock) -> None:
         c0, s0 = clock.read()
         with mock.patch.object(ops, "decode_batch", side_effect=spy):
             m.match_many(reqs)  # warm: compiles every shape
+            cw, _sw = clock.read()
             t0 = time.perf_counter()
             matches = m.match_many(reqs)
             wall = time.perf_counter() - t0
@@ -414,6 +489,8 @@ def four_chips(net, traces: list, clock) -> None:
             devices_spanned=sorted(set(spans)),
             shard_chunks=d.get("decode.shard.chunks", 0))
         check_zero(label, d)
+        check(c1 == cw, f"{label}: {c1 - cw} compiles in the steady pass: "
+                        f"{clock.since(cw)}")
         return m, bodies, spans, d
 
     _m4, b4, spans4, d4 = run("sharded-4")
@@ -445,22 +522,11 @@ def main(argv=None) -> int:
     p.add_argument("--rehearse", action="store_true",
                    help="run on the platform JAX finds (CPU rehearsal); "
                    "never prints the ok line, exits 3 on success")
-    p.add_argument("--rows", type=int, default=150)
-    p.add_argument("--cols", type=int, default=150)
+    p.add_argument("--rows", type=int, default=150,
+                   help="grid city rows = columns")
     p.add_argument("--traces", type=int, default=2048)
-    p.add_argument("--stream-vehicles", type=int, default=512)
-    p.add_argument("--clients", type=int, default=32)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
-
-    # the native host runtime, rebuilt from the committed source on THIS
-    # machine: a library copied from elsewhere is never loaded by mtime,
-    # and the Makefile picks -mf16c from this host's CPU flags
-    t0 = time.perf_counter()
-    subprocess.run(["make", "-B", "-C",
-                    os.path.join(REPO, "reporter_tpu", "native")],
-                   check=True, stdout=subprocess.DEVNULL)
-    native_s = time.perf_counter() - t0
     sys.path.insert(0, REPO)
 
     from reporter_tpu.utils import runtime
@@ -479,15 +545,22 @@ def main(argv=None) -> int:
         check(dev.platform == "tpu", f"platform {dev.platform}, not tpu")
     clock = CompileClock()
 
+    # the native host runtime, rebuilt from the committed source on THIS
+    # machine: a library copied from elsewhere is never loaded by mtime,
+    # and the Makefile picks -mf16c from this host's CPU flags
+    t0 = time.perf_counter()
+    subprocess.run(["make", "-B", "-C",
+                    os.path.join(REPO, "reporter_tpu", "native")],
+                   check=True, stdout=subprocess.DEVNULL)
+    native_s = time.perf_counter() - t0
     from reporter_tpu import native
     check(native.available(), "native host runtime did not load")
 
     from reporter_tpu.synth import build_grid_city
     t0 = time.perf_counter()
-    net = build_grid_city(rows=args.rows, cols=args.cols, spacing_m=200.0,
+    net = build_grid_city(rows=args.rows, cols=args.rows, spacing_m=200.0,
                           seed=args.seed)
-    traces = build_traffic(net, args.rows, args.cols, args.traces,
-                           args.seed + 1)
+    traces = build_traffic(net, args.rows, args.traces, args.seed + 1)
     lengths = sorted(len(tr.points) for tr in traces)
     say("setup", native_build_s=native_s, nodes=int(net.num_nodes),
         edges=int(len(net.edge_start)), traces=len(traces),
@@ -502,7 +575,7 @@ def main(argv=None) -> int:
         else:
             graph = os.path.join(tmp, "city.npz")
             net.save(graph)
-            one_chip(args, net, graph, traces, clock, tmp)
+            one_chip(net, graph, traces, clock, tmp)
 
     n, secs = clock.read()
     say("compile", total_compiles=n, total_compile_s=secs)

@@ -127,7 +127,8 @@ def _packed_cases(d):
     for T in _BUCKETS:
         btk, bt1 = B * T * K, B * (T - 1)
         out.append(([((btk + B + N,), _I32), ((btk + 2 * bt1 + 2,), _F32),
-                     ((S, N), _F32), ((S, N), _F32),
+                     ((S, N), _F32), ((S, N), _F32), ((), _I32),
+                     ((B, T - 1, K, K), _F32),
                      ((E,), _I32), ((E,), _I32), ((E,), _F32),
                      ((E,), _F32), ((E,), _F32), ((E,), _F32)],
                     {"B": B, "T": T, "K": K, "N": N}))

@@ -13,24 +13,28 @@ change report bytes.
 Per chunk the kernel:
 
 1. collects the live candidate edges' end nodes (the relaxation
-   sources), deduplicated and padded to a power of two (bounding the
-   compiled-shape count the way batchpad's row padding does);
+   sources), deduplicated and padded to whole blocks;
 2. relaxes them all at the chunk-global bound — the max over every live
    step's ``max(min_bound, factor * gc)`` — which is exactness-safe: a
    bounded search at a larger bound settles a superset of the same exact
    distances, and the assembly re-applies each step's own bound;
-3. assembles the route tensor and writes it into the prep dict's
-   ``route_m`` rows ``[:B, :T-1]`` (row T-1 is the dead trailing step the
-   native tail fill already covered), folding the device finite max into
+3. assembles the route tensor over every prepared row, filler rows
+   included (so its compiled shape follows the decode bucket, not the
+   chunk's trace count), and writes it into the prep dict's ``route_m``
+   time rows ``[:T-1]`` (row T-1 is the dead trailing step the native
+   tail fill already covered), folding the device finite max into
    ``max_finite`` so the f16 wire decision sees device-written values.
 
 A relaxation that fails to converge within the sweep cap raises instead
-of returning a partially-relaxed tensor; so does a chunk whose padded
-(sources x nodes) kernel rows would exceed the memory budget — both are
-ordinary circuit failures to the caller. On a metro graph one sweep's
-(sources x edges) working set outgrows its budget long before the kernel
-rows do (a 32-trace chunk on 22,500 nodes has ~900 sources), so the
-sources relax in fixed-size blocks, one compiled shape, concatenated.
+of returning a partially-relaxed tensor; so does a graph on which not
+one source's sweep fits the working-set budget — both are ordinary
+circuit failures to the caller. On a metro graph one sweep's (sources x
+edges) working set outgrows its budget at a few hundred sources (a
+32-trace chunk on 22,500 nodes has ~900), so without the cache the
+sources relax in fixed-size blocks — one compiled relaxation and one
+compiled assembly per bucket, whatever the chunk's source count — each
+block assembled on its own and the results merged by elementwise
+minimum.
 
 On small graphs (``2 * N * N`` float32 elements within the cache
 budget) the kernel keeps a device-resident node-kernel cache: one
@@ -69,11 +73,10 @@ ENV_HOPS = "REPORTER_TPU_ROUTE_HOPS"
 #: further blocks. 64M elements = 256 MB.
 _STATE_BUDGET_ELEMS = 64 * 1024 * 1024
 
-#: ceiling on a chunk's relaxed kernel rows (padded sources x nodes
-#: float32 elements, distance + time) — a chunk that would exceed it
-#: raises (-> host fallback) rather than OOM the device. 256M elements
-#: = 1 GB; 4,096 sources on a 22,500-node graph.
-_KERNEL_BUDGET_ELEMS = 256 * 1024 * 1024
+#: most sources per relaxation block: a chunk's source count varies, the
+#: block does not (one compiled relaxation per graph), so a small chunk
+#: pads at most this many rows
+_BLOCK_SOURCES_MAX = 256
 
 #: ceiling on the dense (nodes x nodes) node-kernel cache (two float32
 #: states); graphs over it (N > ~2.8k nodes) serve uncached, per-chunk.
@@ -211,14 +214,16 @@ class DeviceRouteKernel:
         return max(self.n_nodes, 2)
 
     # -- the chunk hot path ------------------------------------------------
-    def fill_prep(self, out: dict, params, B: int,
+    def fill_prep(self, out: dict, params,
                   min_bound_m: float = 500.0,
                   defer: bool = False) -> "Optional[DeferredRoutes]":
-        """Compute and write ``out['route_m'][:B, :T-1]`` for a native
+        """Compute and write ``out['route_m'][:, :T-1]`` for a native
         ``prepare_batch(..., skip_routes=True)`` result dict, updating
-        ``out['max_finite']``. Raises on non-convergence or a
-        budget-exceeding chunk (the caller's circuit fallback re-runs
-        the native prep with routes).
+        ``out['max_finite']``. Every row is assembled, filler rows too
+        (all dead: the same UNREACHABLE bytes the native tail fill
+        writes), so the compiled shape is the decode bucket's. Raises on
+        non-convergence or a budget-exceeding chunk (the caller's
+        circuit fallback re-runs the native prep with routes).
 
         ``defer=True`` skips the device→host sync: the assembly is
         dispatched and a :class:`DeferredRoutes` handle returned (None
@@ -229,13 +234,13 @@ class DeviceRouteKernel:
         background worker — no relax runs, so nothing checkable is
         deferred past this frame."""
         faults.failpoint("route.device")
-        edge = np.asarray(out["edge_ids"][:B])
-        T = edge.shape[1]
+        edge = np.asarray(out["edge_ids"])
+        B, T = edge.shape[:2]
         if T < 2:
             return
-        nk = np.asarray(out["num_kept"][:B])
-        gc = np.asarray(out["gc_m"][:B, :T - 1])
-        dt = np.asarray(out["dt"][:B, :T - 1])
+        nk = np.asarray(out["num_kept"])
+        gc = np.asarray(out["gc_m"][:, :T - 1])
+        dt = np.asarray(out["dt"][:, :T - 1])
 
         # per-step bounds/caps in the C++ double->float32 expression
         bounds = np.maximum(
@@ -265,16 +270,14 @@ class DeviceRouteKernel:
         flags = np.zeros(self.n_nodes, dtype=bool)
         flags[self._end_np[edge[:, :T - 1, :][ea_live]]] = True
         srcs = np.flatnonzero(flags).astype(np.int32)
-        S = _next_pow2(len(srcs))
-        if 2 * S * self.n_nodes > _KERNEL_BUDGET_ELEMS \
-                or self._block_sources() < 1:
+        if self._block_sources() < 1:
             metrics.count("route.device.budget_exceeded")
             raise RuntimeError(
                 f"route relax state over budget: {len(srcs)} sources x "
                 f"{self.n_nodes} nodes")
         btol = float(params.backward_tolerance_m)
         tpen = float(params.turn_penalty_factor)
-        offset = np.asarray(out["offset_m"][:B])
+        offset = np.asarray(out["offset_m"])
         metrics.count("route.device.chunks")
         metrics.count("route.device.pairs",
                       int(ea_live.sum()) * edge.shape[2])
@@ -303,35 +306,36 @@ class DeviceRouteKernel:
 
     def _block_sources(self) -> int:
         """Sources per relaxation block: the largest power of two whose
-        working set fits :data:`_STATE_BUDGET_ELEMS` (0 when not even
-        one source does)."""
+        working set fits :data:`_STATE_BUDGET_ELEMS`, at most
+        :data:`_BLOCK_SOURCES_MAX` (0 when not even one source fits)."""
         fit = _STATE_BUDGET_ELEMS // (2 * max(self.n_nodes, self.n_edges))
-        return 1 << (fit.bit_length() - 1) if fit else 0
+        return min(1 << (fit.bit_length() - 1), _BLOCK_SOURCES_MAX) \
+            if fit else 0
 
-    def _relax(self, srcs: np.ndarray, chunk_bound) -> tuple:
-        """Relax the padded source set at ``chunk_bound``, in blocks of
-        :meth:`_block_sources`; raises on non-convergence (before any
-        cache commit). Returns the (S, N) distance/time kernels, S =
-        len(srcs) padded to a power of two."""
+    def _relax_blocks(self, srcs: np.ndarray, chunk_bound, block: int):
+        """Relax ``srcs`` at ``chunk_bound`` in blocks of ``block``
+        sources (the last padded with a duplicate source: redundant, not
+        wrong). Yields ``(lo, dist, time)`` per block — the (block, N)
+        kernels of padded source rows ``[lo, lo + block)`` — and raises
+        on a block that does not converge, before yielding it."""
         import jax
         import jax.numpy as jnp
 
         from ..ops import route_relax
 
-        S = _next_pow2(len(srcs))
-        pad = np.empty(S, dtype=np.int32)
+        n_blocks = -(-len(srcs) // block)
+        pad = np.full(n_blocks * block, srcs[0], dtype=np.int32)
         pad[:len(srcs)] = srcs
-        pad[len(srcs):] = srcs[0]  # duplicate rows are redundant, not wrong
-        block = min(S, self._block_sources())  # both powers of two
         mesh = self._mesh()
         sharding = None
         if mesh is not None and block % mesh.devices.size == 0:
             from jax.sharding import NamedSharding, PartitionSpec
             sharding = NamedSharding(mesh, PartitionSpec("data"))
             metrics.count("route.device.sharded_chunks")
+        if n_blocks > 1:
+            metrics.count("route.device.relax_blocks", n_blocks)
         cap = self._iter_cap()
-        dists, times = [], []
-        for lo in range(0, S, block):
+        for lo in range(0, len(pad), block):
             src_dev = jnp.asarray(pad[lo:lo + block])
             if sharding is not None:
                 src_dev = jax.device_put(src_dev, sharding)
@@ -345,34 +349,33 @@ class DeviceRouteKernel:
                     f"route relax did not converge within {cap} sweeps "
                     f"(bound {float(chunk_bound):.0f} m)")
             self.max_iters_seen = max(self.max_iters_seen, int(iters))
-            dists.append(dist)
-            times.append(time)
+            yield lo, dist, time
         self.max_bound_seen = max(self.max_bound_seen, float(chunk_bound))
-        if len(dists) == 1:
-            return dists[0], times[0]
-        metrics.count("route.device.relax_blocks", len(dists))
-        return jnp.concatenate(dists), jnp.concatenate(times)
 
     def _kernels_cached(self, srcs: np.ndarray, chunk_bound) -> tuple:
         """Serve (dist_sn, time_sn, node_row) from the node-kernel cache,
         relaxing only the rows whose cached bound does not cover this
-        chunk's. Rows commit only after a converged sweep."""
+        chunk's (in power-of-two blocks: a warming cache relaxes few).
+        Rows commit per converged block."""
         import jax.numpy as jnp
 
         missing = srcs[self._row_bound[srcs] < np.float32(chunk_bound)]
         if len(missing):
-            dist, time = self._relax(missing, chunk_bound)
             if self._cache_dist is None:
                 inf = jnp.full((self.n_nodes, self.n_nodes),
                                jnp.inf, jnp.float32)
                 self._cache_dist = inf
                 self._cache_time = inf
-            rows = jnp.asarray(missing)
-            self._cache_dist = self._cache_dist.at[rows] \
-                .set(dist[:len(missing)])
-            self._cache_time = self._cache_time.at[rows] \
-                .set(time[:len(missing)])
-            self._row_bound[missing] = np.float32(chunk_bound)
+            block = min(_next_pow2(len(missing)), self._block_sources())
+            for lo, dist, time in self._relax_blocks(missing, chunk_bound,
+                                                     block):
+                part = missing[lo:lo + block]
+                rows = jnp.asarray(part)
+                self._cache_dist = self._cache_dist.at[rows] \
+                    .set(dist[:len(part)])
+                self._cache_time = self._cache_time.at[rows] \
+                    .set(time[:len(part)])
+                self._row_bound[part] = np.float32(chunk_bound)
             metrics.count("route.device.cache_miss_rows", int(len(missing)))
         metrics.count("route.device.cache_hit_rows",
                       int(len(srcs) - len(missing)))
@@ -387,34 +390,41 @@ class DeviceRouteKernel:
         """Relax (or cache-serve) + assemble; returns the DEVICE
         (B, T-1, K, K) float32 route array and finite-max scalar,
         dispatched but not synced. Split out so route_matrices shares
-        it."""
+        it. Uncached, each relaxation block is assembled as it lands
+        and merged into the tensor so far (pair_costs_packed), so no
+        (sources x nodes) concatenation is ever built and the programs
+        run are the same for one block or many."""
         import jax.numpy as jnp
 
         from ..ops import route_relax
 
         if self._cache_ok:
             dist, time, node_row = self._kernels_cached(srcs, chunk_bound)
+            blocks = [(0, dist, time)]
         else:
-            dist, time = self._relax(srcs, chunk_bound)
             node_row = np.full(self.n_nodes, -1, dtype=np.int32)
             node_row[srcs] = np.arange(len(srcs), dtype=np.int32)
+            blocks = self._relax_blocks(srcs, chunk_bound,
+                                        self._block_sources())
 
         # two packed blobs instead of eight small transfers: on a warm
         # cache the per-chunk device_put overhead IS the dispatch cost
         B, T, K = edge.shape
-        ints = np.concatenate([
+        ints = jnp.asarray(np.concatenate([
             np.ascontiguousarray(edge, dtype=np.int32).ravel(),
             nk.astype(np.int32, copy=False),
-            node_row])
-        f32s = np.concatenate([
+            node_row]))
+        f32s = jnp.asarray(np.concatenate([
             np.ascontiguousarray(offset, dtype=np.float32).ravel(),
             bounds.ravel(), caps.ravel(),
-            np.array([btol, tpen], dtype=np.float32)])
-        route, max_finite = route_relax.pair_costs_packed(
-            jnp.asarray(ints), jnp.asarray(f32s), dist, time,
-            self._e_start, self._e_end, self._e_len, self._e_v,
-            self._head_x, self._head_y,
-            B=B, T=T, K=K, N=self.n_nodes)
+            np.array([btol, tpen], dtype=np.float32)]))
+        cols = (self._e_start, self._e_end, self._e_len, self._e_v,
+                self._head_x, self._head_y)
+        route = jnp.full((B, T - 1, K, K), UNREACHABLE, jnp.float32)
+        for lo, dist, time in blocks:
+            route, max_finite = route_relax.pair_costs_packed(
+                ints, f32s, dist, time, np.int32(lo), route, *cols,
+                B=B, T=T, K=K, N=self.n_nodes)
         # still device arrays: the caller decides when (and whether on
         # this thread) to pay the sync — fill_prep(defer=True) never does
         return route, max_finite

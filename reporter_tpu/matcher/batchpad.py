@@ -507,7 +507,7 @@ def prepare_batch(runtime, traces_points: Sequence[Sequence[dict]],
         from ..utils import metrics
         try:
             with obs_trace.span("prep.routes_device"):
-                pending = route_kernel.fill_prep(out, params, B,
+                pending = route_kernel.fill_prep(out, params,
                                                  defer=defer_routes)
         except Exception:
             if route_circuit is not None:
@@ -559,10 +559,9 @@ def prepare_batch(runtime, traces_points: Sequence[Sequence[dict]],
         # SAME total max the sync path folds (device route bytes are
         # host-identical, so the decision — and therefore the f16
         # quantisation — matches exactly)
-        rows = int(dist.shape[0])
         route = None
 
-        def finalize(batch, _p=pending, _rows=rows):
+        def finalize(batch, _p=pending):
             import jax.numpy as jnp
 
             from ..utils import metrics
@@ -574,7 +573,7 @@ def prepare_batch(runtime, traces_points: Sequence[Sequence[dict]],
                 # chunk has no route bytes to degrade onto anyway
                 metrics.count("route.device.finalize_errors")
                 raise
-            batch.route_m = _device_route_full(route_dev, _rows, T)
+            batch.route_m = _device_route_full(route_dev)
             _p.write_back(out)
             if _wire_f16() and float(out["max_finite"][0]) <= WIRE_MAX_M:
                 batch.dist_m = runtime.to_f16(out["dist_m"])
@@ -590,16 +589,15 @@ def prepare_batch(runtime, traces_points: Sequence[Sequence[dict]],
                        pt_off=pt_off, times_flat=times, finalize=finalize)
 
 
-def _device_route_full(route_dev, rows: int, T: int):
-    """Pad a deferred (B, T-1, K, K) device route tensor out to the
-    native wire layout (rows, T, K, K): filler rows and the dead
-    trailing time step carry the UNREACHABLE sentinel — the same bytes
-    the native tail fill writes — so every decode shape and SKIP-row
-    behavior is identical to the host-materialised path. Runs as an
-    async device op; nothing here blocks."""
+def _device_route_full(route_dev):
+    """Pad a deferred (rows, T-1, K, K) device route tensor out to the
+    native wire layout (rows, T, K, K): the dead trailing time step
+    carries the UNREACHABLE sentinel — the same bytes the native tail
+    fill writes — so every decode shape is identical to the
+    host-materialised path. Runs as an async device op; nothing here
+    blocks."""
     import jax.numpy as jnp
-    B = int(route_dev.shape[0])
-    return jnp.pad(route_dev, ((0, rows - B), (0, 1), (0, 0), (0, 0)),
+    return jnp.pad(route_dev, ((0, 0), (0, 1), (0, 0), (0, 0)),
                    constant_values=np.float32(UNREACHABLE))
 
 

@@ -188,13 +188,22 @@ def pair_costs(edge, offset, nk, bounds, caps, dist_sn, time_sn,
 
 
 @partial(jax.jit, static_argnames=("B", "T", "K", "N"))
-def pair_costs_packed(ints, f32s, dist_sn, time_sn,
+def pair_costs_packed(ints, f32s, dist_sn, time_sn, row_lo, prev,
                       edge_start, edge_end, edge_len, edge_v,
                       head_x, head_y, *, B, T, K, N):
     """pair_costs with the six small per-chunk tensors packed into two
     1-D blobs so a warm dispatch pays two host->device transfers
     instead of eight. Pure repacking — slices/reshapes inside the jit
     are free and the assembled bytes match pair_costs exactly.
+
+    ``dist_sn``/``time_sn`` hold relaxation rows ``[row_lo, row_lo +
+    S)`` of the chunk's sources: pairs whose source row lies outside
+    them price as UNREACHABLE, and the result is merged into ``prev``
+    (the (B, T-1, K, K) tensor of the blocks before, all UNREACHABLE
+    for the first) by elementwise minimum — each pair takes its value
+    from its own block, the others never exceed UNREACHABLE. One
+    program per (B, T), whatever the chunk's source count; the returned
+    finite max covers every block merged so far.
 
     Layouts (see DeviceRouteKernel._run, the only caller):
       ints: [edge (B*T*K) | nk (B) | node_row (N)]            int32
@@ -204,13 +213,20 @@ def pair_costs_packed(ints, f32s, dist_sn, time_sn,
     btk = B * T * K
     edge = ints[:btk].reshape(B, T, K)
     nk = ints[btk:btk + B]
-    node_row = ints[btk + B:btk + B + N]
+    row = ints[btk + B:btk + B + N] - row_lo
+    node_row = jnp.where((row >= 0) & (row < dist_sn.shape[0]), row, -1)
     offset = f32s[:btk].reshape(B, T, K)
     bt1 = B * (T - 1)
     bounds = f32s[btk:btk + bt1].reshape(B, T - 1)
     caps = f32s[btk + bt1:btk + 2 * bt1].reshape(B, T - 1)
     backward_tol = f32s[btk + 2 * bt1]
     turn_penalty_factor = f32s[btk + 2 * bt1 + 1]
-    return pair_costs(edge, offset, nk, bounds, caps, dist_sn, time_sn,
-                      node_row, edge_start, edge_end, edge_len, edge_v,
-                      head_x, head_y, backward_tol, turn_penalty_factor)
+    route, _ = pair_costs(edge, offset, nk, bounds, caps, dist_sn,
+                          time_sn, node_row, edge_start, edge_end,
+                          edge_len, edge_v, head_x, head_y, backward_tol,
+                          turn_penalty_factor)
+    out = jnp.minimum(prev, route)
+    unreach = jnp.float32(UNREACHABLE)
+    max_finite = jnp.max(jnp.where(out < unreach, out, jnp.float32(0.0)),
+                         initial=jnp.float32(0.0))
+    return out, max_finite

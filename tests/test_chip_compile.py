@@ -20,8 +20,8 @@ K = 8
 #: decode shapes: a full bucket-64 chunk and a bucket-256 chunk
 DECODE_SHAPES = [(512, 64), (256, 256)]
 #: sources per relaxation block on the metro graph (route_device
-#: _block_sources) and padded sources of a 32-trace chunk
-RELAX_BLOCK, CHUNK_SOURCES, ROUTE_B = 256, 1024, 32
+#: _block_sources) and the rows of a full decode chunk
+RELAX_BLOCK, ROUTE_B = 256, 128
 
 
 def _decode_cases():
@@ -138,12 +138,14 @@ def test_relax_block_compiles(one_chip):
 @pytest.mark.parametrize("T", [64, 256])
 def test_pair_costs_packed_compiles(one_chip, T):
     from reporter_tpu.ops.route_relax import pair_costs_packed
-    B, S = ROUTE_B, CHUNK_SOURCES
+    B, S = ROUTE_B, RELAX_BLOCK
     btk, bt1 = B * T * K, B * (T - 1)
     args = (_spec((btk + B + N_NODES,), np.int32, one_chip),
             _spec((btk + 2 * bt1 + 2,), np.float32, one_chip),
             _spec((S, N_NODES), np.float32, one_chip),
-            _spec((S, N_NODES), np.float32, one_chip)) \
+            _spec((S, N_NODES), np.float32, one_chip),
+            _spec((), np.int32, one_chip),
+            _spec((B, T - 1, K, K), np.float32, one_chip)) \
         + _edge_cols(one_chip) \
         + (_spec((N_EDGES,), np.float32, one_chip),
            _spec((N_EDGES,), np.float32, one_chip))

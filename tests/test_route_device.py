@@ -270,6 +270,42 @@ class TestChunkParity:
         assert hr.tobytes() == dr.tobytes()
         assert (dr[5:] == UNREACH).all()
 
+    def test_programs_follow_rows_not_traces(self, city, matcher,
+                                             monkeypatch):
+        """Uncached, in blocks: chunks of 5 and 7 traces padded to 8
+        rows, with different source counts, run the same compiled
+        relaxation and assembly (the metro serving path must not
+        compile per chunk) and stay byte-identical to host routes."""
+        from reporter_tpu.graph import route_device
+        from reporter_tpu.ops import route_relax
+
+        monkeypatch.setattr(route_device, "_STATE_BUDGET_ELEMS",
+                            8 * max(city.num_nodes, city.num_edges))
+        kern = route_device.DeviceRouteKernel(city)
+        kern._cache_ok = False
+        assert kern._block_sources() == 4
+        reqs = _reqs(city, n=7)
+        T = max(bucket_length(len(r["trace"])) for r in reqs)
+        programs, sources = [], []
+        for n in (5, 7):
+            pts = [r["trace"] for r in reqs[:n]]
+            host = prepare_batch(matcher.runtime, pts, matcher.params, T,
+                                 pad_rows=8)
+            metrics.default.reset()
+            dev = prepare_batch(matcher.runtime, pts, matcher.params, T,
+                                pad_rows=8, route_kernel=kern,
+                                defer_routes=True)
+            dev.finalize_wire()
+            assert host.prep["route_m"].tobytes() \
+                == dev.prep["route_m"].tobytes()
+            assert np.asarray(host.route_m).tobytes() \
+                == np.asarray(dev.route_m).tobytes()
+            sources.append(metrics.default.counter("route.device.sources"))
+            programs.append((route_relax.relax_csr._cache_size(),
+                             route_relax.pair_costs_packed._cache_size()))
+        assert sources[0] != sources[1] and min(sources) > 4
+        assert programs[0] == programs[1]
+
     def test_single_point_and_short_traces(self, city, matcher, kernel):
         """nk<=1 traces have no live transitions; mixed with real
         traces the device fill must reproduce the tail-fill bytes."""
@@ -572,7 +608,7 @@ class TestRelaxKernel:
                "route_m": np.zeros((1, 3, 1, 1), np.float32),
                "max_finite": np.zeros(1, np.float32)}
         with pytest.raises(RuntimeError, match="over budget"):
-            kernel.fill_prep(out, MatchParams(max_candidates=1), 1)
+            kernel.fill_prep(out, MatchParams(max_candidates=1))
 
 
 class TestProfileTable:
