@@ -1,0 +1,8 @@
+"""Assembly per trace: timer matcher.assemble total / counter dispatch.traces, in ms."""
+SOURCE = "program_span"
+LAYER = "assembly and wire"
+MOVES = "traces_per_s"
+
+
+def read(r):
+    return r.ratio(r.timer_total("matcher.assemble"), r.counter("dispatch.traces"), 1e3)
