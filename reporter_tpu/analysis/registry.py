@@ -131,12 +131,32 @@ METRICS: Dict[str, str] = {
     "service.procs.restarts": "workers restarted into their slot",
     "service.procs.worker_start": "per-worker post-fork service builds",
     "service.requests.histogram": "/histogram requests",
+    "service.headers": "request line + header parse, every action (timer)",
+    "service.headers.cpu": "service.headers thread CPU, sampled (timer)",
+    "service.headers.cpu_wall": "service.headers wall, same sample (timer)",
+    "service.parse": "/report body read + JSON decode (timer)",
+    "service.parse.cpu": "service.parse thread CPU, sampled (timer)",
+    "service.parse.cpu_wall": "service.parse wall, same sample (timer)",
+    "service.columns": "/report points -> column arrays (timer)",
+    "service.columns.cpu": "service.columns thread CPU, sampled (timer)",
+    "service.columns.cpu_wall": "service.columns wall, same sample (timer)",
+    "report.serialise": "/report response body writer (timer)",
+    "report.serialise.cpu": "report.serialise thread CPU, sampled (timer)",
+    "report.serialise.cpu_wall": "report.serialise wall, same sample "
+                                 "(timer)",
+    "service.respond": "/report status, headers + body to the socket "
+                       "(timer)",
     "service.handle": "/report handling (timer)",
     "service.histogram": "/histogram handling (timer)",
     "service.errors.*": "error responses by status code",
     "dispatch.batches": "micro-batches dispatched",
     "dispatch.traces": "traces dispatched",
     "dispatch.match_many": "batched match call (timer)",
+    "dispatch.queue_wait": "a trace's enqueue -> its batch's match call "
+                           "(timer)",
+    "dispatch.idle": "dispatch loop blocked on an empty queue (timer)",
+    "dispatch.fill": "dispatch loop collecting a batch to its flush "
+                     "(timer)",
     "dispatch.errors": "dispatch loop errors",
     # load management (ISSUE 15)
     "dispatch.queue.*": "bounded-queue sheds: rejected/evicted/waits",
@@ -206,6 +226,7 @@ METRICS: Dict[str, str] = {
     "service.requests.feed": "/feed long-poll requests",
     # observability
     "flightrec.dumps": "flight-recorder postmortems written",
+    "process.gc.pause": "one collector pass, any generation (timer)",
     # device-level profiler (obs/profiler.py)
     "decode.compile.count": "decode dispatches that paid an XLA compile",
     "decode.compile.recompiles": "same-shape recompiles (storm signal)",

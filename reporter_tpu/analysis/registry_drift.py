@@ -24,6 +24,8 @@ MT001  a metric name passed to the metrics layer (``count``/``timer``/
        Names that are dynamic from the first character (the circuit
        breaker's ``f"{self.name}.opened"``) are unresolvable and
        skipped — register the instantiated family as a pattern.
+       A ``timer(name, cpu=True)`` site also records ``<name>.cpu``
+       and ``<name>.cpu_wall``.
 MT002  a dead exact registry entry: no string literal anywhere in the
        scanned code matches it. Pattern entries are exempt — they exist
        precisely because their call sites are dynamic.
@@ -165,8 +167,14 @@ def _metric_sites(files: Sequence[SourceFile]
             if not node.args:
                 continue
             glob = _metric_name_glob(node.args[0])
-            if glob is not None:
-                out.append((sf.relpath, node.lineno, glob))
+            if glob is None:
+                continue
+            out.append((sf.relpath, node.lineno, glob))
+            if node.func.attr == "timer" and any(
+                    kw.arg == "cpu" and isinstance(kw.value, ast.Constant)
+                    and kw.value.value for kw in node.keywords):
+                out += [(sf.relpath, node.lineno, glob + suffix)
+                        for suffix in (".cpu", ".cpu_wall")]
     return out
 
 
@@ -284,7 +292,8 @@ def run(files: Sequence[SourceFile], repo_root: str,
                     "registration"))
 
     # ---- MT001: call sites -> registry -------------------------------------
-    for rel, line, glob in _metric_sites(files):
+    sites = _metric_sites(files)
+    for rel, line, glob in sites:
         if not _covered(glob, metrics_reg):
             findings.append(Finding(
                 rel, line, "MT001",
@@ -294,7 +303,10 @@ def run(files: Sequence[SourceFile], repo_root: str,
 
     # ---- MT002: registry -> code literals ----------------------------------
     if full_scope:
-        literals = _string_literals(files)
+        # a derived name (``<name>.cpu``, ``.cpu_wall``) has its site,
+        # not a literal
+        literals = _string_literals(files) | {
+            glob for _rel, _line, glob in sites if "*" not in glob}
         for entry in sorted(metrics_reg):
             if entry.endswith("*"):
                 continue  # dynamic family: call sites are f-strings

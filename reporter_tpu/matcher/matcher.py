@@ -491,10 +491,15 @@ class SegmentMatcher:
         # threads only spawn on first submit; GC of the matcher releases
         # them) so concurrent first calls can't race a lazy check-then-set
         # into duplicate lanes.
+        # (each lane named for the profiler's host lines too, or it
+        # would carry the name of the thread that first submitted)
         self._dispatch_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="device-dispatch")
+            max_workers=1, thread_name_prefix="device-dispatch",
+            initializer=metrics.name_os_thread,
+            initargs=("device-dispatch",))
         self._drain_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="device-drain")
+            max_workers=1, thread_name_prefix="device-drain",
+            initializer=metrics.name_os_thread, initargs=("device-drain",))
         # build the process-global decode mesh NOW (not on the first
         # request): device enumeration + the sharded jit wrappers are
         # one-time costs that belong at init, and a mis-sliced

@@ -21,8 +21,12 @@ on worker threads record too, and the exporter filters by trace id).
 Completed spans land in :mod:`flightrec`'s bounded ring — the same
 ring the crash postmortem dumps — and :func:`export_trace` renders one
 trace's spans as Chrome/Perfetto trace-event JSON (``ph:"X"`` complete
-events, epoch-microsecond timestamps, so they line up with an XLA
-profile captured by ``metrics.device_trace``).
+events, epoch-microsecond timestamps, comparable across processes).
+They do not line up with a profiler trace (``jax.profiler``,
+``metrics.device_trace``), whose events are relative to its session's
+start. What meets the device timeline is the stage timers themselves:
+every ``metrics.timer`` also opens a ``jax.profiler.TraceAnnotation``
+of its name on its thread's host line, on the profiler's clock.
 """
 from __future__ import annotations
 
@@ -51,7 +55,7 @@ _ctx: "contextvars.ContextVar[Optional[Tuple[str, int]]]" = \
 _ids = itertools.count(1)
 
 #: maps perf_counter_ns timestamps onto wall-clock epoch ns, so span
-#: timestamps are comparable across processes and with an XLA profile
+#: timestamps are comparable across processes
 _EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
 
 

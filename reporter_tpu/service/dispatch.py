@@ -51,7 +51,8 @@ _STOP = object()
 
 
 class _Slot:
-    __slots__ = ("trace", "columns", "event", "result", "error", "ctx")
+    __slots__ = ("trace", "columns", "event", "result", "error", "ctx",
+                 "t_enq")
 
     def __init__(self, trace, columns: Optional[tuple] = None):
         self.trace = trace
@@ -68,6 +69,8 @@ class _Slot:
         self.event = threading.Event()
         self.result: Optional[dict] = None
         self.error: Optional[Exception] = None
+        # dispatch.queue_wait runs from here to its batch's match call
+        self.t_enq = time.perf_counter()
 
 
 class BatchDispatcher:
@@ -296,34 +299,39 @@ class BatchDispatcher:
         would bust ``REPORTER_TPU_BATCH_LATENCY_MS``), ``max_wait``
         elapsed since the first trace, the queue stayed empty for
         ``idle_grace``, or the close() sentinel surfaced (every slot
-        before it still flushes)."""
+        before it still flushes). The wait for the first trace is timed
+        as ``dispatch.idle`` (nothing to send), the collection after it
+        as ``dispatch.fill``."""
         _locks.fuzz_point("dispatch.queue.get")
-        first = self._queue.get()
+        with metrics.timer("dispatch.idle"):
+            first = self._queue.get()
         if first is _STOP:
             self._stopping = True
             return []
         slots = [first]
-        cap = self._effective_cap()
-        if cap < self.max_batch:
-            metrics.count("batch.latency.capped_batches")
-        t0 = time.monotonic()
-        while len(slots) < cap:
-            remaining = self.max_wait - (time.monotonic() - t0)
-            if remaining <= 0:
-                break
-            try:
-                _locks.fuzz_point("dispatch.queue.get")
-                got = self._queue.get(
-                    timeout=min(remaining, self.idle_grace))
-            except queue.Empty:
-                break  # idle past the grace window — flush what we have
-            if got is _STOP:
-                self._stopping = True
-                break
-            slots.append(got)
+        with metrics.timer("dispatch.fill"):
+            cap = self._effective_cap()
+            if cap < self.max_batch:
+                metrics.count("batch.latency.capped_batches")
+            t0 = time.monotonic()
+            while len(slots) < cap:
+                remaining = self.max_wait - (time.monotonic() - t0)
+                if remaining <= 0:
+                    break
+                try:
+                    _locks.fuzz_point("dispatch.queue.get")
+                    got = self._queue.get(
+                        timeout=min(remaining, self.idle_grace))
+                except queue.Empty:
+                    break  # idle past the grace window — flush
+                if got is _STOP:
+                    self._stopping = True
+                    break
+                slots.append(got)
         return slots
 
     def _loop(self):
+        metrics.name_os_thread(self._thread.name)
         while not self._stopping:
             slots = self._drain_batch()
             if not slots:
@@ -360,11 +368,14 @@ class BatchDispatcher:
                             [s.columns for s in slots])
                     else:
                         batch = [s.trace for s in slots]
-                    t_match = time.monotonic()
+                    t_match = time.perf_counter()
+                    for s in slots:
+                        metrics.observe("dispatch.queue_wait",
+                                        t_match - s.t_enq)
                     with metrics.timer("dispatch.match_many"):
                         results = self._match_many(batch)
                     self._note_service_time(
-                        time.monotonic() - t_match, len(slots))
+                        time.perf_counter() - t_match, len(slots))
                     for slot, res in zip(slots, results):
                         slot.result = res
             except Exception as e:  # propagate to every waiter in the batch

@@ -107,6 +107,9 @@ class ReporterService:
         # the loud backstop).
         self.admission = admission.AdmissionGate(self.dispatcher) \
             if admission.armed() else None
+        # collector pauses on /stats and in profiler traces
+        # (process.gc.pause), one hook per process
+        metrics.install_gc_timer()
 
     def handle(self, trace: dict) -> "tuple[int, str | bytes | memoryview]":
         """Validate + match + report; (status, body). The 200 body is
@@ -136,7 +139,8 @@ class ReporterService:
         try:
             # columnarise the wire ONCE, in this request thread — the
             # dispatch loop and matcher never touch point dicts again
-            lat, lon, tm, acc = points_to_columns(trace["trace"])
+            with metrics.timer("service.columns", cpu=True):
+                lat, lon, tm, acc = points_to_columns(trace["trace"])
             match = self.dispatcher.submit(
                 trace, columns=(trace.get("uuid"), lat, lon, tm, acc,
                                 trace.get("match_options")))
@@ -145,7 +149,7 @@ class ReporterService:
             # the native backend (memoryview handed to the socket with
             # no re-encode), the Python columnar writer otherwise; the
             # per-trace report/segment dicts never exist on this path
-            with obs_trace.span("report.serialise"):
+            with metrics.timer("report.serialise", cpu=True):
                 return 200, report_wire(match, trace, self.threshold_sec,
                                         report_levels, transition_levels)
         except admission.Overload as e:
@@ -537,6 +541,11 @@ def make_handler(service: ReporterService):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
 
+        def parse_request(self) -> bool:
+            # the request line and headers, every action
+            with metrics.timer("service.headers", cpu=True):
+                return super().parse_request()
+
         def _parse(self, post: bool) -> dict:
             split = urllib.parse.urlsplit(self.path)
             if split.path.split("/")[-1] not in ACTIONS:
@@ -760,7 +769,7 @@ def make_handler(service: ReporterService):
                 # span below it shares the request's trace_id
                 with obs_trace.span("service.request") as root:
                     try:
-                        with obs_trace.span("service.parse"):
+                        with metrics.timer("service.parse", cpu=True):
                             trace = self._parse(post)
                     except Exception as e:
                         self._respond(400, json.dumps({"error": str(e)}))
@@ -781,10 +790,11 @@ def make_handler(service: ReporterService):
                     gate.release()
             if code != 200:
                 metrics.count(f"service.errors.{code}")
-            if code == 429:
-                self._respond_shed(code, body)
-            else:
-                self._respond(code, body)
+            with metrics.timer("service.respond"):
+                if code == 429:
+                    self._respond_shed(code, body)
+                else:
+                    self._respond(code, body)
 
         def do_GET(self):
             self._do(False)

@@ -11,18 +11,30 @@ This module is that subsystem, kept deliberately small and lock-cheap:
 
 - ``Registry``: named monotonically-increasing counters and stage
   timers. A timer is a fixed log-bucketed histogram (power-of-2 bounds,
-  one numpy bucket increment per observation) plus count/total/max, so
+  one list-slot increment per observation) plus count/total/max, so
   ``snapshot()`` reports p50/p95/p99 per stage — count/total/max alone
   cannot distinguish "steady 10 ms" from "9 ms with a 2 s tail", and
   the tail is what pages people.
 - ``timer(name)``: context manager recording a stage duration. When
   request tracing is armed (``obs.trace``) every timer site doubles as
-  a span site — the stage-timer discipline IS the span tree.
+  a span site — the stage-timer discipline IS the span tree. Once the
+  process has imported JAX, every timer also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so each stage sits
+  on its thread's host line of any profiler trace, on the same clock as
+  the device's operations (idle with no profiler session: ~0.4 µs).
+  ``timer(name, cpu=True)`` also records, on one execution in
+  ``CPU_SAMPLE_EVERY``, ``<name>.cpu`` (the calling thread's CPU time
+  over the stage) and ``<name>.cpu_wall`` (the same executions' wall
+  time): wall minus CPU is time the thread was runnable but waiting
+  (the interpreter lock, on a Python stage).
+- ``name_os_thread(name)``: names the calling thread where the profiler
+  reads it, so its host line says which thread it is.
+- ``install_gc_timer()``: one ``gc.callbacks`` hook per process timing
+  every collector pass as ``process.gc.pause`` (with a ``process.gc``
+  annotation), so collector stalls show on ``/stats`` and in a trace.
 - ``device_trace(out_dir)``: context manager wrapping
   ``jax.profiler.trace`` — a real TPU trace viewable in TensorBoard
-  or Perfetto — gated so importing this module never imports jax. It
-  emits a correlation marker (``jax.profiler.TraceAnnotation`` carrying
-  the current trace id) so host spans line up with the XLA profile.
+  or Perfetto — gated so importing this module never imports jax.
 
 Snapshots report RAW floats: the old 6-decimal rounding collapsed
 sub-microsecond timer means to 0.0, which read as "stage never ran".
@@ -35,8 +47,12 @@ dispatcher); tests construct private ``Registry`` instances.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
+import itertools
 import math
+import sys
 import time
 from typing import Dict, Iterator, List, Tuple
 
@@ -82,7 +98,9 @@ class _Timer:
         self.count = 0
         self.total_s = 0.0
         self.max_s = 0.0
-        self.buckets = np.zeros(_N_BUCKETS, dtype=np.int64)
+        # a plain list: one slot increment is several times cheaper
+        # than a numpy scalar store, and every timed stage pays it
+        self.buckets = [0] * _N_BUCKETS
 
     def add(self, elapsed_s: float) -> None:
         self.count += 1
@@ -109,11 +127,82 @@ class _Timer:
         return min(lo + frac * (hi - lo), self.max_s)
 
 
+#: a ``cpu=True`` stage reads the thread's CPU clock on one execution in
+#: this many: the read is a system call (no vDSO path), several
+#: microseconds apiece under some hypervisors, twice per stage, under
+#: the interpreter lock
+CPU_SAMPLE_EVERY = 16
+
+#: queued timer observations (``Registry._record``) folded into the
+#: histograms by the appender that reaches this many, lock permitting
+_FOLD_AT = 64
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is imported (see
+#: :func:`_annotation`); None until then
+_ANNOTATION = None
+
+
+def _annotation():
+    """The profiler's annotation class, resolved once from
+    ``sys.modules``: a process that never imported JAX imports nothing
+    here and pays one dict lookup per timer."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        _ANNOTATION = getattr(sys.modules.get("jax.profiler"),
+                              "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+class _Stage:
+    """One timed stage (:meth:`Registry.timer`): the profiler annotation
+    outermost, then the request span, then the wall and CPU clocks."""
+
+    __slots__ = ("_reg", "_name", "_cpu_name", "_ann", "_span", "_t0",
+                 "_c0")
+
+    def __init__(self, reg: "Registry", name: str, cpu: bool):
+        self._reg = reg
+        self._name = name
+        self._cpu_name = name + ".cpu" if cpu else None
+
+    def __enter__(self) -> None:
+        ann = _ANNOTATION or _annotation()
+        if ann is not None:
+            ann = ann(self._name)
+            ann.__enter__()
+        self._ann = ann
+        self._span = _trace.span(self._name)  # no-op unless armed
+        self._span.__enter__()
+        # the wall clock's reads enclose the CPU clock's, so a stage
+        # that never waits reads CPU <= wall
+        self._t0 = time.perf_counter()
+        if self._cpu_name is not None:
+            self._c0 = time.thread_time_ns()
+
+    def __exit__(self, *exc) -> bool:
+        cpu_s = (time.thread_time_ns() - self._c0) * 1e-9 \
+            if self._cpu_name is not None else 0.0
+        elapsed = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._reg._record(self._name, elapsed, self._cpu_name, cpu_s)
+        return False
+
+
 class Registry:
     def __init__(self):
         self._lock = _locks.new_lock("metrics.registry")
         self._counters: Dict[str, int] = {}
         self._timers: Dict[str, _Timer] = {}
+        # timer observations wait here, appended without the lock (one
+        # atomic deque append), so a collector callback that runs while
+        # its own thread holds the lock never waits on it: the appender
+        # that fills a batch folds it in when the lock is free, and
+        # every read folds what is left
+        self._pending: "collections.deque" = collections.deque()
+        # per ``cpu=True`` stage name: executions so far, for sampling
+        self._cpu_seq: Dict[str, Iterator[int]] = {}
 
     def count(self, name: str, n: int = 1) -> int:
         """Increment a counter; returns the new value."""
@@ -130,33 +219,61 @@ class Registry:
         with self._lock:
             return self._counters.get(name, 0)
 
-    @contextlib.contextmanager
-    def timer(self, name: str) -> Iterator[None]:
-        sp = _trace.span(name)  # no-op unless request tracing is armed
-        t0 = time.perf_counter()
-        try:
-            with sp:
-                yield
-        finally:
-            elapsed = time.perf_counter() - t0
-            with self._lock:
-                t = self._timers.get(name)
-                if t is None:
-                    t = self._timers[name] = _Timer()
-                t.add(elapsed)
+    def _timer(self, name: str) -> _Timer:
+        """The named histogram, created on first use."""
+        t = self._timers.get(name)
+        if t is None:
+            # every caller holds self._lock
+            t = self._timers[name] = _Timer()  # lint: ignore[LD001]
+        return t
+
+    def _record(self, name: str, elapsed_s: float,
+                cpu_name: "str | None" = None, cpu_s: float = 0.0) -> None:
+        """Queue one stage's wall time and, with ``cpu_name``, its
+        thread CPU time, as one entry (folded under one acquisition:
+        ``cpu_name`` gets the CPU time, ``<cpu_name>_wall`` the wall)."""
+        pending = self._pending
+        pending.append((name, elapsed_s, cpu_name, cpu_s))
+        if len(pending) >= _FOLD_AT and self._lock.acquire(blocking=False):
+            try:
+                self._fold()
+            finally:
+                self._lock.release()
+
+    def timer(self, name: str, cpu: bool = False) -> _Stage:
+        """Context manager timing a stage as ``name``. With ``cpu``, the
+        first of every ``CPU_SAMPLE_EVERY`` executions of the stage also
+        records the thread's CPU time over it as ``<name>.cpu`` and its
+        wall time again as ``<name>.cpu_wall``: their ratio over the
+        sample stands for every execution."""
+        if cpu:
+            seq = self._cpu_seq.get(name)
+            if seq is None:
+                seq = self._cpu_seq.setdefault(name, itertools.count())
+            cpu = next(seq) % CPU_SAMPLE_EVERY == 0
+        return _Stage(self, name, cpu)
 
     def observe(self, name: str, elapsed_s: float) -> None:
-        """Record a duration measured externally."""
-        with self._lock:
-            t = self._timers.get(name)
-            if t is None:
-                t = self._timers[name] = _Timer()
-            t.add(elapsed_s)
+        """Record a duration measured externally (never waits on the
+        lock, so a collector callback may call it)."""
+        self._record(name, elapsed_s)
+
+    def _fold(self) -> None:
+        """Move queued observations into their histograms (the caller
+        holds the lock)."""
+        pending = self._pending
+        while pending:
+            name, elapsed_s, cpu_name, cpu_s = pending.popleft()
+            self._timer(name).add(elapsed_s)
+            if cpu_name is not None:
+                self._timer(cpu_name).add(cpu_s)
+                self._timer(cpu_name + "_wall").add(elapsed_s)
 
     def snapshot(self) -> dict:
         """{"counters": {...}, "timers": {name: {count, total_s, mean_s,
         max_s, p50_s, p95_s, p99_s}}} — raw floats (see module doc)."""
         with self._lock:
+            self._fold()
             counters = dict(self._counters)
             timers = {
                 name: {
@@ -179,9 +296,10 @@ class Registry:
         {timer: (count, total_s, max_s, bucket counts)}). Bucket counts
         align with ``BUCKET_BOUNDS_S`` plus one trailing overflow."""
         with self._lock:
+            self._fold()
             counters = dict(self._counters)
             timers = {name: (t.count, t.total_s, t.max_s,
-                             t.buckets.tolist())
+                             list(t.buckets))
                       for name, t in self._timers.items()}
         return counters, timers
 
@@ -189,12 +307,14 @@ class Registry:
         with self._lock:
             self._counters.clear()
             self._timers.clear()
+            self._pending.clear()
 
     def reset_timers(self) -> None:
         """Clear timers only: bench legs isolate one stage's histogram
         without zeroing cache-hit/egress counters mid-run."""
         with self._lock:
             self._timers.clear()
+            self._pending.clear()
 
 
 def snapshot_rounded(registry: "Registry | None" = None,
@@ -227,24 +347,62 @@ from . import forksafe as _forksafe  # noqa: E402
 _forksafe.register(default.reset)
 
 
+def name_os_thread(name: str) -> None:
+    """Give the calling thread ``name`` at the OS level (Linux, at most
+    15 bytes), where the profiler reads it: the thread's host line in a
+    profiler trace then carries this name, not the process's (or that
+    of the thread that started it)."""
+    try:
+        with open("/proc/thread-self/comm", "w") as f:
+            f.write(name[:15])
+    except OSError:
+        pass
+
+
+#: when the open collector pass started, and its open annotation
+_gc_t0 = 0.0
+_gc_ann = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``process.gc.pause`` observation per
+    pass of any generation, inside a ``process.gc`` annotation. Passes
+    never overlap (the collector is not re-entered), so module state
+    holds the open one. The pass may have started while this thread
+    held the registry lock: ``observe`` never waits on it."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        ann = _annotation()
+        if ann is not None:
+            ann = ann("process.gc")
+            ann.__enter__()
+        _gc_ann = ann
+        _gc_t0 = time.perf_counter()
+        return
+    elapsed = time.perf_counter() - _gc_t0
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    default.observe("process.gc.pause", elapsed)
+
+
+def install_gc_timer() -> None:
+    """Time every collector pass of this process into the default
+    registry (idempotent; a forked child inherits the hook)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
 @contextlib.contextmanager
 def device_trace(out_dir: str) -> Iterator[None]:
     """Capture an XLA/TPU profiler trace into ``out_dir`` (view with
     TensorBoard's profile plugin or Perfetto). A no-op context if jax is
-    unavailable. When request tracing is armed, the profiled region is
-    wrapped in a ``TraceAnnotation`` naming the current trace id — the
-    correlation marker that lines host spans up with the XLA timeline."""
+    unavailable. Every stage timer inside the region lands in the trace
+    as an annotation on its thread's host line."""
     try:
         import jax
     except ImportError:  # pragma: no cover - jax is baked into this image
         yield
         return
-    with _trace.span("device_trace", out_dir=out_dir):
-        ctx = _trace.current()
-        with jax.profiler.trace(out_dir):
-            if ctx is not None:
-                with jax.profiler.TraceAnnotation(
-                        f"reporter_tpu.trace:{ctx[0]}"):
-                    yield
-            else:
-                yield
+    with jax.profiler.trace(out_dir):
+        yield

@@ -318,6 +318,22 @@ def test_registry_unregistered_live_metric_detected():
                for f in findings)
 
 
+def test_registry_cpu_timer_sibling_is_a_live_metric():
+    """A ``timer(name, cpu=True)`` site also records ``<name>.cpu``: the
+    sibling is live (no MT002) and, dropped from a registry copy, fires
+    MT001 at the site."""
+    files = analysis.collect_py_files(REPO)
+    findings = registry_drift.run(files, REPO)
+    assert not any("service.parse.cpu" in f.message for f in findings)
+    metrics_reg = dict(registry.METRICS)
+    del metrics_reg["service.parse.cpu"]
+    findings = registry_drift.run(files, REPO, metrics_reg=metrics_reg,
+                                  full_scope=False)
+    assert any(f.rule == "MT001" and "service.parse.cpu" in f.message
+               and f.path == "reporter_tpu/service/server.py"
+               for f in findings)
+
+
 def test_readme_knob_table_parser_reads_full_names():
     readme = _read(os.path.join(REPO, "README.md"))
     table = registry_drift.parse_readme_knobs(readme)
