@@ -278,6 +278,12 @@ def _bucketing_leg(city, matcher, reqs_pool):
     tb.options = mixed[0]["match_options"]
 
     saved = os.environ.get("REPORTER_TPU_BUCKETS")
+    saved_chunk = os.environ.get("REPORTER_TPU_DECODE_CHUNK")
+    # chunks of 64 rows: the batch is larger than one chunk, so the
+    # matcher takes the per-bucket plan (a batch that fits one chunk
+    # may be merged into one chunk, and then is never split), and each
+    # bucket fits one
+    os.environ["REPORTER_TPU_DECODE_CHUNK"] = "64"
 
     def _leg(spec):
         if spec is None:
@@ -307,6 +313,10 @@ def _bucketing_leg(city, matcher, reqs_pool):
             os.environ.pop("REPORTER_TPU_BUCKETS", None)
         else:
             os.environ["REPORTER_TPU_BUCKETS"] = saved
+        if saved_chunk is None:
+            os.environ.pop("REPORTER_TPU_DECODE_CHUNK", None)
+        else:
+            os.environ["REPORTER_TPU_DECODE_CHUNK"] = saved_chunk
         profiler.reset()
     return {
         "n_traces": len(mixed),

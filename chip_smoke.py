@@ -212,25 +212,25 @@ def warm_shapes(service, reqs: list, pools: dict) -> int:
     """Every (rows, T) shape the measured pass can meet: per bucket, one
     ``report_many`` batch (the service's in-process entry point, one
     dispatcher batch) of each power-of-two size up to the dispatcher's
-    cap. The ladder is pinned to the pools' buckets with splitting off
-    meanwhile, so a batch decodes at its pool's bucket whatever its size
-    (the default ladder only reaches a sub-bucket by splitting a mixed
-    group, never a lone trace). Returns the batches sent."""
+    cap. A batch of up to 128 traces may be merged into one chunk at
+    its longest trace's bucket, whatever the size of that bucket's pool,
+    so every bucket is warmed at every size. The ladder is pinned to
+    the one bucket with splitting off meanwhile, so a batch decodes at
+    that bucket whatever its traces (a longer trace is cut to it).
+    Returns the batches sent."""
     from reporter_tpu.matcher.batchpad import ENV_BUCKETS
     sent = 0
     ladder = os.environ.get(ENV_BUCKETS)
-    os.environ[ENV_BUCKETS] = ",".join(map(str, sorted(pools))) + "@off"
     try:
-        for _T, idx in sorted(pools.items()):
+        for T in sorted(pools):
+            os.environ[ENV_BUCKETS] = f"{T}@off"
             r = 1
-            while r <= service.dispatcher.max_batch:
-                got = service.report_many([reqs[i] for i in idx[:r]])
+            while r <= min(service.dispatcher.max_batch, len(reqs)):
+                got = service.report_many(reqs[:r])
                 check(all(g is not None for g in got),
                       f"warm batch of {r}: {sum(g is None for g in got)} "
                       "reports failed")
                 sent += 1
-                if r >= len(idx):
-                    break
                 r *= 2
     finally:
         if ladder is None:

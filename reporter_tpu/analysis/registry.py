@@ -236,6 +236,9 @@ METRICS: Dict[str, str] = {
     "decode.occupancy.*": "per-bucket occupancy ratio histograms",
     "decode.shard.*": "mesh-path decode chunks + rows fanned across it",
     "decode.bucket.split": "chunks split into finer pow2 sub-buckets",
+    "decode.bucket.coalesced": "groups merged into one chunk where the "
+                               "per-bucket plan made more",
+    "decode.chunks": "decode chunks planned (one prep, dispatch, assembly)",
     "decode.shadow.chunks": "chunks shadow-decoded via the numpy oracle",
     "decode.shadow.sampled": "traces shadow-decoded via the numpy oracle",
     "decode.shadow.mismatch": "shadow decodes scoring off the oracle",

@@ -78,7 +78,15 @@ def bucket_ladder() -> "tuple[tuple, float]":
     """(ladder, split_threshold) from REPORTER_TPU_BUCKETS; the default
     fixed ladder with the default threshold when unset. A malformed
     spec logs and keeps the default (a typo'd ladder must degrade to
-    the shipped shapes, never to an unbounded shape zoo)."""
+    the shipped shapes, never to an unbounded shape zoo).
+
+    The native dispatcher's chunk plan (``SegmentMatcher._plan_chunks``)
+    buckets a group on this ladder and splits a bucket past the
+    threshold; then it merges a group of at most 128 traces into one
+    chunk, at the power of two of its longest trace between
+    ``ladder[0]`` and that trace's bucket (the bucket itself at
+    threshold 1.0), where the padding that adds is worth less than the
+    chunks it saves."""
     spec = _os.environ.get(ENV_BUCKETS, "").strip()
     if not spec:
         # the default is NOT cached: LENGTH_BUCKETS is read live, so

@@ -10,7 +10,8 @@ py/simple_reporter.py:132-133):
 
 plus the batched entry point the reference lacks — ``match_many`` — which is
 the TPU hot path: many traces prepared on host, decoded in one vmapped
-Viterbi per padding bucket.
+Viterbi per chunk (a micro-batch that fits one chunk is one chunk; a
+larger group is cut per padding bucket).
 """
 from __future__ import annotations
 
@@ -32,9 +33,9 @@ from ..utils import faults, metrics
 from ..utils import locks as _locks
 from ..utils.circuit import CircuitBreaker
 from .assemble import assemble_segments
-from .batchpad import (bucket_ladder, kept_point_count, pack_batches,
-                       padded_batch_rows, prepare_batch, prepare_trace,
-                       prepare_traces_numpy)
+from .batchpad import (_next_pow2, bucket_ladder, kept_point_count,
+                       pack_batches, padded_batch_rows, prepare_batch,
+                       prepare_trace, prepare_traces_numpy)
 from .params import MatchParams
 
 # process-wide configuration, mirroring valhalla.Configure's module-level
@@ -77,6 +78,20 @@ def _route_device_enabled() -> bool:
         in ("1", "on", "true", "yes")
 
 
+#: traces a decode chunk holds on one device with the pipeline on
+#: (``_decode_chunk``'s default there), and the most traces the chunk
+#: plan merges into one chunk (``SegmentMatcher._plan_chunks``)
+PIPELINED_CHUNK = 128
+
+#: padded point cells worth one decode chunk's fixed host cost, the
+#: most padding a merge may add for each chunk it saves. Measured on a
+#: v5e host, a chunk pays about 2.3 ms of fixed prep and a decode
+#: dispatch of 3.7 ms at 16 x 128 cells against 5.1 ms at 16 x 256:
+#: about 0.7 us a cell over a fixed 2.2 ms, so the fixed part is worth
+#: some 6,400 cells. Two thirds of that, so a merge has to save clearly.
+CHUNK_COST_CELLS = 4096
+
+
 def _decode_chunk() -> int:
     """Traces per decode dispatch. REPORTER_TPU_DECODE_CHUNK forces it;
     the default follows the pipeline mode: 128 when the device lanes
@@ -94,7 +109,7 @@ def _decode_chunk() -> int:
     if val:
         return max(1, val)
     if pipeline_enabled() and (os.cpu_count() or 1) > 1:
-        base = 128
+        base = PIPELINED_CHUNK
     else:
         base = 512
     from ..ops import decode_mesh_size
@@ -1005,10 +1020,10 @@ class SegmentMatcher:
 
     def _dispatch_native(self, tb: TraceBatch, per_trace_params, chunk,
                          pad, submit):
-        """Hot path: group by prep params, bucket by raw length
-        (vectorised), then ONE rt_prepare_batch call per chunk on this
-        thread — the chunk's flat coordinate columns pass straight from
-        the TraceBatch to the native call, zero per-point Python —
+        """Hot path: group by prep params, plan each group's chunks
+        (``_plan_chunks``), then ONE rt_prepare_batch call per chunk on
+        this thread — the chunk's flat coordinate columns pass straight
+        from the TraceBatch to the native call, zero per-point Python —
         handing each prepared batch to ``submit`` (the device lanes).
 
         Failure domain: each chunk consults the circuit breaker. A
@@ -1018,81 +1033,121 @@ class SegmentMatcher:
         chunks skip native entirely until a half-open probe succeeds.
         """
         workers = max(1, _prep_workers())
-        buckets = np.asarray(bucket_ladder()[0], dtype=np.int64)
         raw_counts = np.diff(tb.offsets)  # per-trace raw point counts
-        # bucket by RAW length (kept length is only known after the
-        # native prep; raw is an upper bound, so a jitter-heavy trace
-        # may decode in a larger bucket — same decoded path, the SKIP
-        # tail is inert)
-        Ts = buckets[np.minimum(
-            np.searchsorted(buckets, np.maximum(tb.lengths(), 1)),
-            len(buckets) - 1)]
         ci = 0  # chunk index across the whole call, a span attribute
         for params, idxs in self._param_groups(per_trace_params):
             sigma = np.float32(params.effective_sigma)
             beta = np.float32(params.beta)
-            for T0 in np.unique(Ts[idxs]).tolist():
-                group = idxs[Ts[idxs] == T0]
-                for T, bucket in self._split_bucket(int(T0), group,
-                                                    raw_counts, pad,
-                                                    chunk):
-                    for lo in range(0, len(bucket), chunk):
-                        part = bucket[lo:lo + chunk]
-                        # part itself is the order: _drain_stage only
-                        # enumerates it, so no per-chunk list conversion
-                        # (reporter-lint HP003)
-                        order = part
-                        rows = padded_batch_rows(len(part), pad)
-                        with obs_trace.span("matcher.chunk", chunk=ci,
-                                            traces=len(part), T=int(T)):
-                            ci += 1
-                            if not self.circuit.allow():
-                                metrics.count(
-                                    "matcher.circuit.fallback_chunks")
-                                self._submit_numpy_chunk(
-                                    tb, part, params, pad, submit,
-                                    sigma, beta)
-                                continue
-                            try:
-                                with metrics.timer("matcher.prep"):
-                                    faults.failpoint("native.prep")
-                                    batch = prepare_batch(
-                                        self.runtime, tb.gather(part),
-                                        params, int(T), pad_rows=rows,
-                                        n_threads=workers,
-                                        route_kernel=self
-                                        ._device_route_kernel(),
-                                        route_circuit=self.circuit_route,
-                                        # device-resident route tensor:
-                                        # the decode stage pays the sync
-                                        # (finalize_wire), overlapped
-                                        # with the next chunk's prep
-                                        defer_routes=True)
-                            except Exception as e:
-                                self.circuit.record_failure()
-                                metrics.count(
-                                    "matcher.circuit.native_errors")
-                                logger.warning(
-                                    "native prep failed for a %d-trace "
-                                    "chunk (%s); serving it via the "
-                                    "numpy fallback", len(part), e)
-                                self._submit_numpy_chunk(
-                                    tb, part, params, pad, submit,
-                                    sigma, beta)
-                                continue
-                            self.circuit.record_success()
-                            # the chunk's wide event: occupancy vs the
-                            # padded (rows, T) grid, memo state, queue
-                            # depth — one call per CHUNK, not per trace
-                            profiler.chunk_event(
-                                bucket_T=int(T), K=params.max_candidates,
-                                traces=len(part),
-                                rows=int(batch.case.shape[0]),
-                                kept_points=kept_point_count(batch),
-                                raw_points=int(raw_counts[part].sum()),
-                                cache=self.runtime.route_memo_stats(),
-                                path="native")
-                            submit(batch, order, sigma, beta)
+            for T, part, coalesced in self._plan_chunks(idxs, raw_counts,
+                                                        pad, chunk):
+                # part itself is the order: _drain_stage only
+                # enumerates it, so no per-chunk list conversion
+                # (reporter-lint HP003)
+                order = part
+                rows = padded_batch_rows(len(part), pad)
+                metrics.count("decode.chunks")
+                with obs_trace.span("matcher.chunk", chunk=ci,
+                                    traces=len(part), T=int(T)):
+                    ci += 1
+                    if not self.circuit.allow():
+                        metrics.count("matcher.circuit.fallback_chunks")
+                        self._submit_numpy_chunk(tb, part, params, pad,
+                                                 submit, sigma, beta)
+                        continue
+                    try:
+                        with metrics.timer("matcher.prep"):
+                            faults.failpoint("native.prep")
+                            batch = prepare_batch(
+                                self.runtime, tb.gather(part), params,
+                                int(T), pad_rows=rows, n_threads=workers,
+                                route_kernel=self._device_route_kernel(),
+                                route_circuit=self.circuit_route,
+                                # device-resident route tensor: the
+                                # decode stage pays the sync
+                                # (finalize_wire), overlapped with the
+                                # next chunk's prep
+                                defer_routes=True)
+                    except Exception as e:
+                        self.circuit.record_failure()
+                        metrics.count("matcher.circuit.native_errors")
+                        logger.warning(
+                            "native prep failed for a %d-trace chunk "
+                            "(%s); serving it via the numpy fallback",
+                            len(part), e)
+                        self._submit_numpy_chunk(tb, part, params, pad,
+                                                 submit, sigma, beta)
+                        continue
+                    self.circuit.record_success()
+                    # the chunk's wide event: occupancy vs the padded
+                    # (rows, T) grid, memo state, queue depth — one
+                    # call per CHUNK, not per trace
+                    profiler.chunk_event(
+                        bucket_T=int(T), K=params.max_candidates,
+                        traces=len(part), rows=int(batch.case.shape[0]),
+                        kept_points=kept_point_count(batch),
+                        raw_points=int(raw_counts[part].sum()),
+                        cache=self.runtime.route_memo_stats(),
+                        path="native", coalesced=coalesced)
+                    submit(batch, order, sigma, beta)
+
+    @staticmethod
+    def _plan_chunks(group, raw_counts, pad=None, chunk=None):
+        """The chunk plan for one params group: ``[(T, index array,
+        coalesced)]``, one entry per decode chunk, i.e. per prep call,
+        decode dispatch and assembly the group pays.
+
+        The per-bucket plan groups traces by ladder bucket of their RAW
+        length (kept length is only known after the native prep; raw is
+        an upper bound, so a jitter-heavy trace may decode in a larger
+        bucket — same decoded path, the SKIP tail is inert), lets
+        ``_split_bucket`` break a wasteful bucket into pow2 sub-buckets,
+        and cuts each (sub-)bucket into ``chunk``-row chunks.
+
+        Then merge: a group of at most ``PIPELINED_CHUNK`` traces (and
+        at most ``chunk``), which the per-bucket plan cuts into more
+        than one chunk, decodes as ONE chunk instead, at the smallest
+        power of two holding its longest raw trace (clipped to [ladder
+        floor, that trace's ladder bucket]; the bucket itself while
+        splitting is off, so the pressure ladder's coarse rung forms no
+        new shape) — but only where the merge's extra padded cells stay
+        within ``CHUNK_COST_CELLS`` for each chunk it saves, so one long
+        trace never pads a micro-batch of short ones to its length
+        (``decode.bucket.coalesced``). The cap is the one-device
+        pipelined chunk whatever the mesh or the pipeline mode scale
+        ``chunk`` to: a larger group (a streaming flush of more than
+        128 traces, a batch-pipeline or mesh chunk) keeps the per-bucket
+        plan and the lanes' overlap across its chunks. A merged chunk
+        is marked ``coalesced``: its padding is chosen, so it stays out
+        of the per-T waste the splitter consults."""
+        ladder, thresh = bucket_ladder()
+        buckets = np.asarray(ladder, dtype=np.int64)
+        raws = raw_counts[group]
+        Ts = buckets[np.minimum(
+            np.searchsorted(buckets, np.maximum(raws, 1)),
+            len(buckets) - 1)]
+        plan, splits = [], 0
+        for T0 in np.unique(Ts).tolist():
+            sub = SegmentMatcher._split_bucket(T0, group[Ts == T0],
+                                               raw_counts, pad, chunk)
+            splits += len(sub) > 1 or sub[0][0] != T0
+            plan += sub
+        step = chunk or len(group)
+        if len(plan) > 1 and len(group) <= min(step, PIPELINED_CHUNK):
+            top = int(Ts.max())
+            T = top if thresh >= 1.0 else min(
+                max(_next_pow2(int(raws.max())), int(ladder[0])), top)
+            # every (sub-)bucket of a group this small is one chunk
+            planned = sum(SegmentMatcher._padded_cells(len(b), pad, t,
+                                                       step)
+                          for t, b in plan)
+            merged = SegmentMatcher._padded_cells(len(group), pad, T, step)
+            if merged - planned <= CHUNK_COST_CELLS * (len(plan) - 1):
+                metrics.count("decode.bucket.coalesced")
+                return [(T, group, True)]
+        if splits:
+            metrics.count("decode.bucket.split", splits)
+        return [(T, bucket[lo:lo + step], False) for T, bucket in plan
+                for lo in range(0, len(bucket), step)]
 
     @staticmethod
     def _padded_cells(n: int, pad, T: int, chunk) -> int:
@@ -1108,10 +1163,13 @@ class SegmentMatcher:
 
     @staticmethod
     def _split_bucket(T: int, group, raw_counts, pad=None, chunk=None):
-        """The occupancy-driven adaptive splitter: ``[(sub_T, index
-        array)]`` for one ladder-bucket group, ``[(T, group)]`` when no
-        split pays. A split breaks a mixed-length group into per-pow2-
-        bucket sub-batches (per-trace smallest power of two >= raw
+        """The occupancy-driven adaptive splitter, the per-bucket plan's
+        second step: ``[(sub_T, index array)]`` for one ladder-bucket
+        group, ``[(T, group)]`` when no split pays (``_plan_chunks``
+        counts ``decode.bucket.split`` for each split it keeps; a small
+        group it merges into one chunk is not split). A split breaks
+        a mixed-length group into per-pow2-bucket sub-batches
+        (per-trace smallest power of two >= raw
         length, clipped to [ladder floor, T]) when the padding waste of
         decoding everything at T exceeds the ladder's threshold —
         consulting the RECORDED per-bucket waste from PR 8's wide
@@ -1163,7 +1221,6 @@ class SegmentMatcher:
             for s, c in zip(uniq.tolist(), counts.tolist())))
         if cells_split >= cells_unsplit:
             return [(T, group)]
-        metrics.count("decode.bucket.split")
         return [(int(s), group[subTs == s]) for s in uniq.tolist()]
 
     def _submit_numpy_chunk(self, tb: TraceBatch, part, params, pad,
@@ -1206,6 +1263,7 @@ class SegmentMatcher:
             beta = np.float32(params.beta)
             for lo in range(0, len(idxs), chunk):
                 part = idxs[lo:lo + chunk]
+                metrics.count("decode.chunks")
                 with obs_trace.span("matcher.chunk", chunk=ci,
                                     traces=len(part)):
                     ci += 1

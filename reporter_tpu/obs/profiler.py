@@ -97,8 +97,9 @@ _platform_cache: Optional[str] = None
 _queue_depths: Dict[str, int] = {}
 _total_kept = 0           # running occupancy totals (point slots)
 _total_cells = 0
-#: per-bucket-T running [kept, cells] — the recorded waste the adaptive
-#: bucket splitter acts on (SegmentMatcher._split_bucket)
+#: per-bucket-T running [kept, cells] of every chunk but the coalesced
+#: ones — the recorded waste the adaptive bucket splitter acts on
+#: (SegmentMatcher._split_bucket)
 _bucket_totals: Dict[int, list] = {}
 _compile_episodes = 0
 
@@ -284,7 +285,7 @@ def _reset_queue_depths() -> None:
 def chunk_event(bucket_T: int, K: int, traces: int, rows: int,
                 kept_points: int, raw_points: int,
                 cache: Optional[dict] = None,
-                path: str = "native") -> None:
+                path: str = "native", coalesced: bool = False) -> None:
     """Record one decode chunk's wide event (called once per chunk by
     the matcher's dispatch paths — a handful of scalars, one append).
 
@@ -292,7 +293,10 @@ def chunk_event(bucket_T: int, K: int, traces: int, rows: int,
     so ``rows * bucket_T`` is the point-slot grid the device actually
     decodes; ``kept_points`` is how many of those slots carry a real
     (kept) probe point. The waste ratio is what adaptive/variable
-    bucketing (FLASH) would reclaim.
+    bucketing (FLASH) would reclaim. A ``coalesced`` chunk (a whole
+    micro-batch merged into one chunk, padding chosen on purpose)
+    counts in the lifetime waste but not in its T's
+    :func:`bucket_waste`, which the splitter reads.
     """
     # the ONE occupancy formula, shared with the pinning tests (lazy
     # import: batchpad sits under matcher/, which imports this module)
@@ -315,6 +319,7 @@ def chunk_event(bucket_T: int, K: int, traces: int, rows: int,
         "occupancy": round(occupancy, 6),
         "padding_waste": round(waste, 6),
         "queue_depth": queue_depth(),
+        "coalesced": bool(coalesced),
     }
     if cache:
         event["cache"] = cache
@@ -330,11 +335,12 @@ def chunk_event(bucket_T: int, K: int, traces: int, rows: int,
         _events.extend((event,))
         _total_kept += int(kept_points)
         _total_cells += int(cells)
-        tot = _bucket_totals.get(int(bucket_T))
-        if tot is None:
-            tot = _bucket_totals[int(bucket_T)] = [0, 0]
-        tot[0] += int(kept_points)
-        tot[1] += int(cells)
+        if not coalesced:
+            tot = _bucket_totals.get(int(bucket_T))
+            if tot is None:
+                tot = _bucket_totals[int(bucket_T)] = [0, 0]
+            tot[0] += int(kept_points)
+            tot[1] += int(cells)
     metrics.count("profile.chunks")
     # per-bucket occupancy histogram: the ratio rides the fixed
     # log-bucket timer machinery (units are ratio, not seconds) so

@@ -309,15 +309,20 @@ class TestAdaptiveBucketing:
     def test_split_plan_projection(self):
         """A mixed 64-bucket group whose raw lengths project waste past
         the threshold splits into pow2 sub-buckets covering exactly the
-        original indices."""
+        original indices; the chunk plan of a group larger than one
+        chunk keeps the split and counts it."""
         profiler.reset()
         group = np.arange(8, dtype=np.int64)
         raws = np.array([10, 10, 17, 17, 30, 30, 60, 60], dtype=np.int64)
-        before = metrics.default.counter("decode.bucket.split")
         plan = SegmentMatcher._split_bucket(64, group, raws)
         assert [t for t, _ in plan] == [16, 32, 64]
         covered = np.concatenate([idx for _, idx in plan])
         assert sorted(covered.tolist()) == group.tolist()
+        before = metrics.default.counter("decode.bucket.split")
+        chunks = SegmentMatcher._plan_chunks(group, raws, None, 4)
+        assert [(t, p.tolist(), c) for t, p, c in chunks] == [
+            (16, [0, 1], False), (32, [2, 3, 4, 5], False),
+            (64, [6, 7], False)]
         assert metrics.default.counter("decode.bucket.split") == before + 1
 
     @staticmethod
@@ -384,6 +389,9 @@ class TestAdaptiveBucketing:
         inert, so a trace decoded at its pow2 sub-bucket yields the
         same report body as at the full ladder bucket."""
         monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
+        # a chunk of 8 rows: the 16-trace group is larger than one
+        # chunk, so it takes the per-bucket plan and may split
+        monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", "8")
         m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
         # mixed lengths in ONE 64-bucket: 8 traces at raw 18 (pads to
         # 64 fixed, 32 split) + 8 near-full at raw 60 — each sub-batch
@@ -406,6 +414,163 @@ class TestAdaptiveBucketing:
         assert fixed == adaptive
         assert metrics.default.counter("decode.bucket.split") > before
         assert waste_adaptive < waste_fixed
+
+    #: raw lengths of one params group, the REPORTER_TPU_BUCKETS spec,
+    #: the rows of a chunk, and the chunks planned: [(T, traces)], then
+    #: whether the group was merged into one chunk
+    PLANS = {
+        # a sparse-fleet micro-batch: ladder buckets 16, 64 and 256
+        "sparse": (np.linspace(12, 110, 15).round(), "", 128,
+                   [(128, 15)], True),
+        # a 1 Hz micro-batch: one 256 bucket no split pays for
+        "1hz": (np.linspace(70, 250, 13).round(), "", 128, [(256, 13)],
+                False),
+        # one 256 bucket the splitter would cut at 128
+        "1hz-split": ([70] * 8 + [250] * 5, "", 128, [(256, 13)], True),
+        # splitting off (the pressure ladder's coarse rung): merged at
+        # the longest trace's ladder bucket, no new shape
+        "sparse-coarse": (np.linspace(12, 110, 15).round(), "@off", 128,
+                          [(256, 15)], True),
+        # one long trace among short ones: merging would pad 127 rows
+        # of 16 to 1024, far more cells than the chunk it saves
+        "outlier": ([1000] + [16] * 127, "", 128,
+                    [(16, 127), (1024, 1)], False),
+        # larger than one chunk: the per-bucket plan, split and cut
+        "mixed-200": ([12] * 30 + [20] * 20 + [40] * 20 + [200] * 130, "",
+                      128,
+                      [(16, 30), (32, 20), (64, 20), (256, 128), (256, 2)],
+                      False),
+        # the four-chip mesh's (or an unpipelined host's) 512-row chunk:
+        # the merge still takes at most 128 traces
+        "mesh-512": ([12] * 100 + [40] * 100 + [100] * 312, "", 512,
+                     [(16, 100), (64, 100), (128, 312)], False),
+        "mesh-128": (np.linspace(12, 110, 128).round(), "", 512,
+                     [(128, 128)], True),
+        "mesh-129": (np.linspace(12, 110, 129).round(), "", 512,
+                     [(16, 6), (64, 63), (128, 60)], False),
+    }
+
+    @pytest.mark.parametrize("case", sorted(PLANS))
+    def test_chunk_plan(self, case, monkeypatch):
+        raws, spec, chunk, want, merged = self.PLANS[case]
+        monkeypatch.setenv("REPORTER_TPU_BUCKETS", spec)
+        profiler.reset()
+        raws = np.asarray(raws, dtype=np.int64)
+        group = np.arange(len(raws), dtype=np.int64)
+        before = metrics.default.counter("decode.bucket.coalesced")
+        plan = SegmentMatcher._plan_chunks(group, raws, None, chunk)
+        assert [(T, len(part)) for T, part, _ in plan] == want
+        assert all(c == merged for _, _, c in plan)
+        covered = np.concatenate([part for _, part, _ in plan])
+        assert sorted(covered.tolist()) == group.tolist()
+        assert metrics.default.counter("decode.bucket.coalesced") \
+            == before + merged
+
+    @pytest.mark.parametrize("case", ["sparse", "1hz", "1hz-split",
+                                      "mesh-128"])
+    def test_coalesced_shape_is_warmed(self, case, monkeypatch):
+        """The benchmark's warm-up builds every shape a merge forms:
+        pow2 rows up to the dispatcher's cap, pow2 T from the ladder's
+        floor to the longest trace's bucket."""
+        from reporter_tpu.matcher.batchpad import padded_batch_rows
+        monkeypatch.syspath_prepend(os.path.join(REPO, "benchmark"))
+        import serve_http
+        warmed = set()
+
+        class Service:  # records the (rows, T) each warm batch forms
+            class dispatcher:
+                max_batch = 256
+
+            @staticmethod
+            def report_many(reqs):
+                warmed.add((padded_batch_rows(len(reqs), None),
+                            bucket_ladder()[0][0]))
+                return [{}] * len(reqs)
+
+        serve_http.warm_shapes(Service, [{}] * 256, 256)
+        raws, _spec, _chunk, _want, _merged = self.PLANS[case]
+        raws = np.asarray(raws, dtype=np.int64)
+        profiler.reset()
+        plan = SegmentMatcher._plan_chunks(
+            np.arange(len(raws), dtype=np.int64), raws, None, 128)
+        for T, part, _ in plan:
+            assert (padded_batch_rows(len(part), None), T) in warmed
+
+    @staticmethod
+    def _cut(reqs, lengths):
+        for r, n in zip(reqs, lengths):
+            r["trace"] = r["trace"][:n]
+        return reqs
+
+    @pytest.mark.skipif(
+        not __import__("reporter_tpu.native", fromlist=["available"])
+        .available(), reason="the chunk plan lives in the native dispatch")
+    @pytest.mark.parametrize("lengths,chunk,chunks,merged", [
+        ((12, 12, 40, 40, 60, 60), "", 1, 1),  # three buckets, merged
+        ((60,) * 6, "", 1, 0),                 # one bucket, one chunk
+        ((12, 40, 60, 60) * 4, "8", 3, 0),     # over a chunk of 8 rows
+    ])
+    def test_chunk_counters(self, city, monkeypatch, lengths, chunk,
+                            chunks, merged):
+        """``decode.chunks`` counts every chunk planned,
+        ``decode.bucket.coalesced`` every merge; a merged chunk's
+        padding stays out of the per-T waste the splitter reads."""
+        monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
+        monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", chunk)
+        m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
+        reqs = self._cut(_mixed_reqs(city, n=len(lengths), seed=31,
+                                     max_edges=14), lengths)
+        profiler.reset()
+        c0 = metrics.default.counter("decode.chunks")
+        m0 = metrics.default.counter("decode.bucket.coalesced")
+        m.match_many(reqs)
+        assert metrics.default.counter("decode.chunks") - c0 == chunks
+        assert metrics.default.counter("decode.bucket.coalesced") - m0 \
+            == merged
+        assert profiler.padding_waste() is not None
+        recorded = [profiler.bucket_waste(T) for T in (16, 32, 64)]
+        assert (recorded == [None] * 3) == bool(merged)
+        profiler.reset()
+
+    @pytest.mark.skipif(
+        not __import__("reporter_tpu.native", fromlist=["available"])
+        .available(), reason="the chunk plan lives in the native dispatch")
+    def test_coalesced_results_byte_identical(self, city, monkeypatch):
+        """Merging changes shapes, never bytes: the same 16 mixed traces
+        as one merged chunk and as the per-bucket plan's chunks (a chunk
+        of 8 rows) give the same report bodies."""
+        monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
+        m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
+        reqs = self._cut(_mixed_reqs(city, n=16, seed=31, max_edges=14),
+                         (12, 18, 30, 60) * 4)
+        before = metrics.default.counter("decode.bucket.coalesced")
+        monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", "8")
+        per_bucket = _bodies(m.match_many(reqs))
+        assert metrics.default.counter("decode.bucket.coalesced") == before
+        monkeypatch.delenv("REPORTER_TPU_DECODE_CHUNK")
+        merged = _bodies(m.match_many(reqs))
+        assert metrics.default.counter("decode.bucket.coalesced") \
+            == before + 1
+        assert merged == per_bucket
+        profiler.reset()
+
+    def test_chunks_per_batch_reader(self, monkeypatch):
+        """The benchmark's reader: chunks a dispatcher batch; nothing
+        from a program without the counter."""
+        import importlib.util
+        monkeypatch.syspath_prepend(os.path.join(REPO, "benchmark"))
+        from readings import Readings
+        name = "decode_chunks_per_batch.closed"
+        spec = importlib.util.spec_from_file_location(
+            "metric_" + name.replace(".", "_"),
+            os.path.join(REPO, "benchmark", "metrics", name + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        got = mod.read(Readings({"decode.chunks": 36, "dispatch.batches": 10},
+                                {}, {}, None, "cpu"))
+        assert got == pytest.approx(3.6)
+        assert mod.read(Readings({"dispatch.batches": 10}, {}, {}, None,
+                                 "cpu")) is None
 
 
 class TestMultichipGate:
