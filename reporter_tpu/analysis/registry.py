@@ -185,6 +185,14 @@ METRICS: Dict[str, str] = {
     "replay.quarantined": "dead-letter entries moved to .quarantine",
     # pipeline
     "pipeline.gather": "backfill stage 1 (timer)",
+    "pipeline.gather.lines": "stage 1 probe lines read",
+    "pipeline.gather.probes": "stage 1 probes kept and sharded",
+    "pipeline.gather.uuid_hashes": "stage 1 shard hashes, one per distinct "
+                                   "uuid of a part",
+    "pipeline.gather.hour_epochs": "stage 1 hour epochs, one per distinct "
+                                   "hour of a part",
+    "pipeline.gather_failures": "part files lost to a parse error or a "
+                                "dead gather worker",
     "pipeline.match": "backfill stage 2 (timer)",
     "pipeline.report": "backfill stage 3 (timer)",
     "pipeline.parse": "stage 2 shard read, argsort and window cut (timer)",

@@ -33,6 +33,7 @@ counters), so the CLI and any other caller time the same code.
 from __future__ import annotations
 
 import argparse
+import calendar
 import functools
 import glob
 import gzip
@@ -56,6 +57,7 @@ from typing import Callable, Iterable, List
 
 from ..core.osmlr import INVALID_SEGMENT_ID, tile_index, tile_level
 from ..core.types import Segment
+from ..parallel.multihost import host_uuid_filter
 from ..utils import metrics
 
 logger = logging.getLogger("reporter_tpu.pipeline")
@@ -68,25 +70,42 @@ MAX_ACCURACY_M = 1000  # reference: simple_reporter.py:112
 # --------------------------------------------------------------------------
 
 def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
-                     bbox: List[float], dest_dir: str) -> int:
-    """Parse one downloaded part file into uuid-sharded trace files."""
+                     bbox: List[float], dest_dir: str) -> tuple:
+    """Parse one downloaded part file into uuid-sharded trace files.
+
+    Returns ``(lines, probes, uuid_hashes, hour_epochs)``: lines read,
+    probes kept, distinct uuids hashed and hour epochs computed. A part
+    holds thousands of lines per uuid and per hour, so a uuid's shard
+    (and its host verdict) and an hour's epoch are computed once and
+    looked up after that."""
     # multi-host backfill: when REPORTER_TPU_NUM_PROCESSES/PROCESS_ID are
     # set, each host keeps only its share of the uuid space, so N hosts
     # pointed at the same --src partition the work instead of repeating it
     # (the reference splits days across instances by hand,
     # load-historical-data/README.md)
-    from ..parallel import host_uuid_filter
     uuid_filter = host_uuid_filter()
     fast_time = time_pattern == "%Y-%m-%d %H:%M:%S"
     opener = gzip.open if path.endswith(".gz") else open
     shards: dict[str, list[str]] = {}
-    count = 0
+    # str(uuid) -> its shard's row list, or None where another host owns it
+    rows_of: dict[str, list | None] = {}
+    hour_epoch: dict[str, int] = {}  # "YYYY-MM-DD HH" -> epoch of the hour
+    lines = count = 0
     with opener(path, "rt") as f:
         for line in f:
+            lines += 1
             try:
                 uuid, tm, lat, lon, acc = valuer(line)
-                if uuid_filter is not None and \
-                        not uuid_filter(str(uuid)):
+                key = str(uuid)
+                try:
+                    rows = rows_of[key]
+                except KeyError:
+                    rows = None
+                    if uuid_filter is None or uuid_filter(key):
+                        shard = hashlib.sha1(key.encode()).hexdigest()[:3]
+                        rows = shards.setdefault(shard, [])
+                    rows_of[key] = rows
+                if rows is None:
                     continue
                 lat = float(lat)
                 lon = float(lon)
@@ -95,49 +114,60 @@ def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
                     continue
                 if isinstance(tm, str) and not tm.isdigit():
                     if fast_time:
-                        st = time.struct_time((
-                            int(tm[0:4]), int(tm[5:7]), int(tm[8:10]),
-                            int(tm[11:13]), int(tm[14:16]), int(tm[17:19]),
-                            0, 0, 0))
+                        # timegm is linear in minutes and seconds
+                        hour = tm[:13]
+                        epoch = hour_epoch.get(hour)
+                        if epoch is None:
+                            epoch = hour_epoch[hour] = calendar.timegm((
+                                int(tm[0:4]), int(tm[5:7]), int(tm[8:10]),
+                                int(tm[11:13]), 0, 0))
+                        epoch += int(tm[14:16]) * 60 + int(tm[17:19])
                     else:
-                        st = time.strptime(tm, time_pattern)
-                    import calendar
-                    epoch = calendar.timegm(st)
+                        epoch = calendar.timegm(
+                            time.strptime(tm, time_pattern))
                 else:
                     epoch = int(tm)
                 acc = min(int(math.ceil(float(acc))), MAX_ACCURACY_M)
             except Exception:
                 continue
-            shard = hashlib.sha1(str(uuid).encode()).hexdigest()[:3]
-            shards.setdefault(shard, []).append(
-                f"{uuid},{epoch},{lat},{lon},{acc}\n")
+            rows.append(f"{uuid},{epoch},{lat},{lon},{acc}\n")
             count += 1
     # one shard file per worker process (suffix = pid): concurrent gather
     # workers never share a file, so no interleaved/torn rows — stage 2
     # walks every file in the directory regardless of suffix
     pid = os.getpid()
     for shard, rows in shards.items():
-        with open(os.path.join(dest_dir, f"{shard}.{pid}"), "a") as f:
-            f.write("".join(rows))
-    return count
+        if rows:  # a shard whose uuids kept no line gets no empty file
+            with open(os.path.join(dest_dir, f"{shard}.{pid}"), "a") as f:
+                f.write("".join(rows))
+    return lines, count, len(rows_of), len(hour_epoch)
 
 
 def _gather_worker(paths: List[str], valuer_src: str, time_pattern: str,
-                   bbox: List[float], dest_dir: str) -> None:
+                   bbox: List[float], dest_dir: str, results) -> None:
+    """Parse ``paths`` in turn, sending each part's tally (or None where
+    the part raised) down the ``results`` pipe."""
     valuer = eval(valuer_src)  # user-supplied lambda, like the reference
     for path in paths:
         try:
-            n = _parse_part_file(path, valuer, time_pattern, bbox, dest_dir)
-            logger.info("Gathered %d probes from %s", n, path)
+            tally = _parse_part_file(path, valuer, time_pattern, bbox,
+                                     dest_dir)
+            logger.info("Gathered %d probes from %s", tally[1], path)
         except Exception as e:
+            tally = None
             logger.error("%s was not processed: %s", path, e)
+        results.send(tally)
 
 
 def gather_traces(src: str, key_regex: str, valuer_src: str,
                   time_pattern: str, bbox: List[float],
                   concurrency: int) -> str:
     """Stage 1 driver. ``src`` is a local directory of part files, or an
-    ``s3://bucket/prefix`` URL (requires boto3 + credentials)."""
+    ``s3://bucket/prefix`` URL (requires boto3 + credentials).
+
+    Counts ``pipeline.gather.lines``, ``.probes``, ``.uuid_hashes`` and
+    ``.hour_epochs`` over the parts, and ``pipeline.gather_failures`` for
+    each part that raised or whose worker died before reporting it."""
     with metrics.timer("pipeline.gather"):
         dest_dir = tempfile.mkdtemp(prefix="traces_", dir=".")
         if src.startswith("s3://"):
@@ -150,17 +180,34 @@ def gather_traces(src: str, key_regex: str, valuer_src: str,
                 if os.path.isfile(p) and rx.match(os.path.relpath(p, src)))
         logger.info("Gathering %d part files into %s", len(paths), dest_dir)
         chunks = [paths[i::concurrency] for i in range(concurrency)]
-        procs = []
+        workers = []
         for chunk in chunks:
             if not chunk:
                 continue
+            recv, send = _MP.Pipe(duplex=False)
             p = _MP.Process(
                 target=_gather_worker,
-                args=(chunk, valuer_src, time_pattern, bbox, dest_dir))
+                args=(chunk, valuer_src, time_pattern, bbox, dest_dir, send))
             p.start()
-            procs.append(p)
-        for p in procs:
+            send.close()  # the child's end: recv sees EOF once it exits
+            workers.append((p, recv))
+        tallies = []
+        for p, recv in workers:
+            with recv:
+                while True:
+                    try:
+                        tallies.append(recv.recv())
+                    except EOFError:
+                        break
             p.join()
+        done = [t for t in tallies if t is not None]
+        lines, probes, hashes, hours = (
+            [sum(col) for col in zip(*done)] or [0, 0, 0, 0])
+        metrics.count("pipeline.gather.lines", lines)
+        metrics.count("pipeline.gather.probes", probes)
+        metrics.count("pipeline.gather.uuid_hashes", hashes)
+        metrics.count("pipeline.gather.hour_epochs", hours)
+        metrics.count("pipeline.gather_failures", len(paths) - len(done))
     return dest_dir
 
 

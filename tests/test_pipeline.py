@@ -380,3 +380,201 @@ def test_warm_builds_every_shape_match_many_forms(city, monkeypatch):
     for k in (1, 3, 7, 12):
         assert all(r is not None for r in m.match_many(traces[:k]))
     assert len(compiles) == n
+
+
+def _parse_part_file_per_line(path, valuer, time_pattern, bbox, dest_dir):
+    """The gather's parse as it was before its memos: a shard hash, a
+    host verdict and a calendar epoch for every line. The oracle for
+    ``_parse_part_file``'s shard files."""
+    import calendar
+    import hashlib
+    import math
+    import time
+    from reporter_tpu.parallel import host_uuid_filter
+    uuid_filter = host_uuid_filter()
+    fast_time = time_pattern == "%Y-%m-%d %H:%M:%S"
+    opener = gzip.open if path.endswith(".gz") else open
+    shards = {}
+    with opener(path, "rt") as f:
+        for line in f:
+            try:
+                uuid, tm, lat, lon, acc = valuer(line)
+                if uuid_filter is not None and not uuid_filter(str(uuid)):
+                    continue
+                lat = float(lat)
+                lon = float(lon)
+                if lat < bbox[0] or lat > bbox[2] or \
+                        lon < bbox[1] or lon > bbox[3]:
+                    continue
+                if isinstance(tm, str) and not tm.isdigit():
+                    if fast_time:
+                        st = time.struct_time((
+                            int(tm[0:4]), int(tm[5:7]), int(tm[8:10]),
+                            int(tm[11:13]), int(tm[14:16]), int(tm[17:19]),
+                            0, 0, 0))
+                    else:
+                        st = time.strptime(tm, time_pattern)
+                    epoch = calendar.timegm(st)
+                else:
+                    epoch = int(tm)
+                acc = min(int(math.ceil(float(acc))), 1000)
+            except Exception:
+                continue
+            shard = hashlib.sha1(str(uuid).encode()).hexdigest()[:3]
+            shards.setdefault(shard, []).append(
+                f"{uuid},{epoch},{lat},{lon},{acc}\n")
+    pid = os.getpid()
+    for shard, rows in shards.items():
+        with open(os.path.join(dest_dir, f"{shard}.{pid}"), "a") as f:
+            f.write("".join(rows))
+
+
+def _gather_cases_part(path):
+    """A part of 40 vehicles whose lines cross an hour and a day boundary
+    in both time formats, with malformed lines, probes outside the bbox,
+    numeric epochs, and fractional and over-1,000 m accuracies."""
+    import datetime
+    rng = np.random.default_rng(7)
+    t0 = datetime.datetime(2023, 12, 31, 22, 58, 30)
+    lines = ["", "garbage", "a|b|c", "|".join(["x"] * 11),
+             "2023-12-31 23:00:00|veh-x|x|x|x|abc|x|x|x|40.5|-74.0",
+             "2023-13-31 23:00:00|veh-x|x|x|x|5|x|x|x|40.5|-74.0",
+             "2023-12-31 23:0|veh-x|x|x|x|5|x|x|x|40.5|-74.0"]
+    for i in range(4000):
+        t = t0 + datetime.timedelta(seconds=int(i * 1.9))
+        cols = ["x"] * 11
+        cols[1] = f"veh-{i % 40}"
+        kind = (i // 40) % 5  # each vehicle in every time format
+        cols[0] = (str(int(t.replace(tzinfo=datetime.timezone.utc)
+                           .timestamp())) if kind == 1
+                   else t.strftime("%d/%m/%Y %H:%M:%S") if kind == 2
+                   else t.strftime("%Y-%m-%d %H:%M:%S"))
+        cols[5] = str(rng.choice(["3", "4.2", "999.01", "1000", "1500.5"]))
+        cols[9] = str(40.0 + rng.uniform(-0.2, 1.2))  # some beyond 41.0
+        cols[10] = str(-74.5 + rng.uniform(0.0, 1.0))
+        lines.append("|".join(cols))
+    with gzip.open(path, "wt") as f:
+        f.write("\n".join(lines))
+    return lines
+
+
+class TestGather:
+    """Stage 1: a uuid's shard and an hour's epoch are computed once per
+    part; the shard files are the per-line parse's, byte for byte."""
+
+    @pytest.mark.parametrize("hosts", ["one", "two"])
+    @pytest.mark.parametrize("time_pattern",
+                             ["%Y-%m-%d %H:%M:%S", "%d/%m/%Y %H:%M:%S"])
+    def test_shard_files_match_the_per_line_parse(self, tmp_path,
+                                                  monkeypatch, hosts,
+                                                  time_pattern):
+        import functools
+        from reporter_tpu.pipeline.simple_reporter import (
+            DEFAULT_VALUER, _parse_part_file)
+        if hosts == "two":
+            monkeypatch.setenv("REPORTER_TPU_NUM_PROCESSES", "2")
+            monkeypatch.setenv("REPORTER_TPU_PROCESS_ID", "1")
+        else:
+            monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+            monkeypatch.delenv("REPORTER_TPU_PROCESS_ID", raising=False)
+        part = str(tmp_path / "part-0.gz")
+        lines = _gather_cases_part(part)
+        valuer = eval(DEFAULT_VALUER, {"functools": functools})
+        bbox = [39.9, -74.5, 41.0, -73.5]
+        old, new = tmp_path / "old", tmp_path / "new"
+        old.mkdir()
+        new.mkdir()
+        _parse_part_file_per_line(part, valuer, time_pattern, bbox, str(old))
+        n_lines, probes, hashes, hours = _parse_part_file(
+            part, valuer, time_pattern, bbox, str(new))
+        want = {f: (old / f).read_bytes() for f in os.listdir(old)}
+        got = {f: (new / f).read_bytes() for f in os.listdir(new)}
+        assert want and got == want
+        assert n_lines == len(lines)
+        assert probes == sum(b.count(b"\n") for b in want.values())
+        kept = {b.split(b",")[0] for v in want.values()
+                for b in v.splitlines()}
+        assert len(kept) == 40 if hosts == "one" else 0 < len(kept) < 40
+        assert hashes == 42  # the 40 vehicles, veh-x and the all-x line
+        # 22:58:30 on 31 December to 01:05:08 on 1 January
+        assert hours == (4 if time_pattern.startswith("%Y") else 0)
+
+    def test_a_spawned_worker_imports_no_jax(self, tmp_path):
+        """A gather worker reaches the multi-host uuid filter without
+        importing JAX, which each spawned worker would otherwise pay for
+        at start-up; the package's JAX-backed names still resolve."""
+        import subprocess
+        import sys
+        from reporter_tpu.parallel import host_uuid_filter, make_mesh
+        assert callable(make_mesh) and callable(host_uuid_filter)
+        part = str(tmp_path / "part-0.gz")
+        _gather_cases_part(part)
+        dest = tmp_path / "traces"
+        dest.mkdir()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import multiprocessing, sys\n"
+            "from reporter_tpu.pipeline import simple_reporter as sr\n"
+            "recv, send = multiprocessing.Pipe(duplex=False)\n"
+            f"sr._gather_worker([{part!r}], sr.DEFAULT_VALUER, "
+            f"'%Y-%m-%d %H:%M:%S', [-90.0, -180.0, 90.0, 180.0], "
+            f"{str(dest)!r}, send)\n"
+            "tally = recv.recv()\n"
+            "assert tally is not None and tally[1] > 0, tally\n"
+            "assert 'jax' not in sys.modules\n"
+            "print('ok')\n")
+        env = dict(os.environ, PYTHONPATH=repo,
+                   REPORTER_TPU_NUM_PROCESSES="2",
+                   REPORTER_TPU_PROCESS_ID="1")
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-800:]
+        assert proc.stdout.strip() == "ok"
+        assert os.listdir(dest)
+
+    def test_counters_and_a_lost_part(self, day_archive, tmp_path,
+                                      monkeypatch):
+        """On the two-part day archive every line is kept and each uuid
+        hashed at most once a part; a third part that is not gzip counts
+        one failure, and the other two are still gathered."""
+        import shutil
+        from reporter_tpu.utils import metrics
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        uuids, n_lines = set(), 0
+        parts = sorted(os.listdir(day_archive))
+        for part in parts:
+            with gzip.open(os.path.join(day_archive, part), "rt") as f:
+                body = f.read().splitlines()
+            n_lines += len(body)
+            uuids |= {ln.split("|")[1] for ln in body}
+
+        def gather(src):
+            before = metrics.default.snapshot()["counters"]
+            out = gather_traces(src, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
+                                [-90.0, -180.0, 90.0, 180.0], concurrency=2)
+            after = metrics.default.snapshot()["counters"]
+            rows = []
+            for r, _d, fs in os.walk(out):
+                for f in fs:
+                    with open(os.path.join(r, f)) as fh:
+                        rows += fh.readlines()
+            rows.sort()
+            return {k: v - before.get(k, 0) for k, v in after.items()
+                    if k.startswith("pipeline.gather")}, rows
+
+        c, rows = gather(day_archive)
+        assert c["pipeline.gather.probes"] == n_lines == len(rows)
+        assert c["pipeline.gather.lines"] >= c["pipeline.gather.probes"]
+        assert 0 < c["pipeline.gather.uuid_hashes"] \
+            <= len(uuids) * len(parts)
+        assert c["pipeline.gather.hour_epochs"] == 0  # numeric epochs
+        assert c["pipeline.gather_failures"] == 0
+
+        src = tmp_path / "with-bad-part"
+        shutil.copytree(day_archive, src)
+        (src / "part-2.gz").write_bytes(b"not a gzip stream\n" * 8)
+        c3, rows3 = gather(str(src))
+        assert c3["pipeline.gather_failures"] == 1
+        assert c3["pipeline.gather.probes"] == n_lines
+        assert rows3 == rows
