@@ -226,107 +226,6 @@ def _time_report_writers(matches, reqs, repeats=3):
     return out
 
 
-def _bucketing_leg(city, matcher, reqs_pool):
-    """The adaptive-bucket before/after pair (ISSUE 13): one MIXED-
-    length batch — raw lengths straddling the fixed 16/64/256 ladder
-    rungs — decoded twice over the same traces: once with the splitter
-    off (``REPORTER_TPU_BUCKETS=@off``, the fixed-ladder status quo)
-    and once with the default occupancy-driven splitter. Records the
-    profiler's whole-leg ``padding_waste`` for each, the split count,
-    and the adaptive leg's recompile-storm count (must be 0: every
-    sub-bucket is a NEW shape = one episode each, never a second
-    compile of a known shape). A true same-box pair, gated by
-    ``perf_gate --max-padding-waste``. An explicit ``skipped`` record
-    when the native runtime is absent (the splitter lives in the
-    native dispatch path) — the gate passes an explicit skip with a
-    note, vs hard-failing a silently missing block."""
-    if matcher.runtime is None:
-        return {"skipped": "no native runtime: the adaptive splitter "
-                "lives in the native dispatch path"}
-    from reporter_tpu.core.tracebatch import TraceBatch
-    from reporter_tpu.obs import profiler
-    from reporter_tpu.synth import generate_trace
-    from reporter_tpu.utils import metrics
-
-    # mixed raw lengths sitting ON pow2 rungs the fixed 16/64/256/1024
-    # ladder mostly lacks (32 and 128 pad 2x under it), subsampled 2x
-    # so point spacing clears the interpolation distance (kept ~= raw —
-    # the waste measured is BUCKET pad, not jitter drops); pow2 group
-    # counts so row padding stays exact in both legs
-    plan = ((16, 32), (32, 32), (64, 16), (128, 8))
-    rng = np.random.default_rng(13)
-    mixed = []
-    for want_len, count in plan:
-        got, attempts = 0, 0
-        while got < count:
-            attempts += 1
-            if attempts > 500 * count:
-                raise RuntimeError(
-                    f"could not build {count} mixed traces of {want_len}")
-            tr = generate_trace(city, f"mix{want_len}-{got}", rng,
-                                noise_m=4.0,
-                                min_route_edges=max(4, want_len // 5),
-                                max_route_edges=90)
-            if tr is None or len(tr.points) < 2 * want_len:
-                continue
-            req = tr.request_json()
-            req["trace"] = tr.points[:2 * want_len:2]
-            req["match_options"] = reqs_pool[0]["match_options"]
-            mixed.append(req)
-            got += 1
-    tb = TraceBatch.from_requests(mixed)
-    tb.options = mixed[0]["match_options"]
-
-    saved = os.environ.get("REPORTER_TPU_BUCKETS")
-    saved_chunk = os.environ.get("REPORTER_TPU_DECODE_CHUNK")
-    # chunks of 64 rows: the batch is larger than one chunk, so the
-    # matcher takes the per-bucket plan (a batch that fits one chunk
-    # may be merged into one chunk, and then is never split), and each
-    # bucket fits one
-    os.environ["REPORTER_TPU_DECODE_CHUNK"] = "64"
-
-    def _leg(spec):
-        if spec is None:
-            os.environ.pop("REPORTER_TPU_BUCKETS", None)
-        else:
-            os.environ["REPORTER_TPU_BUCKETS"] = spec
-        profiler.reset()
-        splits0 = metrics.default.counter("decode.bucket.split")
-        # two passes: the second exercises the recorded-waste decision
-        # path (the first may decide from the raw-length projection)
-        matcher.match_many(tb)
-        matcher.match_many(tb)
-        prof = profiler.snapshot(n_events=0)
-        return {
-            "padding_waste": prof["totals"]["padding_waste"],
-            "splits": metrics.default.counter("decode.bucket.split")
-            - splits0,
-            "recompiles": sum(max(0, s["compiles"] - 1)
-                              for s in prof["shapes"]),
-        }
-
-    try:
-        fixed = _leg("@off")
-        adaptive = _leg(None)
-    finally:
-        if saved is None:
-            os.environ.pop("REPORTER_TPU_BUCKETS", None)
-        else:
-            os.environ["REPORTER_TPU_BUCKETS"] = saved
-        if saved_chunk is None:
-            os.environ.pop("REPORTER_TPU_DECODE_CHUNK", None)
-        else:
-            os.environ["REPORTER_TPU_DECODE_CHUNK"] = saved_chunk
-        profiler.reset()
-    return {
-        "n_traces": len(mixed),
-        "fixed_waste": fixed["padding_waste"],
-        "adaptive_waste": adaptive["padding_waste"],
-        "splits": adaptive["splits"],
-        "recompiles": adaptive["recompiles"],
-    }
-
-
 def _query_leg(n_segments: int = 256, repeats: int = 3):
     """The serving-tier batched-query pair (ISSUE 14): ONE
     ``query_many(256)`` sweep vs 256 single ``query_segment`` calls
@@ -614,15 +513,6 @@ def main():
         "padding_waste": prof["totals"]["padding_waste"],
     }
 
-    # -- adaptive-bucket before/after pair (ISSUE 13) ---------------------
-    # fixed-ladder vs occupancy-driven splitting over one mixed-length
-    # batch; runs AFTER compile_field so its profiler resets can't eat
-    # the main run's telemetry
-    try:
-        bucketing_field = _bucketing_leg(city, matcher, reqs)
-    except Exception as e:  # record the failure, keep the artifact
-        bucketing_field = {"error": str(e)[:200]}
-
     # -- serving-tier batched-query pair (ISSUE 14) -----------------------
     # query_many(256) vs 256 singles over one synthetic store; parity
     # asserted inside the leg, ratio gated by perf_gate
@@ -679,7 +569,6 @@ def main():
         "baseline": {"traces_per_sec": round(baseline_tps, 1),
                      "n_traces": n_base, "repeats": base_repeats},
         "compile": compile_field,
-        "bucketing": bucketing_field,
         "query": query_field,
         "routes": routes_field,
         "device": {"platform": platform,

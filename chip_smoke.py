@@ -194,9 +194,10 @@ def post_all(port: int, bodies: list, clients: int) -> list:
 
 
 def shape_pools(traces: list) -> dict:
-    """Trace indices by the decode bucket each can land in: its ladder
-    bucket, or the power-of-two sub-bucket the matcher splits a
-    wasteful group into (``SegmentMatcher._split_bucket``)."""
+    """Trace indices by their class in the chunk plan
+    (``batchpad.plan_chunks``): the power of two of the trace's length,
+    clipped to [ladder floor, its ladder bucket]. A chunk decodes at the
+    largest class among its traces."""
     from reporter_tpu.matcher.batchpad import bucket_ladder
     ladder = bucket_ladder()[0]
     pools = {}
@@ -209,21 +210,20 @@ def shape_pools(traces: list) -> dict:
 
 
 def warm_shapes(service, reqs: list, pools: dict) -> int:
-    """Every (rows, T) shape the measured pass can meet: per bucket, one
+    """Every (rows, T) shape the measured pass can meet: per class, one
     ``report_many`` batch (the service's in-process entry point, one
     dispatcher batch) of each power-of-two size up to the dispatcher's
-    cap. A batch of up to 128 traces may be merged into one chunk at
-    its longest trace's bucket, whatever the size of that bucket's pool,
-    so every bucket is warmed at every size. The ladder is pinned to
-    the one bucket with splitting off meanwhile, so a batch decodes at
-    that bucket whatever its traces (a longer trace is cut to it).
-    Returns the batches sent."""
+    cap. A chunk decodes at the largest class among its traces,
+    whatever the size of that class's pool, so every class is warmed at
+    every size. The ladder is pinned to the one class meanwhile, so a
+    batch decodes at it whatever its traces (a longer trace is cut to
+    it). Returns the batches sent."""
     from reporter_tpu.matcher.batchpad import ENV_BUCKETS
     sent = 0
     ladder = os.environ.get(ENV_BUCKETS)
     try:
         for T in sorted(pools):
-            os.environ[ENV_BUCKETS] = f"{T}@off"
+            os.environ[ENV_BUCKETS] = str(T)
             r = 1
             while r <= min(service.dispatcher.max_batch, len(reqs)):
                 got = service.report_many(reqs[:r])

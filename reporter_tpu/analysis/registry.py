@@ -47,7 +47,7 @@ ENV_KNOBS: Dict[str, str] = {
     "REPORTER_TPU_DECODE_SHARD": "decode mesh: auto|on|off",
     "REPORTER_TPU_DEVICE_SLICE": "this process's local-device subset",
     "REPORTER_TPU_SEQ_SHARDS": "sequence-parallel time-axis shards",
-    "REPORTER_TPU_BUCKETS": "bucket ladder [+ @waste split threshold]",
+    "REPORTER_TPU_BUCKETS": "decode length-bucket ladder",
     "REPORTER_TPU_COORDINATOR": "jax.distributed rendezvous address",
     "REPORTER_TPU_NUM_PROCESSES": "jax.distributed process count",
     "REPORTER_TPU_PROCESS_ID": "jax.distributed process id",
@@ -253,9 +253,6 @@ METRICS: Dict[str, str] = {
     "decode.dispatch.steady": "steady-state dispatch wall (timer)",
     "decode.occupancy.*": "per-bucket occupancy ratio histograms",
     "decode.shard.*": "mesh-path decode chunks + rows fanned across it",
-    "decode.bucket.split": "chunks split into finer pow2 sub-buckets",
-    "decode.bucket.coalesced": "groups merged into one chunk where the "
-                               "per-bucket plan made more",
     "decode.chunks": "decode chunks planned (one prep, dispatch, assembly)",
     "decode.shadow.chunks": "chunks shadow-decoded via the numpy oracle",
     "decode.shadow.sampled": "traces shadow-decoded via the numpy oracle",

@@ -19,8 +19,8 @@ Per chunk the kernel:
    bounded search at a larger bound settles a superset of the same exact
    distances, and the assembly re-applies each step's own bound;
 3. assembles the route tensor over every prepared row, filler rows
-   included (so its compiled shape follows the decode bucket, not the
-   chunk's trace count), and writes it into the prep dict's ``route_m``
+   included (so its compiled shape follows the chunk's planned rows
+   and T, not its trace count), and writes it into the prep dict's ``route_m``
    time rows ``[:T-1]`` (row T-1 is the dead trailing step the native
    tail fill already covered), folding the device finite max into
    ``max_finite`` so the f16 wire decision sees device-written values.
@@ -221,9 +221,9 @@ class DeviceRouteKernel:
         ``prepare_batch(..., skip_routes=True)`` result dict, updating
         ``out['max_finite']``. Every row is assembled, filler rows too
         (all dead: the same UNREACHABLE bytes the native tail fill
-        writes), so the compiled shape is the decode bucket's. Raises on
-        non-convergence or a budget-exceeding chunk (the caller's
-        circuit fallback re-runs the native prep with routes).
+        writes), so the compiled shape is the chunk's planned one.
+        Raises on non-convergence or a budget-exceeding chunk (the
+        caller's circuit fallback re-runs the native prep with routes).
 
         ``defer=True`` skips the device→host sync: the assembly is
         dispatched and a :class:`DeferredRoutes` handle returned (None

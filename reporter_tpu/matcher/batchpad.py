@@ -45,27 +45,16 @@ LENGTH_BUCKETS = (16, 64, 256, 1024)
 #: sampler (obs/shadow.py) is the guard rail when arming it.
 ENV_PRUNE = "REPORTER_TPU_ROUTE_PRUNE_SIGMA"
 
-#: runtime bucket-ladder override: "16,64,256,1024" (ascending ints),
-#: with an optional "@<waste>" suffix setting the occupancy-driven
-#: split threshold ("@1" / "@off" disables splitting). Default: the
-#: fixed LENGTH_BUCKETS ladder with splitting at DEFAULT_SPLIT_WASTE.
+#: runtime bucket-ladder override: "16,64,256,1024" (ascending ints).
+#: An "@..." suffix is accepted and ignored, so a spec such as "64@off"
+#: still pins the ladder to (64,).
 ENV_BUCKETS = "REPORTER_TPU_BUCKETS"
-
-#: padding-waste ratio above which the native dispatcher breaks a
-#: mixed-length chunk into per-pow2-bucket sub-batches (matcher.py
-#: SegmentMatcher._split_bucket) — high enough that the exact-fill steady state
-#: (BENCH_DEV_r07 recorded 0.21 whole-run, mostly jitter drops and
-#: pow2 row padding a finer T can't reclaim) never splits, low enough
-#: that a 17-point trace padding to T=64 (waste ~0.73) always does
-DEFAULT_SPLIT_WASTE = 0.35
 
 _ladder_cache: "dict[str, tuple]" = {}
 
-#: pressure-ladder rung (service/admission.py "coarse_buckets"): under
-#: sustained overload the adaptive splitter is disabled — fewer, larger
-#: decode shapes, no split dispatches and no fresh compile episodes
-#: mid-storm. The ladder flips it; bucket_ladder() reports threshold
-#: 1.0 (never split) while it holds.
+#: pressure-ladder rung (service/admission.py "coarse_buckets"): while it
+#: holds, :func:`plan_chunks` classes every trace at its ladder bucket —
+#: fewer, larger decode shapes and no fresh compile episodes mid-storm.
 _pressure_coarse = False
 
 
@@ -74,58 +63,41 @@ def set_pressure_coarse(on: bool) -> None:
     _pressure_coarse = bool(on)
 
 
-def bucket_ladder() -> "tuple[tuple, float]":
-    """(ladder, split_threshold) from REPORTER_TPU_BUCKETS; the default
-    fixed ladder with the default threshold when unset. A malformed
-    spec logs and keeps the default (a typo'd ladder must degrade to
-    the shipped shapes, never to an unbounded shape zoo).
-
-    The native dispatcher's chunk plan (``SegmentMatcher._plan_chunks``)
-    buckets a group on this ladder and splits a bucket past the
-    threshold; then it merges a group of at most 128 traces into one
-    chunk, at the power of two of its longest trace between
-    ``ladder[0]`` and that trace's bucket (the bucket itself at
-    threshold 1.0), where the padding that adds is worth less than the
-    chunks it saves."""
+def bucket_ladder() -> "tuple[tuple, None]":
+    """``(ladder, None)``: the ladder from REPORTER_TPU_BUCKETS, the
+    fixed LENGTH_BUCKETS when unset. A malformed spec logs and keeps the
+    default (a typo'd ladder must degrade to the shipped shapes, never
+    to an unbounded shape zoo). The second element has no job and is
+    always None; callers read ``[0]``."""
     spec = _os.environ.get(ENV_BUCKETS, "").strip()
     if not spec:
         # the default is NOT cached: LENGTH_BUCKETS is read live, so
         # tests that monkeypatch the module ladder keep working
-        return (LENGTH_BUCKETS,
-                1.0 if _pressure_coarse else DEFAULT_SPLIT_WASTE)
-    got = _ladder_cache.get(spec)
-    if got is not None:
-        return (got[0], 1.0) if _pressure_coarse else got
-    ladder, thresh = LENGTH_BUCKETS, DEFAULT_SPLIT_WASTE
-    if spec:
-        body, _, tail = spec.partition("@")
+        return LENGTH_BUCKETS, None
+    ladder = _ladder_cache.get(spec)
+    if ladder is None:
+        ladder = LENGTH_BUCKETS
+        body = spec.partition("@")[0]
         try:
-            if tail.strip().lower() in ("off", "no", "false"):
-                thresh = 1.0
-            elif tail.strip():
-                thresh = float(tail)
             vals = tuple(int(v) for v in body.split(",") if v.strip())
             if body.strip():
                 if not vals or any(v <= 0 for v in vals) or \
                         list(vals) != sorted(set(vals)):
                     raise ValueError("ladder must be ascending positive")
                 ladder = vals
-            if not 0.0 < thresh:
-                raise ValueError("threshold must be positive")
         except ValueError as e:
             import logging
             logging.getLogger("reporter_tpu.matcher").warning(
                 "%s=%r not understood (%s); keeping the default ladder",
                 ENV_BUCKETS, spec, e)
-            ladder, thresh = LENGTH_BUCKETS, DEFAULT_SPLIT_WASTE
-    _ladder_cache[spec] = (ladder, thresh)
-    return (ladder, 1.0) if _pressure_coarse else (ladder, thresh)
+        _ladder_cache[spec] = ladder
+    return ladder, None
 
 
 def bucket_length(n: int) -> int:
     """Smallest bucket >= n (the last bucket caps the trace length).
     Reads the runtime ladder (REPORTER_TPU_BUCKETS; default unchanged)."""
-    ladder, _ = bucket_ladder()
+    ladder = bucket_ladder()[0]
     idx = bisect.bisect_left(ladder, n)
     return ladder[min(idx, len(ladder) - 1)]
 
@@ -691,6 +663,67 @@ def padded_batch_rows(B: int, pad: "int | None", pow2: bool = True) -> int:
         if not pad or p2 % pad == 0:
             rows = p2
     return rows
+
+
+#: padded point cells worth one decode chunk's fixed host cost, the
+#: least a split must save to pay for the chunk it adds. Measured on a
+#: v5e host, a chunk pays about 2.3 ms of fixed prep and a decode
+#: dispatch of 3.7 ms at 16 x 128 cells against 5.1 ms at 16 x 256:
+#: about 0.7 us a cell over a fixed 2.2 ms, so the fixed part is worth
+#: some 6,400 cells. Two thirds of that, so a split has to save clearly.
+CHUNK_COST_CELLS = 4096
+
+
+def plan_chunks(raw_counts, group, chunk: int, pad: "int | None"
+                ) -> "list[tuple[int, np.ndarray]]":
+    """The decode plan of one params group: ``[(T, index array)]``, one
+    entry per decode chunk, i.e. per prep call, decode dispatch and
+    assembly. ``group`` indexes ``raw_counts`` (raw point counts),
+    ``chunk`` is the most traces a chunk holds and ``pad`` the mesh row
+    multiple. A pure function of those, the ladder and the pressure
+    rung: no run history.
+
+    A trace's class is the power of two of its raw length, clipped to
+    [ladder floor, its ladder bucket]; while the ``coarse_buckets`` rung
+    holds, its ladder bucket. Raw length bounds the kept length, known
+    only after prep, so a jitter-heavy trace may decode at a larger T:
+    the same path, since the SKIP tail is inert. The group is ordered by
+    class (stable) and cut into runs of ``chunk`` traces. A run splits
+    in two at the class boundary that saves the most padded cells
+    (``padded_batch_rows`` x the part's largest class) where that saving
+    exceeds CHUNK_COST_CELLS, and each part again. A part decodes at its
+    largest class, so every shape lies in :meth:`SegmentMatcher.warm`'s
+    set: pow2 rows up to a chunk, pow2 T from the floor to the top
+    bucket."""
+    ladder = np.asarray(bucket_ladder()[0], dtype=np.int64)
+    raws = np.maximum(np.asarray(raw_counts)[group], 1)
+    cls = ladder[np.minimum(np.searchsorted(ladder, raws), len(ladder) - 1)]
+    if not _pressure_coarse:
+        pow2 = np.exp2(np.ceil(np.log2(raws))).astype(np.int64)
+        cls = np.minimum(np.maximum(pow2, ladder[0]), cls)
+    order = np.argsort(cls, kind="stable")
+    cls, idx = cls[order], np.asarray(group)[order]
+
+    def cells(lo: int, hi: int) -> int:
+        return padded_batch_rows(hi - lo, pad) * int(cls[hi - 1])
+
+    plan = []
+
+    def cut(lo: int, hi: int) -> None:
+        best, at = CHUNK_COST_CELLS, None
+        for b in (np.flatnonzero(np.diff(cls[lo:hi])) + lo + 1).tolist():
+            saved = cells(lo, hi) - cells(lo, b) - cells(b, hi)
+            if saved > best:
+                best, at = saved, b
+        if at is None:
+            plan.append((int(cls[hi - 1]), idx[lo:hi]))
+        else:
+            cut(lo, at)
+            cut(at, hi)
+
+    for lo in range(0, len(idx), chunk):
+        cut(lo, min(lo + chunk, len(idx)))
+    return plan
 
 
 def pack_batches(prepared: Sequence[PreparedTrace],

@@ -33,8 +33,8 @@ from ..utils import faults, metrics
 from ..utils import locks as _locks
 from ..utils.circuit import CircuitBreaker
 from .assemble import assemble_segments
-from .batchpad import (_next_pow2, bucket_ladder, kept_point_count,
-                       pack_batches, padded_batch_rows, prepare_batch,
+from .batchpad import (bucket_ladder, kept_point_count, pack_batches,
+                       padded_batch_rows, plan_chunks, prepare_batch,
                        prepare_trace, prepare_traces_numpy)
 from .params import MatchParams
 
@@ -79,17 +79,8 @@ def _route_device_enabled() -> bool:
 
 
 #: traces a decode chunk holds on one device with the pipeline on
-#: (``_decode_chunk``'s default there), and the most traces the chunk
-#: plan merges into one chunk (``SegmentMatcher._plan_chunks``)
+#: (``_decode_chunk``'s default there)
 PIPELINED_CHUNK = 128
-
-#: padded point cells worth one decode chunk's fixed host cost, the
-#: most padding a merge may add for each chunk it saves. Measured on a
-#: v5e host, a chunk pays about 2.3 ms of fixed prep and a decode
-#: dispatch of 3.7 ms at 16 x 128 cells against 5.1 ms at 16 x 256:
-#: about 0.7 us a cell over a fixed 2.2 ms, so the fixed part is worth
-#: some 6,400 cells. Two thirds of that, so a merge has to save clearly.
-CHUNK_COST_CELLS = 4096
 
 
 def _decode_chunk() -> int:
@@ -719,17 +710,33 @@ class SegmentMatcher:
             raise first_err
         return results
 
+    @staticmethod
+    def _warm_shapes(max_points: int, chunk: int) -> "list[tuple]":
+        """``[(traces, T)]`` that :meth:`warm` runs: each power-of-two T
+        from the ladder's floor up to the ladder bucket that holds
+        ``max_points``, times each power-of-two trace count up to
+        ``chunk``. With rows padded as ``padded_batch_rows`` pads them,
+        this holds every shape ``batchpad.plan_chunks`` can form for
+        such traces."""
+        ladder = [int(b) for b in bucket_ladder()[0]]
+        top = next((b for b in ladder if b >= max_points), ladder[-1])
+        shapes, T = [], ladder[0]
+        while T <= top:
+            n = 1
+            while n <= chunk:
+                shapes.append((n, T))
+                n *= 2
+            T *= 2
+        return shapes
+
     def warm(self, traces, max_points: int) -> int:
         """Build, before a measured stretch of work, every decode program
         :meth:`match_many` can form for traces of up to ``max_points``
-        points: each power-of-two T from the ladder's floor up to the
-        ladder bucket that holds ``max_points``, times each power-of-two
-        row count up to a decode chunk, rows padded as the chunk plan
-        pads them. Each shape runs a chunk's own path once, inline:
-        native prep of the first rows of ``traces`` (taken in turn where
-        there are fewer) cut to T, the device decode and the assembly,
-        so whatever else that path compiles is built too. Returns the
-        shapes run."""
+        points (:meth:`_warm_shapes`, with the decode chunk). Each shape
+        runs a chunk's own path once, inline: native prep of the first
+        rows of ``traces`` (taken in turn where there are fewer) cut to
+        T, the device decode and the assembly, so whatever else that
+        path compiles is built too. Returns the shapes run."""
         if self.runtime is None:
             raise RuntimeError("warm needs the native host runtime")
         from ..ops import batch_pad_multiple, decode_batch
@@ -738,33 +745,24 @@ class SegmentMatcher:
         params = self.params.with_options(opts) if opts else self.params
         sigma = np.float32(params.effective_sigma)
         beta = np.float32(params.beta)
-        ladder = [int(b) for b in bucket_ladder()[0]]
-        top = next((b for b in ladder if b >= max_points), ladder[-1])
         chunk = _decode_chunk()
         pad = batch_pad_multiple()
         if pad:
             chunk = ((chunk + pad - 1) // pad) * pad
-        shapes = 0
-        T = ladder[0]
-        while T <= top:
-            n = 1
-            while n <= chunk:
-                part = np.arange(n, dtype=np.int64) % len(tb)
-                sub = tb.gather(part)
-                batch = prepare_batch(
-                    self.runtime, sub, params, T,
-                    pad_rows=padded_batch_rows(n, pad),
-                    n_threads=max(1, _prep_workers()),
-                    route_kernel=self._device_route_kernel(),
-                    route_circuit=self.circuit_route, defer_routes=True)
-                decoded = self._dispatch_stage(batch, sigma, beta,
-                                               decode_batch)
-                self._drain_stage(batch, range(n), decoded, [params] * n,
-                                  [None] * n, sub)
-                shapes += 1
-                n *= 2
-            T *= 2
-        return shapes
+        shapes = self._warm_shapes(max_points, chunk)
+        for n, T in shapes:
+            part = np.arange(n, dtype=np.int64) % len(tb)
+            sub = tb.gather(part)
+            batch = prepare_batch(
+                self.runtime, sub, params, T,
+                pad_rows=padded_batch_rows(n, pad),
+                n_threads=max(1, _prep_workers()),
+                route_kernel=self._device_route_kernel(),
+                route_circuit=self.circuit_route, defer_routes=True)
+            decoded = self._dispatch_stage(batch, sigma, beta, decode_batch)
+            self._drain_stage(batch, range(n), decoded, [params] * n,
+                              [None] * n, sub)
+        return len(shapes)
 
     def match_incremental(self, traces) -> List[Optional[dict]]:
         """Match via carried per-trace decode state where possible.
@@ -1068,10 +1066,11 @@ class SegmentMatcher:
     def _dispatch_native(self, tb: TraceBatch, per_trace_params, chunk,
                          pad, submit):
         """Hot path: group by prep params, plan each group's chunks
-        (``_plan_chunks``), then ONE rt_prepare_batch call per chunk on
-        this thread — the chunk's flat coordinate columns pass straight
-        from the TraceBatch to the native call, zero per-point Python —
-        handing each prepared batch to ``submit`` (the device lanes).
+        (``batchpad.plan_chunks``), then ONE rt_prepare_batch call per
+        chunk on this thread — the chunk's flat coordinate columns pass
+        straight from the TraceBatch to the native call, zero per-point
+        Python — handing each prepared batch to ``submit`` (the device
+        lanes).
 
         Failure domain: each chunk consults the circuit breaker. A
         native prep error degrades THAT chunk to the numpy path (the
@@ -1085,8 +1084,7 @@ class SegmentMatcher:
         for params, idxs in self._param_groups(per_trace_params):
             sigma = np.float32(params.effective_sigma)
             beta = np.float32(params.beta)
-            for T, part, coalesced in self._plan_chunks(idxs, raw_counts,
-                                                        pad, chunk):
+            for T, part in plan_chunks(raw_counts, idxs, chunk, pad):
                 # part itself is the order: _drain_stage only
                 # enumerates it, so no per-chunk list conversion
                 # (reporter-lint HP003)
@@ -1134,141 +1132,8 @@ class SegmentMatcher:
                         kept_points=kept_point_count(batch),
                         raw_points=int(raw_counts[part].sum()),
                         cache=self.runtime.route_memo_stats(),
-                        path="native", coalesced=coalesced)
+                        path="native")
                     submit(batch, order, sigma, beta)
-
-    @staticmethod
-    def _plan_chunks(group, raw_counts, pad=None, chunk=None):
-        """The chunk plan for one params group: ``[(T, index array,
-        coalesced)]``, one entry per decode chunk, i.e. per prep call,
-        decode dispatch and assembly the group pays.
-
-        The per-bucket plan groups traces by ladder bucket of their RAW
-        length (kept length is only known after the native prep; raw is
-        an upper bound, so a jitter-heavy trace may decode in a larger
-        bucket — same decoded path, the SKIP tail is inert), lets
-        ``_split_bucket`` break a wasteful bucket into pow2 sub-buckets,
-        and cuts each (sub-)bucket into ``chunk``-row chunks.
-
-        Then merge: a group of at most ``PIPELINED_CHUNK`` traces (and
-        at most ``chunk``), which the per-bucket plan cuts into more
-        than one chunk, decodes as ONE chunk instead, at the smallest
-        power of two holding its longest raw trace (clipped to [ladder
-        floor, that trace's ladder bucket]; the bucket itself while
-        splitting is off, so the pressure ladder's coarse rung forms no
-        new shape) — but only where the merge's extra padded cells stay
-        within ``CHUNK_COST_CELLS`` for each chunk it saves, so one long
-        trace never pads a micro-batch of short ones to its length
-        (``decode.bucket.coalesced``). The cap is the one-device
-        pipelined chunk whatever the mesh or the pipeline mode scale
-        ``chunk`` to: a larger group (a streaming flush of more than
-        128 traces, a batch-pipeline or mesh chunk) keeps the per-bucket
-        plan and the lanes' overlap across its chunks. A merged chunk
-        is marked ``coalesced``: its padding is chosen, so it stays out
-        of the per-T waste the splitter consults."""
-        ladder, thresh = bucket_ladder()
-        buckets = np.asarray(ladder, dtype=np.int64)
-        raws = raw_counts[group]
-        Ts = buckets[np.minimum(
-            np.searchsorted(buckets, np.maximum(raws, 1)),
-            len(buckets) - 1)]
-        plan, splits = [], 0
-        for T0 in np.unique(Ts).tolist():
-            sub = SegmentMatcher._split_bucket(T0, group[Ts == T0],
-                                               raw_counts, pad, chunk)
-            splits += len(sub) > 1 or sub[0][0] != T0
-            plan += sub
-        step = chunk or len(group)
-        if len(plan) > 1 and len(group) <= min(step, PIPELINED_CHUNK):
-            top = int(Ts.max())
-            T = top if thresh >= 1.0 else min(
-                max(_next_pow2(int(raws.max())), int(ladder[0])), top)
-            # every (sub-)bucket of a group this small is one chunk
-            planned = sum(SegmentMatcher._padded_cells(len(b), pad, t,
-                                                       step)
-                          for t, b in plan)
-            merged = SegmentMatcher._padded_cells(len(group), pad, T, step)
-            if merged - planned <= CHUNK_COST_CELLS * (len(plan) - 1):
-                metrics.count("decode.bucket.coalesced")
-                return [(T, group, True)]
-        if splits:
-            metrics.count("decode.bucket.split", splits)
-        return [(T, bucket[lo:lo + step], False) for T, bucket in plan
-                for lo in range(0, len(bucket), step)]
-
-    @staticmethod
-    def _padded_cells(n: int, pad, T: int, chunk) -> int:
-        """Point cells ``n`` traces of bucket ``T`` actually decode as,
-        chunked exactly as the dispatch loop chunks them — each chunk
-        re-pays its own mesh-multiple + pow2 row padding."""
-        cells = 0
-        while n > 0:
-            take = min(n, chunk) if chunk else n
-            cells += padded_batch_rows(take, pad) * T
-            n -= take
-        return cells
-
-    @staticmethod
-    def _split_bucket(T: int, group, raw_counts, pad=None, chunk=None):
-        """The occupancy-driven adaptive splitter, the per-bucket plan's
-        second step: ``[(sub_T, index array)]`` for one ladder-bucket
-        group, ``[(T, group)]`` when no split pays (``_plan_chunks``
-        counts ``decode.bucket.split`` for each split it keeps; a small
-        group it merges into one chunk is not split). A split breaks
-        a mixed-length group into per-pow2-bucket sub-batches
-        (per-trace smallest power of two >= raw
-        length, clipped to [ladder floor, T]) when the padding waste of
-        decoding everything at T exceeds the ladder's threshold —
-        consulting the RECORDED per-bucket waste from PR 8's wide
-        events (profiler.bucket_waste) once chunks of this T have been
-        measured, and a projection from this group's raw lengths before
-        that (kept <= raw, so the projection under-states waste and
-        never over-splits). ``pad`` is the mesh row multiple: a split
-        only happens when the total padded point cells ACROSS the
-        sub-batches — each re-paying mesh-multiple + pow2 ROW padding —
-        actually drop, so splitting can never trade tail pad for worse
-        filler-row pad (a 4-trace sub-batch on an 8-wide mesh pads
-        right back to 8 rows). Decoded paths are unchanged — the SKIP
-        tail is inert, pinned byte-identical by
-        tests/test_sharded_decode.py — and the shape cost is bounded:
-        sub-buckets are powers of two, each new (rows, T) pair is ONE
-        compile episode, and a second compile of the same shape still
-        trips the storm counter."""
-        ladder, thresh = bucket_ladder()
-        if thresh >= 1.0 or len(group) < 2 or T <= int(ladder[0]):
-            return [(T, group)]
-        raws = np.minimum(raw_counts[group], T)
-        # decision waste = max(projected, recorded). The projection
-        # uses the same denominator the recorded waste does — PADDED
-        # rows chunked exactly as dispatch will chunk them (mesh
-        # multiple + pow2 filler counts as waste there too) — with
-        # kept <= raw in the numerator, so it under-states and never
-        # over-splits; the recorded per-bucket number (PR 8's wide
-        # events) catches what the projection can't see (kept << raw
-        # on jitter-heavy streams). max, not recorded-first: after a
-        # split, the low-waste SUB-chunks record under this same T
-        # and a recorded-first read would oscillate
-        # (split -> record low -> stop splitting -> record high -> ...)
-        cells_unsplit = SegmentMatcher._padded_cells(len(group), pad, T,
-                                                     chunk)
-        waste = 1.0 - float(raws.sum()) / cells_unsplit
-        recorded = profiler.bucket_waste(T)
-        if recorded is not None:
-            waste = max(waste, recorded)
-        if waste <= thresh:
-            return [(T, group)]
-        subTs = np.minimum(np.maximum(
-            np.exp2(np.ceil(np.log2(np.maximum(raws, 1))))
-            .astype(np.int64), int(ladder[0])), T)
-        uniq, counts = np.unique(subTs, return_counts=True)
-        if uniq.tolist() == [T]:
-            return [(T, group)]
-        cells_split = int(sum(
-            SegmentMatcher._padded_cells(int(c), pad, int(s), chunk)
-            for s, c in zip(uniq.tolist(), counts.tolist())))
-        if cells_split >= cells_unsplit:
-            return [(T, group)]
-        return [(int(s), group[subTs == s]) for s in uniq.tolist()]
 
     def _submit_numpy_chunk(self, tb: TraceBatch, part, params, pad,
                             submit, sigma, beta) -> None:

@@ -107,11 +107,6 @@ def entry_from_bench(parsed: dict, source: str, label: str, kind: str,
         "ok": parsed.get("vs_baseline") is not None,
         "context": context,
     }
-    # the adaptive-bucket before/after pair (ISSUE 13): fixed-ladder vs
-    # adaptive padding waste over the same mixed-length batch — a true
-    # same-box ratio pair, gated by perf_gate --max-padding-waste
-    if isinstance(parsed.get("bucketing"), dict):
-        entry["bucketing"] = parsed["bucketing"]
     # the serving-tier batched-query pair (ISSUE 14): query_many(256)
     # vs 256 single queries over the same store — another same-box
     # ratio pair, gated by perf_gate --min-query-ratio

@@ -45,9 +45,10 @@ budgets and the PR 8 queue-depth gauges as *sensors*:
                        thread's CPU goes back to serving);
   * ``shed_trace``     per-request ``?trace=1`` tracing refused
                        (span/export overhead shed);
-  * ``coarse_buckets`` the adaptive bucket splitter disabled — fewer,
-                       larger decode shapes, no split dispatches and no
-                       fresh compile episodes mid-storm;
+  * ``coarse_buckets`` the chunk planner classes every trace at its
+                       ladder bucket (``batchpad.plan_chunks``) — fewer,
+                       larger decode shapes and no fresh compile
+                       episodes mid-storm;
   * ``oracle_decode``  the last rung: decode serves via the numpy
                        oracle path (the PR 9 circuit fallback), keeping
                        the device queue free for the drain backlog.

@@ -56,7 +56,7 @@ ACTIONS = {"report", "stats", "metrics", "histogram", "health",
 
 #: pressure-ladder rung at which /feed sheds subscribers (429 +
 #: Retry-After): rung 2 (shed_trace) — one rung BEFORE the ladder
-#: starts degrading the match path itself (coarse_buckets), so feed
+#: starts coarsening the decode shapes (coarse_buckets), so feed
 #: fan-out is always the first load dropped
 FEED_SHED_LEVEL = 2
 

@@ -84,9 +84,15 @@ class TestWindowedQuantile:
 
 class TestPressureLadder:
     def test_hysteresis_and_rung_effects(self):
+        import numpy as np
+
         from reporter_tpu.matcher import batchpad
         from reporter_tpu.matcher import matcher as matcher_mod
         from reporter_tpu.obs import profiler
+
+        def planned_t(raw):  # the T a lone trace of ``raw`` points gets
+            return batchpad.plan_chunks(np.array([raw]), np.arange(1),
+                                        128, None)[0][0]
         clk = [0.0]
         lad = PressureLadder(hold_s=1.0, clock=lambda: clk[0])
         assert lad.observe(True) == 0          # dwell 0 < hold
@@ -100,7 +106,7 @@ class TestPressureLadder:
         assert not admission.allow_request_trace()
         clk[0] = 3.0
         assert lad.observe(True) == 3
-        assert batchpad.bucket_ladder()[1] == 1.0  # splitter off
+        assert planned_t(100) == 256  # classed at its ladder bucket
         clk[0] = 4.0
         assert lad.observe(True) == 4
         assert matcher_mod._pressure_oracle
@@ -113,10 +119,10 @@ class TestPressureLadder:
         clk[0] = 7.0
         assert lad.observe(False) == 3
         assert not matcher_mod._pressure_oracle   # oracle rung left
-        assert batchpad.bucket_ladder()[1] == 1.0  # coarse still held
+        assert planned_t(100) == 256  # coarse still held
         clk[0] = 9.0
         assert lad.observe(False) == 2
-        assert batchpad.bucket_ladder()[1] != 1.0
+        assert planned_t(100) == 128
         assert not admission.allow_request_trace()  # trace still shed
         clk[0] = 11.0
         assert lad.observe(False) == 1

@@ -1,4 +1,4 @@
-"""ISSUE 13: device-mesh decode scale-out + adaptive bucketing.
+"""Device-mesh decode scale-out and the chunk plan.
 
 The contract under test is BIT-IDENTITY: the 1-D ``("data",)`` decode
 mesh carries no collective, so the sharded scan decode must equal the
@@ -7,7 +7,7 @@ single-device scan backend bit for bit — same Viterbi paths, same
 it at N∈{1,2,8} (the device count is fixed at backend init, so each N
 is its own interpreter); in-process tests cover the conftest 8-device
 mesh, the rows-not-divisible-by-mesh chunk, all-SKIP filler rows, the
-adaptive bucket splitter, and the new knobs/gates.
+chunk plan, and the knobs/gates.
 """
 import hashlib
 import json
@@ -20,8 +20,9 @@ import pytest
 
 from reporter_tpu import ops
 from reporter_tpu.matcher import MatchParams, SegmentMatcher
-from reporter_tpu.matcher.batchpad import (DEFAULT_SPLIT_WASTE,
-                                           LENGTH_BUCKETS, bucket_ladder)
+from reporter_tpu.matcher import batchpad
+from reporter_tpu.matcher.batchpad import (LENGTH_BUCKETS, bucket_ladder,
+                                           plan_chunks)
 from reporter_tpu.matcher.matcher import (MatchRuns, _decode_chunk,
                                           match_batch_default,
                                           render_segments_json)
@@ -296,186 +297,133 @@ class TestDeviceSlice:
         assert captured["slice"] == "1/2"
 
 
-class TestAdaptiveBucketing:
+class TestChunkPlan:
     def test_ladder_default_and_env(self, monkeypatch):
-        assert bucket_ladder() == (LENGTH_BUCKETS, DEFAULT_SPLIT_WASTE)
+        assert bucket_ladder() == (LENGTH_BUCKETS, None)
         monkeypatch.setenv("REPORTER_TPU_BUCKETS", "8,32,128@0.5")
-        assert bucket_ladder() == ((8, 32, 128), 0.5)
+        assert bucket_ladder() == ((8, 32, 128), None)
+        monkeypatch.setenv("REPORTER_TPU_BUCKETS", "64@off")
+        assert bucket_ladder() == ((64,), None)
         monkeypatch.setenv("REPORTER_TPU_BUCKETS", "@off")
-        assert bucket_ladder() == (LENGTH_BUCKETS, 1.0)
+        assert bucket_ladder() == (LENGTH_BUCKETS, None)
         monkeypatch.setenv("REPORTER_TPU_BUCKETS", "64,16@0.2")  # bad
-        assert bucket_ladder() == (LENGTH_BUCKETS, DEFAULT_SPLIT_WASTE)
+        assert bucket_ladder() == (LENGTH_BUCKETS, None)
 
-    def test_split_plan_projection(self):
-        """A mixed 64-bucket group whose raw lengths project waste past
-        the threshold splits into pow2 sub-buckets covering exactly the
-        original indices; the chunk plan of a group larger than one
-        chunk keeps the split and counts it."""
+    def test_plan_ignores_history(self):
+        """The plan is a function of the group alone: a recorded, very
+        wasteful chunk of the planned T changes nothing."""
         profiler.reset()
-        group = np.arange(8, dtype=np.int64)
-        raws = np.array([10, 10, 17, 17, 30, 30, 60, 60], dtype=np.int64)
-        plan = SegmentMatcher._split_bucket(64, group, raws)
-        assert [t for t, _ in plan] == [16, 32, 64]
-        covered = np.concatenate([idx for _, idx in plan])
-        assert sorted(covered.tolist()) == group.tolist()
-        before = metrics.default.counter("decode.bucket.split")
-        chunks = SegmentMatcher._plan_chunks(group, raws, None, 4)
-        assert [(t, p.tolist(), c) for t, p, c in chunks] == [
-            (16, [0, 1], False), (32, [2, 3, 4, 5], False),
-            (64, [6, 7], False)]
-        assert metrics.default.counter("decode.bucket.split") == before + 1
-
-    @staticmethod
-    def _is_noop(plan, T, group):
-        return len(plan) == 1 and plan[0][0] == T and plan[0][1] is group
-
-    def test_split_plan_skips_full_buckets(self):
-        profiler.reset()
+        raws = np.array([24, 24, 60, 64], dtype=np.int64)
         group = np.arange(4, dtype=np.int64)
-        raws = np.array([60, 61, 62, 64], dtype=np.int64)
-        assert self._is_noop(
-            SegmentMatcher._split_bucket(64, group, raws), 64, group)
-
-    def test_split_plan_consults_recorded_waste(self):
-        """The ISSUE wording, pinned: once the PR 8 wide events have
-        RECORDED high waste for a shape, the dispatcher splits even a
-        group whose raw lengths project full buckets (kept < raw is
-        exactly what the projection can't see)."""
-        profiler.reset()
-        group = np.arange(4, dtype=np.int64)
-        raws = np.array([60, 61, 62, 64], dtype=np.int64)
-        # record one very wasteful 64-bucket chunk (occupancy 0.1)
-        # a mildly-mixed group that PROJECTS under the threshold
-        # (1 - 204/256 = 0.20): no split before any chunk is measured
-        raws2 = np.array([24, 24, 60, 64], dtype=np.int64)
-        assert self._is_noop(
-            SegmentMatcher._split_bucket(64, group, raws2), 64, group)
-        # record one very wasteful 64-bucket chunk (occupancy 0.1) —
-        # the same group now splits on the measured record alone
+        before = plan_chunks(raws, group, 128, None)
+        assert [(T, p.tolist()) for T, p in before] == [(64, [0, 1, 2, 3])]
         profiler.chunk_event(bucket_T=64, K=8, traces=4, rows=4,
                              kept_points=int(0.1 * 4 * 64),
                              raw_points=256)
-        plan2 = SegmentMatcher._split_bucket(64, group, raws2)
-        assert [t for t, _ in plan2] == [32, 64]
-        # full-length raws can't split no matter what the record says
-        assert self._is_noop(
-            SegmentMatcher._split_bucket(64, group, raws), 64, group)
+        after = plan_chunks(raws, group, 128, None)
+        assert [(T, p.tolist()) for T, p in after] \
+            == [(T, p.tolist()) for T, p in before]
         profiler.reset()
-
-    def test_split_projection_is_chunk_aware(self):
-        """A group one trace past the chunk boundary must not read the
-        whole-group pow2 row padding as reclaimable waste: cells are
-        accounted per CHUNK, exactly as dispatch pads them, so a
-        near-perfectly-packed 513-trace group stays unsplit."""
-        profiler.reset()
-        group = np.arange(513, dtype=np.int64)
-        raws = np.full(513, 64, dtype=np.int64)
-        raws[-1] = 16
-        plan = SegmentMatcher._split_bucket(64, group, raws, None, 512)
-        assert self._is_noop(plan, 64, group)
-
-    def test_split_disabled_by_off_threshold(self, monkeypatch):
-        monkeypatch.setenv("REPORTER_TPU_BUCKETS", "@off")
-        group = np.arange(8, dtype=np.int64)
-        raws = np.array([10] * 8, dtype=np.int64)
-        assert self._is_noop(
-            SegmentMatcher._split_bucket(64, group, raws), 64, group)
 
     @pytest.mark.skipif(
         not __import__("reporter_tpu.native", fromlist=["available"])
-        .available(), reason="splitter lives in the native dispatch")
-    def test_split_results_byte_identical(self, city, monkeypatch):
-        """Splitting changes shapes, never bytes: the SKIP tail is
-        inert, so a trace decoded at its pow2 sub-bucket yields the
-        same report body as at the full ladder bucket."""
+        .available(), reason="the chunk plan lives in the native dispatch")
+    def test_plan_t_results_byte_identical(self, city, monkeypatch):
+        """The T a chunk decodes at changes shapes, never bytes: the
+        SKIP tail is inert, so a trace decoded at its power-of-two class
+        yields the same report body as at its ladder bucket (the
+        coarse_buckets rung)."""
         monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
-        # a chunk of 8 rows: the 16-trace group is larger than one
-        # chunk, so it takes the per-bucket plan and may split
+        # a chunk of 8 rows: 8 traces at raw 18 (class 32, bucket 64)
+        # decode in one chunk, 8 near-full at raw 60 in another
         monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", "8")
         m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
-        # mixed lengths in ONE 64-bucket: 8 traces at raw 18 (pads to
-        # 64 fixed, 32 split) + 8 near-full at raw 60 — each sub-batch
-        # is a whole mesh multiple, so the split's row re-padding
-        # can't eat the reclaimed tail
         reqs = _mixed_reqs(city, n=16, seed=31, max_edges=14)
         for r in reqs[:8]:
             r["trace"] = r["trace"][:18]
         for r in reqs[8:]:
             r["trace"] = r["trace"][:60]
-        monkeypatch.setenv("REPORTER_TPU_BUCKETS", "@off")
+        monkeypatch.setattr(batchpad, "_pressure_coarse", True)
         profiler.reset()
-        fixed = _bodies(m.match_many(reqs))
-        waste_fixed = profiler.padding_waste()
-        monkeypatch.setenv("REPORTER_TPU_BUCKETS", "@0.2")
+        coarse = _bodies(m.match_many(reqs))
+        waste_coarse = profiler.padding_waste()
+        monkeypatch.setattr(batchpad, "_pressure_coarse", False)
         profiler.reset()
-        before = metrics.default.counter("decode.bucket.split")
-        adaptive = _bodies(m.match_many(reqs))
-        waste_adaptive = profiler.padding_waste()
-        assert fixed == adaptive
-        assert metrics.default.counter("decode.bucket.split") > before
-        assert waste_adaptive < waste_fixed
+        fine = _bodies(m.match_many(reqs))
+        waste_fine = profiler.padding_waste()
+        assert fine == coarse
+        assert waste_fine < waste_coarse
+        profiler.reset()
 
-    #: raw lengths of one params group, the REPORTER_TPU_BUCKETS spec,
-    #: the rows of a chunk, and the chunks planned: [(T, traces)], then
-    #: whether the group was merged into one chunk
+    #: raw lengths of one params group, whether the coarse_buckets rung
+    #: holds, the rows of a chunk, and the chunks planned: [(T, traces)]
     PLANS = {
-        # a sparse-fleet micro-batch: ladder buckets 16, 64 and 256
-        "sparse": (np.linspace(12, 110, 15).round(), "", 128,
-                   [(128, 15)], True),
-        # a 1 Hz micro-batch: one 256 bucket no split pays for
-        "1hz": (np.linspace(70, 250, 13).round(), "", 128, [(256, 13)],
-                False),
-        # one 256 bucket the splitter would cut at 128
-        "1hz-split": ([70] * 8 + [250] * 5, "", 128, [(256, 13)], True),
-        # splitting off (the pressure ladder's coarse rung): merged at
-        # the longest trace's ladder bucket, no new shape
-        "sparse-coarse": (np.linspace(12, 110, 15).round(), "@off", 128,
-                          [(256, 15)], True),
-        # one long trace among short ones: merging would pad 127 rows
+        # a sparse-fleet micro-batch: classes 16 to 128, one chunk
+        "sparse": (np.linspace(12, 110, 15).round(), False, 128,
+                   [(128, 15)]),
+        # a 1 Hz micro-batch: classes 128 and 256, no split pays
+        "1hz": (np.linspace(70, 250, 13).round(), False, 128, [(256, 13)]),
+        # a split at 128 would save 1,024 cells, under a chunk's cost
+        "1hz-split": ([70] * 8 + [250] * 5, False, 128, [(256, 13)]),
+        # the coarse_buckets rung: every class its ladder bucket
+        "sparse-coarse": (np.linspace(12, 110, 15).round(), True, 128,
+                          [(256, 15)]),
+        # one long trace among short ones: one chunk would pad 127 rows
         # of 16 to 1024, far more cells than the chunk it saves
-        "outlier": ([1000] + [16] * 127, "", 128,
-                    [(16, 127), (1024, 1)], False),
-        # larger than one chunk: the per-bucket plan, split and cut
-        "mixed-200": ([12] * 30 + [20] * 20 + [40] * 20 + [200] * 130, "",
-                      128,
-                      [(16, 30), (32, 20), (64, 20), (256, 128), (256, 2)],
-                      False),
-        # the four-chip mesh's (or an unpipelined host's) 512-row chunk:
-        # the merge still takes at most 128 traces
-        "mesh-512": ([12] * 100 + [40] * 100 + [100] * 312, "", 512,
-                     [(16, 100), (64, 100), (128, 312)], False),
-        "mesh-128": (np.linspace(12, 110, 128).round(), "", 512,
-                     [(128, 128)], True),
-        "mesh-129": (np.linspace(12, 110, 129).round(), "", 512,
-                     [(16, 6), (64, 63), (128, 60)], False),
+        "outlier": ([1000] + [16] * 127, False, 128,
+                    [(16, 127), (1024, 1)]),
+        # larger than one chunk: cut in class order, the first run split
+        # where the 256 class starts
+        "mixed-200": ([12] * 30 + [20] * 20 + [40] * 20 + [200] * 130,
+                      False, 128, [(64, 70), (256, 58), (256, 72)]),
+        # the four-chip mesh's (or an unpipelined host's) 512-row chunk
+        "mesh-512": ([12] * 100 + [40] * 100 + [100] * 312, False, 512,
+                     [(128, 512)]),
+        "mesh-128": (np.linspace(12, 110, 128).round(), False, 512,
+                     [(128, 128)]),
+        "mesh-129": (np.linspace(12, 110, 129).round(), False, 512,
+                     [(64, 69), (128, 60)]),
+        # each split saves more than a chunk's cost: 32|64, then 16|32
+        "split-16-32-64": ([10] * 128 + [17] * 128 + [30] * 128
+                           + [60] * 128, False, 512,
+                           [(16, 128), (32, 256), (64, 128)]),
+        # one class: nothing to split
+        "full-64": ([60, 61, 62, 64], False, 128, [(64, 4)]),
+        # cells count per chunk, as dispatch pads them: one short trace
+        # past a full chunk is not split off the 512 it sorts into
+        "chunk-aware-513": ([64] * 512 + [16], False, 512,
+                            [(64, 512), (64, 1)]),
+        # the split-16-32-64 group under the coarse_buckets rung
+        "coarse-rung": ([10] * 128 + [17] * 128 + [30] * 128 + [60] * 128,
+                        True, 512, [(64, 512)]),
+        # a backfill call: 503 windows of 480-1,024 points
+        "backfill": (np.linspace(480, 1024, 503).round(), False, 128,
+                     [(1024, 128), (1024, 128), (1024, 128), (1024, 119)]),
     }
 
     @pytest.mark.parametrize("case", sorted(PLANS))
     def test_chunk_plan(self, case, monkeypatch):
-        raws, spec, chunk, want, merged = self.PLANS[case]
-        monkeypatch.setenv("REPORTER_TPU_BUCKETS", spec)
-        profiler.reset()
+        raws, coarse, chunk, want = self.PLANS[case]
+        monkeypatch.setattr(batchpad, "_pressure_coarse", coarse)
         raws = np.asarray(raws, dtype=np.int64)
         group = np.arange(len(raws), dtype=np.int64)
-        before = metrics.default.counter("decode.bucket.coalesced")
-        plan = SegmentMatcher._plan_chunks(group, raws, None, chunk)
-        assert [(T, len(part)) for T, part, _ in plan] == want
-        assert all(c == merged for _, _, c in plan)
-        covered = np.concatenate([part for _, part, _ in plan])
+        plan = plan_chunks(raws, group, chunk, None)
+        assert [(T, len(part)) for T, part in plan] == want
+        covered = np.concatenate([part for _, part in plan])
         assert sorted(covered.tolist()) == group.tolist()
-        assert metrics.default.counter("decode.bucket.coalesced") \
-            == before + merged
 
     @pytest.mark.parametrize("case", ["sparse", "1hz", "1hz-split",
-                                      "mesh-128"])
-    def test_coalesced_shape_is_warmed(self, case, monkeypatch):
-        """The benchmark's warm-up builds every shape a merge forms:
-        pow2 rows up to the dispatcher's cap, pow2 T from the ladder's
-        floor to the longest trace's bucket."""
+                                      "mesh-128", "backfill"])
+    def test_planned_shape_is_warmed(self, case, monkeypatch):
+        """Both warm-ups build every shape the plan forms: the
+        benchmark's served warm-up (pow2 rows up to the dispatcher's
+        cap, each pow2 T pinned in turn) and ``SegmentMatcher.warm``'s
+        (pow2 rows up to a chunk, pow2 T from the ladder's floor to the
+        longest trace's bucket)."""
         from reporter_tpu.matcher.batchpad import padded_batch_rows
         monkeypatch.syspath_prepend(os.path.join(REPO, "benchmark"))
         import serve_http
-        warmed = set()
+        served = set()
 
         class Service:  # records the (rows, T) each warm batch forms
             class dispatcher:
@@ -483,18 +431,20 @@ class TestAdaptiveBucketing:
 
             @staticmethod
             def report_many(reqs):
-                warmed.add((padded_batch_rows(len(reqs), None),
+                served.add((padded_batch_rows(len(reqs), None),
                             bucket_ladder()[0][0]))
                 return [{}] * len(reqs)
 
-        serve_http.warm_shapes(Service, [{}] * 256, 256)
-        raws, _spec, _chunk, _want, _merged = self.PLANS[case]
-        raws = np.asarray(raws, dtype=np.int64)
-        profiler.reset()
-        plan = SegmentMatcher._plan_chunks(
-            np.arange(len(raws), dtype=np.int64), raws, None, 128)
-        for T, part, _ in plan:
+        raws = np.asarray(self.PLANS[case][0], dtype=np.int64)
+        top = int(raws.max())
+        serve_http.warm_shapes(Service, [{}] * 256, top)
+        warmed = {(padded_batch_rows(n, None), T)
+                  for n, T in SegmentMatcher._warm_shapes(top, 128)}
+        plan = plan_chunks(raws, np.arange(len(raws), dtype=np.int64),
+                           128, None)
+        for T, part in plan:
             assert (padded_batch_rows(len(part), None), T) in warmed
+            assert (padded_batch_rows(len(part), None), T) in served
 
     @staticmethod
     def _cut(reqs, lengths):
@@ -505,16 +455,15 @@ class TestAdaptiveBucketing:
     @pytest.mark.skipif(
         not __import__("reporter_tpu.native", fromlist=["available"])
         .available(), reason="the chunk plan lives in the native dispatch")
-    @pytest.mark.parametrize("lengths,chunk,chunks,merged", [
-        ((12, 12, 40, 40, 60, 60), "", 1, 1),  # three buckets, merged
-        ((60,) * 6, "", 1, 0),                 # one bucket, one chunk
-        ((12, 40, 60, 60) * 4, "8", 3, 0),     # over a chunk of 8 rows
+    @pytest.mark.parametrize("lengths,chunk,chunks", [
+        ((12, 12, 40, 40, 60, 60), "", 1),  # three classes, one chunk
+        ((60,) * 6, "", 1),                 # one class, one chunk
+        ((12, 40, 60, 60) * 4, "8", 2),     # over a chunk of 8 rows
     ])
     def test_chunk_counters(self, city, monkeypatch, lengths, chunk,
-                            chunks, merged):
-        """``decode.chunks`` counts every chunk planned,
-        ``decode.bucket.coalesced`` every merge; a merged chunk's
-        padding stays out of the per-T waste the splitter reads."""
+                            chunks):
+        """``decode.chunks`` counts every chunk planned, and each
+        chunk's wide event feeds the lifetime padding waste."""
         monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
         monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", chunk)
         m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
@@ -522,36 +471,30 @@ class TestAdaptiveBucketing:
                                      max_edges=14), lengths)
         profiler.reset()
         c0 = metrics.default.counter("decode.chunks")
-        m0 = metrics.default.counter("decode.bucket.coalesced")
         m.match_many(reqs)
         assert metrics.default.counter("decode.chunks") - c0 == chunks
-        assert metrics.default.counter("decode.bucket.coalesced") - m0 \
-            == merged
         assert profiler.padding_waste() is not None
-        recorded = [profiler.bucket_waste(T) for T in (16, 32, 64)]
-        assert (recorded == [None] * 3) == bool(merged)
         profiler.reset()
 
     @pytest.mark.skipif(
         not __import__("reporter_tpu.native", fromlist=["available"])
         .available(), reason="the chunk plan lives in the native dispatch")
-    def test_coalesced_results_byte_identical(self, city, monkeypatch):
-        """Merging changes shapes, never bytes: the same 16 mixed traces
-        as one merged chunk and as the per-bucket plan's chunks (a chunk
-        of 8 rows) give the same report bodies."""
+    def test_plan_chunking_results_byte_identical(self, city, monkeypatch):
+        """Cutting a group into more chunks changes shapes, never bytes:
+        the same 16 mixed traces as one chunk and as two chunks of 8
+        rows give the same report bodies."""
         monkeypatch.setenv("REPORTER_TPU_DECODE", "scan")
         m = SegmentMatcher(net=city, params=MatchParams(max_candidates=6))
         reqs = self._cut(_mixed_reqs(city, n=16, seed=31, max_edges=14),
                          (12, 18, 30, 60) * 4)
-        before = metrics.default.counter("decode.bucket.coalesced")
+        c0 = metrics.default.counter("decode.chunks")
         monkeypatch.setenv("REPORTER_TPU_DECODE_CHUNK", "8")
-        per_bucket = _bodies(m.match_many(reqs))
-        assert metrics.default.counter("decode.bucket.coalesced") == before
+        two = _bodies(m.match_many(reqs))
+        assert metrics.default.counter("decode.chunks") - c0 == 2
         monkeypatch.delenv("REPORTER_TPU_DECODE_CHUNK")
-        merged = _bodies(m.match_many(reqs))
-        assert metrics.default.counter("decode.bucket.coalesced") \
-            == before + 1
-        assert merged == per_bucket
+        one = _bodies(m.match_many(reqs))
+        assert metrics.default.counter("decode.chunks") - c0 == 3
+        assert one == two
         profiler.reset()
 
     def test_chunks_per_batch_reader(self, monkeypatch):
@@ -607,22 +550,6 @@ class TestMultichipGate:
         ], {"4": 0.9})
         passed, verdict = perf_gate.gate_multichip(path, 0.5)
         assert passed, verdict
-
-    def test_padding_waste_gate_skip_and_fail(self):
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import perf_gate
-        # an EXPLICIT native-less skip passes with a note
-        ok, v = perf_gate.gate_padding_waste(
-            {"source": "a", "bucketing": {"skipped": "no native"}}, 0.1)
-        assert ok and "skipped" in v["note"]
-        # a silently missing block still fails loudly
-        ok, _v = perf_gate.gate_padding_waste({"source": "a"}, 0.1)
-        assert not ok
-        # and the ceiling binds
-        ok, _v = perf_gate.gate_padding_waste(
-            {"source": "a", "bucketing": {"adaptive_waste": 0.2,
-                                          "fixed_waste": 0.5}}, 0.1)
-        assert not ok
 
 
 class TestLedgerLegacyScope:

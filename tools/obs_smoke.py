@@ -282,12 +282,10 @@ def leg_service() -> int:
             return fail(f"second same-shape request recompiled: "
                         f"decode.compile.count {c0} -> {c1}")
 
-        # mixed-length batch through one dispatcher round trip. Lengths
-        # chosen so the 64-bucket pair (40, 45) rides ONE multi-trace
-        # chunk: both sit above the 32 rung, so the ISSUE-13 adaptive
-        # splitter (which would break a (12, 25, 40) mix into 1-trace
-        # pow2 sub-batches) has nothing to reclaim and the wide-event
-        # assertions below still see a >=2-trace chunk
+        # mixed-length batch through one dispatcher round trip. The
+        # chunk plan decodes the three as ONE chunk (a split would save
+        # far fewer padded cells than a chunk costs), so the wide-event
+        # assertions below see a >=2-trace chunk
         mixed = []
         for i, n_pts in enumerate((12, 40, 45)):
             r = _request(city, f"mix-{i}", seed=20 + i)

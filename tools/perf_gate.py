@@ -217,41 +217,6 @@ def gate_shares_absolute(candidate: dict, max_shares: dict
     return (not verdict["failures"]), verdict
 
 
-def gate_padding_waste(candidate: dict, ceiling: float
-                       ) -> Tuple[bool, dict]:
-    """Hard ceiling on the adaptive-bucket leg's padding waste
-    (``--max-padding-waste 0.10``): the ISSUE-13 acceptance number —
-    the mixed-length bench batch's decoded point slots must stay
-    mostly real probes, not pad. Reads the bench artifact's
-    ``bucketing.adaptive_waste`` (the after-leg of the before/after
-    pair); a candidate without the block fails loudly — the ceiling
-    the caller believes binds must never be skipped silently. The one
-    exception is an EXPLICIT skip (``bucketing.skipped``, recorded by
-    bench.py when the native runtime is absent): a declared
-    native-less run passes with the note carried into the verdict —
-    nothing regressed, the leg just cannot run there."""
-    bucketing = candidate.get("bucketing") or {}
-    waste = bucketing.get("adaptive_waste")
-    verdict: dict = {"candidate": {"source": candidate.get("source"),
-                                   "bucketing": bucketing or None},
-                     "max_padding_waste": ceiling, "failures": []}
-    if bucketing.get("skipped"):
-        verdict["note"] = f"bucketing leg skipped: {bucketing['skipped']}"
-        return True, verdict
-    if waste is None:
-        verdict["failures"].append(
-            {"check": "padding_waste", "reason": "candidate records no "
-             "bucketing.adaptive_waste to hold under the ceiling"})
-    elif waste > ceiling:
-        verdict["failures"].append(
-            {"check": "padding_waste", "candidate": waste,
-             "ceiling": ceiling,
-             "reason": f"adaptive-bucket padding waste {waste} exceeds "
-             f"the hard ceiling {ceiling} (fixed-ladder leg recorded "
-             f"{bucketing.get('fixed_waste')})"})
-    return (not verdict["failures"]), verdict
-
-
 def gate_query_ratio(candidate: dict, floor: float) -> Tuple[bool, dict]:
     """Floor on the serving-tier batched-query speedup
     (``--min-query-ratio 5``): the ISSUE-14 acceptance number — ONE
@@ -529,12 +494,6 @@ def main(argv=None) -> int:
                         help="hard absolute ceiling on a candidate "
                         "stage share (repeatable), e.g. report=0.2 — "
                         "checked in addition to the median gate")
-    parser.add_argument("--max-padding-waste", type=float, default=None,
-                        metavar="CEIL",
-                        help="hard ceiling on the candidate's adaptive-"
-                        "bucket padding waste (bucketing.adaptive_waste"
-                        " from bench.py's before/after pair), e.g. 0.10"
-                        " — checked in addition to the median gate")
     parser.add_argument("--min-query-ratio", type=float, default=None,
                         metavar="FLOOR",
                         help="floor on the candidate's batched-query "
@@ -564,14 +523,13 @@ def main(argv=None) -> int:
             max_shares[stage.strip()] = float(ceil)
         except ValueError:
             parser.error(f"--max-share wants STAGE=CEIL, got {spec!r}")
-    if (max_shares or args.max_padding_waste is not None
-            or args.min_query_ratio is not None) \
+    if (max_shares or args.min_query_ratio is not None) \
             and (args.bigreplay or args.multichip or args.feed):
-        # those artifacts carry no stage shares / bucketing block —
-        # refuse loudly rather than silently ignoring a ceiling the
-        # caller believes binds
-        parser.error("--max-share/--max-padding-waste/--min-query-ratio "
-                     "apply to --candidate/--self-check runs only")
+        # those artifacts carry no stage shares / query block — refuse
+        # loudly rather than silently ignoring a ceiling the caller
+        # believes binds
+        parser.error("--max-share/--min-query-ratio apply to "
+                     "--candidate/--self-check runs only")
 
     if args.bigreplay:
         passed, verdict = gate_bigreplay(args.bigreplay,
@@ -646,13 +604,6 @@ def main(argv=None) -> int:
         verdict["max_shares"] = abs_verdict["max_shares"]
         verdict["failures"].extend(abs_verdict["failures"])
         passed = passed and abs_ok
-
-    if args.max_padding_waste is not None:
-        pw_ok, pw_verdict = gate_padding_waste(candidate,
-                                               args.max_padding_waste)
-        verdict["max_padding_waste"] = args.max_padding_waste
-        verdict["failures"].extend(pw_verdict["failures"])
-        passed = passed and pw_ok
 
     if args.min_query_ratio is not None:
         qr_ok, qr_verdict = gate_query_ratio(candidate,
