@@ -186,7 +186,8 @@ METRICS: Dict[str, str] = {
     # pipeline
     "pipeline.gather": "backfill stage 1 (timer)",
     "pipeline.gather.lines": "stage 1 probe lines read",
-    "pipeline.gather.probes": "stage 1 probes kept and sharded",
+    "pipeline.gather.probes": "stage 1 probes kept and appended to "
+                              "their shard's columnar block",
     "pipeline.gather.uuid_hashes": "stage 1 shard hashes, one per distinct "
                                    "uuid of a part",
     "pipeline.gather.hour_epochs": "stage 1 hour epochs, one per distinct "
@@ -195,7 +196,12 @@ METRICS: Dict[str, str] = {
                                 "dead gather worker",
     "pipeline.match": "backfill stage 2 (timer)",
     "pipeline.report": "backfill stage 3 (timer)",
-    "pipeline.parse": "stage 2 shard read, argsort and window cut (timer)",
+    "pipeline.parse": "stage 2 shard blocks read, one (uuid, time) sort "
+                      "and window cut (timer)",
+    "pipeline.parse.probes": "stage 2 records read from shard blocks; "
+                             "equals pipeline.gather.probes on a sound run",
+    "pipeline.parse.torn_blocks": "stage 2 shard blocks skipped as cut "
+                                  "short at a file's end",
     "pipeline.match_many": "stage 2 device batch call (timer)",
     "pipeline.rows": "stage 2 report, seam filter and tile-row append "
                      "(timer); tile rows appended (counter)",

@@ -7,11 +7,13 @@ the TPU. Same three stages, same artifacts:
 1. **gather_traces** — list + download part files (S3 via boto3 when
    configured and available, else a local directory), parse each line with
    a user-supplied ``--src-valuer`` lambda, bbox-filter, cap accuracy at
-   1000 m, and shard lines into files by ``sha1(uuid)[:3]``
+   1000 m, and shard probes into files by ``sha1(uuid)[:3]``
    (reference: :87-129, :256-276). IO-bound -> process fan-out.
 
 2. **match_traces** — per shard: group by uuid, sort by time, split into
-   windows at ``--inactivity`` gaps (reference: :149-163). THE redesign:
+   windows at ``--inactivity`` gaps (reference: :149-163). Stage 2 reads
+   the gather's typed values as arrays; it parses no text (below). THE
+   redesign:
    instead of one C++ ``Match`` call per window (reference: :164-168 — the
    hot loop), *windows go to the device ``--device-batch`` at a time,
    pooled across shards,* via ``SegmentMatcher.match_many``; ``report()``
@@ -29,6 +31,26 @@ Stage-level resume via --trace-dir / --match-dir is preserved
 ``pipeline.parse``, ``pipeline.match_many`` and ``pipeline.rows``) and
 counts its windows, points, batches, rows and failures (``pipeline.*``
 counters), so the CLI and any other caller time the same code.
+
+**Shard files** (the trace dir between stages 1 and 2; the reference's
+are CSV text) are named ``{shard}.{pid}``: one per shard and gather
+worker. Each part a worker gathers appends one block to each shard file
+it has probes for, whole, once the part is parsed, so a part that raises
+writes nothing and a worker killed after k parts leaves k whole blocks
+and at most one torn one at the end. A block, little-endian:
+
+- header, 32 bytes: the tag ``BLOCK_TAG`` (``RTSHARD`` and a version
+  byte, 1), then uint64 uuid count, record count and uuid-table bytes;
+- uuid table: int32 UTF-8 byte length of each uuid, then the uuids'
+  UTF-8 bytes, zero-padded to a multiple of 8 bytes; a uuid's code is
+  its index, in order of its first record in the block;
+- records, 32 bytes each, in input order: epoch int64, lat float64,
+  lon float64, accuracy int32, uuid code int32.
+
+Stage 2 skips and counts a torn block (``pipeline.parse.torn_blocks``)
+and refuses a file that holds anything else, such as a CSV trace dir an
+earlier build gathered: gather that again. ``pipeline.parse.probes``
+equals ``pipeline.gather.probes`` on a sound run.
 """
 from __future__ import annotations
 
@@ -50,6 +72,7 @@ _MP = multiprocessing.get_context("spawn")
 import os
 import re
 import shutil
+import struct
 import sys
 import tempfile
 import time
@@ -64,6 +87,32 @@ logger = logging.getLogger("reporter_tpu.pipeline")
 
 MAX_ACCURACY_M = 1000  # reference: simple_reporter.py:112
 
+# the shard block (module docstring); the tag's last byte is the version
+BLOCK_TAG = b"RTSHARD\x01"
+_HEADER = struct.Struct("<8sQQQ")  # tag, uuids, records, uuid-table bytes
+_RECORD = struct.Struct("<qddi")  # epoch, lat, lon, accuracy ...
+_CODE = struct.Struct("<i")  # ... then the uuid's code in the block
+RECORD_BYTES = _RECORD.size + _CODE.size
+
+
+class _Block:
+    """One part's records for one shard, as the gather appends them."""
+
+    __slots__ = ("uuids", "records")
+
+    def __init__(self):
+        self.uuids: list[str] = []  # code -> uuid, in order of first record
+        self.records = bytearray()
+
+    def encode(self) -> bytes:
+        names = [u.encode() for u in self.uuids]
+        table = struct.pack(f"<{len(names)}i", *map(len, names)) \
+            + b"".join(names)
+        table += bytes(-len(table) % 8)  # records start 8-byte aligned
+        return _HEADER.pack(BLOCK_TAG, len(names),
+                            len(self.records) // RECORD_BYTES,
+                            len(table)) + table + self.records
+
 
 # --------------------------------------------------------------------------
 # stage 1: gather
@@ -71,7 +120,8 @@ MAX_ACCURACY_M = 1000  # reference: simple_reporter.py:112
 
 def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
                      bbox: List[float], dest_dir: str) -> tuple:
-    """Parse one downloaded part file into uuid-sharded trace files.
+    """Parse one downloaded part file into uuid-sharded trace files: one
+    columnar block appended to each shard file it has probes for.
 
     Returns ``(lines, probes, uuid_hashes, hour_epochs)``: lines read,
     probes kept, distinct uuids hashed and hour epochs computed. A part
@@ -86,9 +136,10 @@ def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
     uuid_filter = host_uuid_filter()
     fast_time = time_pattern == "%Y-%m-%d %H:%M:%S"
     opener = gzip.open if path.endswith(".gz") else open
-    shards: dict[str, list[str]] = {}
-    # str(uuid) -> its shard's row list, or None where another host owns it
-    rows_of: dict[str, list | None] = {}
+    shards: dict[str, _Block] = {}
+    # str(uuid) -> [its shard's block, its code there packed, or None until
+    # its first record], or None where another host owns it
+    block_of: dict[str, list | None] = {}
     hour_epoch: dict[str, int] = {}  # "YYYY-MM-DD HH" -> epoch of the hour
     lines = count = 0
     with opener(path, "rt") as f:
@@ -98,14 +149,17 @@ def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
                 uuid, tm, lat, lon, acc = valuer(line)
                 key = str(uuid)
                 try:
-                    rows = rows_of[key]
+                    entry = block_of[key]
                 except KeyError:
-                    rows = None
+                    entry = None
                     if uuid_filter is None or uuid_filter(key):
                         shard = hashlib.sha1(key.encode()).hexdigest()[:3]
-                        rows = shards.setdefault(shard, [])
-                    rows_of[key] = rows
-                if rows is None:
+                        block = shards.get(shard)
+                        if block is None:
+                            block = shards[shard] = _Block()
+                        entry = [block, None]
+                    block_of[key] = entry
+                if entry is None:
                     continue
                 lat = float(lat)
                 lon = float(lon)
@@ -128,19 +182,28 @@ def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
                 else:
                     epoch = int(tm)
                 acc = min(int(math.ceil(float(acc))), MAX_ACCURACY_M)
+                # struct.error where a value does not fit its column
+                record = _RECORD.pack(epoch, lat, lon, acc)
             except Exception:
                 continue
-            rows.append(f"{uuid},{epoch},{lat},{lon},{acc}\n")
+            block, code = entry
+            if code is None:
+                # codes follow first records, so the uuid table lists
+                # exactly the uuids the block's records name
+                code = entry[1] = _CODE.pack(len(block.uuids))
+                block.uuids.append(key)
+            block.records += record
+            block.records += code
             count += 1
     # one shard file per worker process (suffix = pid): concurrent gather
-    # workers never share a file, so no interleaved/torn rows — stage 2
+    # workers never share a file, so no interleaved/torn blocks — stage 2
     # walks every file in the directory regardless of suffix
     pid = os.getpid()
-    for shard, rows in shards.items():
-        if rows:  # a shard whose uuids kept no line gets no empty file
-            with open(os.path.join(dest_dir, f"{shard}.{pid}"), "a") as f:
-                f.write("".join(rows))
-    return lines, count, len(rows_of), len(hour_epoch)
+    for shard, block in shards.items():
+        if block.uuids:  # a shard whose uuids kept no probe gets no file
+            with open(os.path.join(dest_dir, f"{shard}.{pid}"), "ab") as f:
+                f.write(block.encode())
+    return lines, count, len(block_of), len(hour_epoch)
 
 
 def _gather_worker(paths: List[str], valuer_src: str, time_pattern: str,
@@ -310,45 +373,94 @@ def _windows_of(points: List[dict], inactivity: int,
         yield points[lo:hi]
 
 
+def _read_shard(paths: List[str]) -> tuple:
+    """Every whole block of a shard's files, in the order given:
+    ``(uuids, records, codes, torn)``. ``records`` is a structured array
+    (epoch, lat, lon, acc, code) in file order, ``codes`` each record's
+    index into ``uuids``, which lists the shard's uuids in order of
+    first record, and ``torn`` the blocks cut short at a file's end (a
+    gather worker killed mid-write), which are skipped.
+
+    Raises ValueError for a file that holds anything but blocks, such as
+    a CSV trace dir an earlier build gathered."""
+    import numpy as np
+
+    rec_dtype = np.dtype([("epoch", "<i8"), ("lat", "<f8"), ("lon", "<f8"),
+                          ("acc", "<i4"), ("code", "<i4")])
+    code_of: dict[str, int] = {}
+    records, codes = [], []
+    torn = 0
+    for path in paths:
+        with open(path, "rb") as f:
+            buf = f.read()
+        off = 0
+        while off < len(buf):
+            head = buf[off:off + _HEADER.size]
+            if head[:len(BLOCK_TAG)] != BLOCK_TAG[:len(head)]:
+                raise ValueError(
+                    f"{path} holds no shard block at byte {off}: the trace "
+                    f"dir was not gathered by this build; gather it again")
+            if len(head) < _HEADER.size:
+                torn += 1
+                break
+            _tag, n_uuids, n_records, table_bytes = _HEADER.unpack(head)
+            start = off + _HEADER.size
+            off = start + table_bytes + n_records * RECORD_BYTES
+            if off > len(buf):
+                torn += 1
+                break
+            remap = np.empty(n_uuids, np.int32)
+            pos = start + 4 * n_uuids
+            for i, n in enumerate(struct.unpack_from(f"<{n_uuids}i", buf,
+                                                     start)):
+                name = buf[pos:pos + n].decode()
+                remap[i] = code_of.setdefault(name, len(code_of))
+                pos += n
+            recs = np.frombuffer(buf, rec_dtype, n_records,
+                                 start + table_bytes)
+            records.append(recs)
+            codes.append(remap[recs["code"]])
+    if not records:
+        return [], np.zeros(0, rec_dtype), np.zeros(0, np.int32), torn
+    return (list(code_of), np.concatenate(records), np.concatenate(codes),
+            torn)
+
+
 def _shard_windows(paths: List[str], inactivity: int, holdback_s: int,
                    options: dict) -> list:
     """One shard's matcher windows as columnar parts (uuid, lat, lon,
-    time, accuracy, options) over array slices, each uuid's in time
-    order.
+    time, accuracy, options) over array slices: uuids in order of first
+    record, each uuid's points in time order, ties in file order.
 
-    Columnar parse: per-uuid coordinate LISTS (one append per row, never
-    a point dict), then arrays + argsort per uuid. The chunker's holdback
-    must equal report()'s threshold: report withholds segments starting
-    within threshold_sec of a chunk's end, and the next chunk re-presents
-    exactly that span."""
+    One stable sort of the shard's records by (uuid, time), then each
+    uuid's run is cut into windows. The chunker's holdback must equal
+    report()'s threshold: report withholds segments starting within
+    threshold_sec of a chunk's end, and the next chunk re-presents
+    exactly that span. Counts ``pipeline.parse.probes`` and
+    ``pipeline.parse.torn_blocks``."""
     import numpy as np
 
-    by_uuid: dict[str, tuple] = {}
-    for path in paths:
-        with open(path) as f:
-            for line in f:
-                try:
-                    uuid, tm, lat, lon, acc = line.strip().split(",")
-                    cols = by_uuid.get(uuid)
-                    if cols is None:
-                        cols = by_uuid[uuid] = ([], [], [], [])
-                    cols[0].append(int(tm))
-                    cols[1].append(float(lat))
-                    cols[2].append(float(lon))
-                    cols[3].append(int(acc))
-                except ValueError:
-                    continue
+    uuids, records, codes, torn = _read_shard(paths)
+    metrics.count("pipeline.parse.probes", len(codes))
+    if torn:
+        metrics.count("pipeline.parse.torn_blocks", torn)
+        logger.error("Skipped %d torn blocks in %s", torn, paths)
+    if not len(codes):
+        return []
+    order = np.lexsort((records["epoch"], codes))
+    codes = codes[order]
+    tm = records["epoch"][order].astype(np.float64)
+    la = records["lat"][order]
+    lo = records["lon"][order]
+    ac = records["acc"][order].astype(np.float32)
+    cuts = (np.flatnonzero(np.diff(codes)) + 1).tolist()
     parts = []
-    for uuid, (tms, lats, lons, accs) in by_uuid.items():
-        tm = np.asarray(tms, dtype=np.float64)
-        order = np.argsort(tm, kind="stable")
-        tm = tm[order]
-        la = np.asarray(lats, dtype=np.float64)[order]
-        lo_ = np.asarray(lons, dtype=np.float64)[order]
-        ac = np.asarray(accs, dtype=np.float32)[order]
-        for a, b in _window_spans(tm, inactivity, holdback_s=holdback_s):
-            parts.append((uuid, la[a:b], lo_[a:b], tm[a:b], ac[a:b],
-                          options))
+    for a, b in zip([0] + cuts, cuts + [len(codes)]):
+        uuid = uuids[codes[a]]
+        for lo_, hi_ in _window_spans(tm[a:b], inactivity,
+                                      holdback_s=holdback_s):
+            s = slice(a + lo_, a + hi_)
+            parts.append((uuid, la[s], lo[s], tm[s], ac[s], options))
     return parts
 
 
@@ -626,7 +738,13 @@ def main(argv=None):
                         default=multiprocessing.cpu_count())
     parser.add_argument("--bbox", type=_bbox,
                         default=[-90.0, -180.0, 90.0, 180.0])
-    parser.add_argument("--trace-dir", help="resume: pre-gathered traces")
+    parser.add_argument(
+        "--trace-dir",
+        help="resume: a trace dir this build's stage 1 gathered (shard "
+             "files of columnar blocks: a 32-byte header tagged RTSHARD, "
+             "a uuid table, 32-byte records of epoch, lat, lon, accuracy "
+             "and uuid code; see the module docstring); a CSV trace dir "
+             "of an earlier build is refused and must be gathered again")
     parser.add_argument("--match-dir", help="resume: pre-matched segments")
     parser.add_argument("--device-batch", type=int, default=512)
     parser.add_argument("--cleanup", action=argparse.BooleanOptionalAction,

@@ -1,6 +1,7 @@
 """Batch pipeline tests: gather -> batched match -> tile report, end to end
 over local files (the reference's S3 path is gated off in this image)."""
 import gzip
+import hashlib
 import os
 
 import numpy as np
@@ -383,9 +384,10 @@ def test_warm_builds_every_shape_match_many_forms(city, monkeypatch):
 
 
 def _parse_part_file_per_line(path, valuer, time_pattern, bbox, dest_dir):
-    """The gather's parse as it was before its memos: a shard hash, a
-    host verdict and a calendar epoch for every line. The oracle for
-    ``_parse_part_file``'s shard files."""
+    """The gather's parse as it was before its memos and its blocks: a
+    shard hash, a host verdict and a calendar epoch for every line, and
+    each probe a CSV text row. The oracle for ``_parse_part_file``'s
+    shard files."""
     import calendar
     import hashlib
     import math
@@ -460,7 +462,8 @@ def _gather_cases_part(path):
 
 class TestGather:
     """Stage 1: a uuid's shard and an hour's epoch are computed once per
-    part; the shard files are the per-line parse's, byte for byte."""
+    part; the shard files decode to the per-line parse's records, file
+    for file and in order."""
 
     @pytest.mark.parametrize("hosts", ["one", "two"])
     @pytest.mark.parametrize("time_pattern",
@@ -487,13 +490,12 @@ class TestGather:
         _parse_part_file_per_line(part, valuer, time_pattern, bbox, str(old))
         n_lines, probes, hashes, hours = _parse_part_file(
             part, valuer, time_pattern, bbox, str(new))
-        want = {f: (old / f).read_bytes() for f in os.listdir(old)}
-        got = {f: (new / f).read_bytes() for f in os.listdir(new)}
+        want = {f: _text_records(old / f) for f in os.listdir(old)}
+        got = {f: _block_records(new / f) for f in os.listdir(new)}
         assert want and got == want
         assert n_lines == len(lines)
-        assert probes == sum(b.count(b"\n") for b in want.values())
-        kept = {b.split(b",")[0] for v in want.values()
-                for b in v.splitlines()}
+        assert probes == sum(len(v) for v in want.values())
+        kept = {r[0] for v in want.values() for r in v}
         assert len(kept) == 40 if hosts == "one" else 0 < len(kept) < 40
         assert hashes == 42  # the 40 vehicles, veh-x and the all-x line
         # 22:58:30 on 31 December to 01:05:08 on 1 January
@@ -554,12 +556,8 @@ class TestGather:
             out = gather_traces(src, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
                                 [-90.0, -180.0, 90.0, 180.0], concurrency=2)
             after = metrics.default.snapshot()["counters"]
-            rows = []
-            for r, _d, fs in os.walk(out):
-                for f in fs:
-                    with open(os.path.join(r, f)) as fh:
-                        rows += fh.readlines()
-            rows.sort()
+            rows = sorted(rec for r, _d, fs in os.walk(out) for f in fs
+                          for rec in _block_records(os.path.join(r, f)))
             return {k: v - before.get(k, 0) for k, v in after.items()
                     if k.startswith("pipeline.gather")}, rows
 
@@ -578,3 +576,274 @@ class TestGather:
         assert c3["pipeline.gather_failures"] == 1
         assert c3["pipeline.gather.probes"] == n_lines
         assert rows3 == rows
+
+
+def _text_records(path):
+    """(uuid, epoch, lat, lon, accuracy) of each row of a CSV text shard
+    file, in file order."""
+    with open(path) as f:
+        return [(u, int(t), float(la), float(lo), int(a))
+                for u, t, la, lo, a in (ln.rstrip("\n").split(",")
+                                        for ln in f)]
+
+
+def _block_records(path):
+    """(uuid, epoch, lat, lon, accuracy) of each record of a shard file's
+    blocks, in file order."""
+    from reporter_tpu.pipeline.simple_reporter import _read_shard
+    uuids, recs, codes, torn = _read_shard([str(path)])
+    assert torn == 0
+    return [(uuids[c], int(r["epoch"]), float(r["lat"]), float(r["lon"]),
+             int(r["acc"])) for r, c in zip(recs, codes.tolist())]
+
+
+def _text_shard_windows(paths, inactivity, holdback_s, options):
+    """Stage 2's parse of CSV text shards as it was before the blocks: a
+    line at a time, per-uuid lists, a stable argsort per uuid. The oracle
+    for ``_shard_windows``."""
+    by_uuid = {}
+    for path in paths:
+        with open(path) as f:
+            for line in f:
+                try:
+                    uuid, tm, lat, lon, acc = line.strip().split(",")
+                    cols = by_uuid.get(uuid)
+                    if cols is None:
+                        cols = by_uuid[uuid] = ([], [], [], [])
+                    cols[0].append(int(tm))
+                    cols[1].append(float(lat))
+                    cols[2].append(float(lon))
+                    cols[3].append(int(acc))
+                except ValueError:
+                    continue
+    from reporter_tpu.pipeline.simple_reporter import _window_spans
+    parts = []
+    for uuid, (tms, lats, lons, accs) in by_uuid.items():
+        tm = np.asarray(tms, dtype=np.float64)
+        order = np.argsort(tm, kind="stable")
+        tm = tm[order]
+        la = np.asarray(lats, dtype=np.float64)[order]
+        lo_ = np.asarray(lons, dtype=np.float64)[order]
+        ac = np.asarray(accs, dtype=np.float32)[order]
+        for a, b in _window_spans(tm, inactivity, holdback_s=holdback_s):
+            parts.append((uuid, la[a:b], lo_[a:b], tm[a:b], ac[a:b],
+                          options))
+    return parts
+
+
+def _probe_part(path, vehicles, rng):
+    """A part of pipe-separated lines for ``vehicles`` {uuid: points},
+    shuffled across uuids and in time: times repeat (ties), gaps beyond
+    30 s split windows, and the 2,500-point vehicle is chunked."""
+    lines = []
+    for uuid, n in vehicles.items():
+        span = n if n > 1024 else 40 * n
+        for t in 1_500_000_000 + rng.integers(0, span, n):
+            cols = ["x"] * 11
+            cols[0], cols[1] = str(t), uuid
+            cols[5] = str(rng.choice(["3", "7.5", "1200"]))
+            cols[9] = str(40.0 + rng.uniform(0.0, 0.5))
+            cols[10] = str(-74.0 + rng.uniform(0.0, 0.5))
+            lines.append("|".join(cols))
+    rng.shuffle(lines)
+    with gzip.open(path, "wt") as f:
+        f.write("\n".join(lines))
+
+
+def _shard_paths(root):
+    """{shard: its files, sorted} of a trace dir."""
+    by_shard = {}
+    for f in sorted(os.listdir(root)):
+        by_shard.setdefault(f.split(".")[0], []).append(
+            os.path.join(root, f))
+    return by_shard
+
+
+def _parse_counters(fn):
+    """(fn's result, its moves of the pipeline.parse.* counters)."""
+    from reporter_tpu.utils import metrics
+    before = metrics.default.snapshot()["counters"]
+    out = fn()
+    after = metrics.default.snapshot()["counters"]
+    return out, {k: after.get(k, 0) - before.get(k, 0)
+                 for k in ("pipeline.parse.probes",
+                           "pipeline.parse.torn_blocks")}
+
+
+def _assert_same_parts(got, want):
+    assert [p[0] for p in got] == [p[0] for p in want]
+    for g, w in zip(got, want):
+        for a, b in zip(g[1:5], w[1:5]):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        assert g[5] is w[5]
+
+
+class TestShardBlocks:
+    """The columnar shard format between stage 1 and stage 2."""
+
+    def _gather(self, parts, dest, per_line):
+        """Each part of ``parts`` into ``dest`` as today's gather or the
+        per-line text oracle. Of several parts, the first part's files
+        are renamed as if another worker wrote them, so a shard has
+        files of two workers and, from three parts, a file of several
+        parts' blocks."""
+        import functools
+        from reporter_tpu.pipeline.simple_reporter import (
+            DEFAULT_VALUER, _parse_part_file)
+        valuer = eval(DEFAULT_VALUER, {"functools": functools})
+        parse = _parse_part_file_per_line if per_line else _parse_part_file
+        dest.mkdir()
+        for k, part in enumerate(parts):
+            parse(str(part), valuer, "%Y-%m-%d %H:%M:%S",
+                  [-90.0, -180.0, 90.0, 180.0], str(dest))
+            if k == 0 and len(parts) > 1:
+                for f in os.listdir(dest):
+                    if f.endswith(f".{os.getpid()}"):
+                        os.rename(dest / f, dest / f.replace(
+                            f".{os.getpid()}", ".0"))
+
+    def test_stage_2_parts_equal_the_text_parse(self, tmp_path,
+                                                monkeypatch):
+        """Value for value and in order: uuids in order of first probe
+        over the shard's files, ties in time in file order, lat and lon
+        bit for bit, epochs as float64 and accuracies as float32."""
+        from reporter_tpu.pipeline.simple_reporter import _shard_windows
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        rng = np.random.default_rng(3)
+        vehicles = {f"veh-{i}": 300 for i in range(18)}
+        vehicles["veh-long"] = 2500
+        shard_of = (lambda u: hashlib.sha1(u.encode()).hexdigest()[:3])
+        one = [u for u in (f"fleet-{i}" for i in range(200_000))
+               if shard_of(u) == shard_of("veh-long")][:6]
+        vehicles.update((u, 300) for u in one)  # seven uuids in one shard
+        parts = []
+        for k in range(3):
+            parts.append(tmp_path / f"part-{k}.gz")
+            _probe_part(parts[-1], vehicles, rng)
+        self._gather(parts, tmp_path / "text", per_line=True)
+        self._gather(parts, tmp_path / "blocks", per_line=False)
+        text = _shard_paths(tmp_path / "text")
+        blocks = _shard_paths(tmp_path / "blocks")
+        assert sorted(text) == sorted(blocks)
+        assert [os.path.basename(p) for v in text.values() for p in v] \
+            == [os.path.basename(p) for v in blocks.values() for p in v]
+        assert any(len(v) > 1 for v in blocks.values())
+        opts = {"mode": "auto"}
+        n_parts = n_probes = ties = 0
+        for shard, paths in blocks.items():
+            want = _text_shard_windows(text[shard], 30, 15, opts)
+            got, moved = _parse_counters(
+                lambda: _shard_windows(paths, 30, 15, opts))
+            _assert_same_parts(got, want)
+            n_parts += len(got)
+            ties += sum(int(np.sum(np.diff(p[3]) == 0)) for p in got)
+            n_probes += moved["pipeline.parse.probes"]
+            assert moved["pipeline.parse.torn_blocks"] == 0
+        assert n_parts > 3 * len(vehicles) and ties > 0
+        assert len({p[0] for p in _shard_windows(
+            blocks[shard_of("veh-long")], 30, 15, opts)}) == 7
+        assert n_probes == 3 * sum(vehicles.values())
+
+    @pytest.mark.parametrize("cut", ["header", "records"])
+    def test_a_torn_last_block_is_skipped_and_counted(self, tmp_path,
+                                                      monkeypatch, cut):
+        """A worker killed while appending its second block leaves the
+        first whole: stage 2 reads the first block's probes alone and
+        counts one torn block."""
+        from reporter_tpu.pipeline.simple_reporter import (
+            _HEADER, _shard_windows)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        part = tmp_path / "part-0.gz"
+        _probe_part(part, {"veh-torn": 400}, np.random.default_rng(5))
+        self._gather([part], tmp_path / "first", per_line=False)
+        (first,) = os.listdir(tmp_path / "first")
+        whole = (tmp_path / "first" / first).read_bytes()
+        torn = tmp_path / "torn"
+        # the same block again, cut inside its header or its records
+        torn.write_bytes(whole + whole[:_HEADER.size - 5 if cut == "header"
+                                       else len(whole) - 7])
+        want = _shard_windows([str(tmp_path / "first" / first)], 120, 15,
+                              None)
+        got, moved = _parse_counters(
+            lambda: _shard_windows([str(torn)], 120, 15, None))
+        _assert_same_parts(got, want)
+        assert moved == {"pipeline.parse.probes": 400,
+                         "pipeline.parse.torn_blocks": 1}
+
+    def test_a_csv_trace_dir_must_be_gathered_again(self, tmp_path):
+        """A trace dir of CSV text shards, as an earlier build gathered
+        it, is refused by name, never misread."""
+        trace_dir = tmp_path / "traces"
+        trace_dir.mkdir()
+        (trace_dir / "abc.1").write_text(
+            "veh-1,1500000000,40.5,-74.0,5\nveh-1,1500000001,40.5,-74.0,5\n")
+
+        class Unused:
+            def match_many(self, tb):
+                raise AssertionError("no batch may reach the matcher")
+
+        with pytest.raises(ValueError, match="abc.1.*gather it again"):
+            match_traces(str(trace_dir), Unused(), "auto", {0, 1}, {0, 1},
+                         quantisation=3600, inactivity=120, source="test")
+
+    def test_stage_2_reads_every_probe_stage_1_kept(self, day_archive,
+                                                    tmp_path, monkeypatch):
+        """The format's own consistency check: on a sound run
+        ``pipeline.parse.probes`` equals ``pipeline.gather.probes`` and no
+        block is torn."""
+        from reporter_tpu.utils import metrics
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        before = metrics.default.snapshot()["counters"]
+        trace_dir = gather_traces(day_archive, ".*", VALUER,
+                                  "%Y-%m-%d %H:%M:%S",
+                                  [-90.0, -180.0, 90.0, 180.0],
+                                  concurrency=2)
+        seen = []
+
+        class Recorder:
+            def match_many(self, tb):
+                seen.extend(int(n) for n in tb.lengths())
+                raise RuntimeError("recorded")
+
+        match_traces(trace_dir, Recorder(), "auto", {0, 1}, {0, 1},
+                     quantisation=3600, inactivity=120, source="test")
+        after = metrics.default.snapshot()["counters"]
+        c = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("pipeline.gather.probes", "pipeline.parse.probes",
+                       "pipeline.parse.torn_blocks")}
+        assert c["pipeline.parse.probes"] == c["pipeline.gather.probes"] > 0
+        assert c["pipeline.parse.torn_blocks"] == 0
+        assert sum(seen) >= c["pipeline.parse.probes"]
+
+    def test_a_uuid_with_a_comma_survives_both_stages(self, city, tmp_path,
+                                                      monkeypatch):
+        """A uuid is kept whole through the gather and stage 2, whatever
+        it holds; the CSV shards split it at its comma and lost it."""
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        src = tmp_path / "src"
+        src.mkdir()
+        lines = make_part_file(city, str(src / "part-0.gz"), n_traces=2,
+                               seed=9)
+        odd = "fleet,7 \u00e9"
+        with gzip.open(src / "part-0.gz", "wt") as f:
+            f.write("\n".join(ln.replace("veh-9-1", odd) for ln in lines))
+        trace_dir = gather_traces(str(src), ".*", VALUER,
+                                  "%Y-%m-%d %H:%M:%S",
+                                  [-90.0, -180.0, 90.0, 180.0],
+                                  concurrency=1)
+        seen = {}
+
+        class Recorder:
+            def match_many(self, tb):
+                for i in range(len(tb)):
+                    seen[tb.uuid(i)] = seen.get(tb.uuid(i), 0) \
+                        + int(tb.lengths()[i])
+                raise RuntimeError("recorded")
+
+        match_traces(trace_dir, Recorder(), "auto", {0, 1}, {0, 1},
+                     quantisation=3600, inactivity=120, source="test")
+        n_odd = sum(ln.split("|")[1] == "veh-9-1" for ln in lines)
+        assert set(seen) == {"veh-9-0", odd}
+        assert seen[odd] == n_odd > 0
