@@ -183,6 +183,13 @@ METRICS: Dict[str, str] = {
     "replay.tiles.ok": "dead-letter tiles re-egressed successfully",
     "replay.tiles.fail": "dead-letter tile replay attempts that failed",
     "replay.quarantined": "dead-letter entries moved to .quarantine",
+    "stream.points": "messages offered to the streaming worker's run",
+    "stream.windows": "report windows submitted and answered, any path",
+    "stream.ingest": "the worker's run: message loop and drain (timer)",
+    "stream.report_flush": "one batched report submit, pending or "
+                           "eviction flush (timer)",
+    "stream.tiles": "one tile flush: snapshot barrier, cull, egress "
+                    "(timer)",
     # pipeline
     "pipeline.gather": "backfill stage 1 (timer)",
     "pipeline.gather.lines": "stage 1 probe lines read",
@@ -375,8 +382,8 @@ KERNEL_CONTRACTS: Dict[str, str] = {
         "mesh-sharded re-jit of assoc decode (signature owned by "
         "ops/assoc_viterbi.py; needs a Mesh, no stand-alone eval cases)",
     "reporter_tpu/ops/incremental.py::incremental_step_batch":
-        "one-point incremental Viterbi advance -> (N,K) scores + bp "
-        "+ (N,) restart anchors",
+        "R-round incremental Viterbi advance -> (N,K) scores + (R,N,K) "
+        "bp + (R,N) restart anchors",
 }
 
 # ---- device lanes / host-sync whitelist (DP rules) -------------------------

@@ -92,13 +92,13 @@ def _decode_cases(d, extra_statics=None):
 
 
 def _incremental_cases(d):
-    """(dist_m, valid, route_m, gc_m, case, prev_scores, sigma, beta) —
-    one appended kept point for N carried traces (no time axis; N plays
+    """(dist_m, valid, route_m, gc_m, case, live, prev_scores, sigma,
+    beta) — R = 4 appended kept points for N carried traces (N plays
     the batch role B plays in the windowed decode)."""
-    N, K = d["B"], d["K"]
-    return [([((N, K), _F32), ((N, K), _BOOL), ((N, K, K), _F32),
-              ((N,), _F32), ((N,), _I32), ((N, K), _F32),
-              ((), _F32), ((), _F32)], {})]
+    N, K, R = d["B"], d["K"], 4
+    return [([((R, N, K), _F32), ((R, N, K), _BOOL), ((R, N, K, K), _F32),
+              ((R, N), _F32), ((R, N), _I32), ((R, N), _BOOL),
+              ((N, K), _F32), ((), _F32), ((), _F32)], {})]
 
 
 def _relax_cases(d):

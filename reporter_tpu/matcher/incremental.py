@@ -7,8 +7,11 @@ report, forever, per long-lived uuid. This module carries the decode
 forward instead: per uuid it keeps the last-step log-scores (K,), a
 bounded backpointer ring of the **uncommitted** tail, and the compact
 per-step scalars segment assembly actually reads for the committed
-prefix; an appended point then costs one candidate lookup, one route
-row, and one batched device step (``ops/incremental.py``) — flat in T.
+prefix; an appended point then costs a candidate row, one route row,
+and one step of a batched device advance (``ops/incremental.py``) —
+flat in T. A report's appended points are prepped together (one
+candidate lookup, one route call) and advanced together: every trace
+of a flush, up to ``ROUNDS_MAX`` points each, in one dispatch.
 
 Byte-exact parity with the windowed batch path is the design
 constraint, not an aspiration:
@@ -75,6 +78,13 @@ ENV_BUDGET_MB = "REPORTER_TPU_INCREMENTAL_MB"
 
 DEFAULT_LAG = 32
 DEFAULT_BUDGET_MB = 64.0
+
+#: most rounds (appended points a trace) one device dispatch advances;
+#: a dispatch's rounds pad to the next power of four (1, 4, 16, 64) and
+#: its traces to a power of two of at least ``MIN_ROWS``, so the programs
+#: :meth:`IncrementalTable.warm` builds stay few
+ROUNDS_MAX = 64
+MIN_ROWS = 8
 
 
 def incremental_enabled() -> bool:
@@ -143,28 +153,34 @@ class _Ring:
         self.prev_best = int(prev_best)
         self.route_in = route_in      # (K, K) f32 | None (window-first)
 
-    def nbytes(self, K: int) -> int:
+    @staticmethod
+    def nbytes(K: int) -> int:
         return 4 * K * K + 3 * 4 * K + 64
 
 
-class _Step:
-    """Host-prepped inputs for one appended kept point, queued for the
-    batched device step."""
+class _Steps:
+    """Host-prepped inputs for a trace's appended kept points, one row
+    each, queued for the batched device advance."""
 
-    __slots__ = ("kept_idx", "case", "dist_w", "valid", "route_w", "gc_w",
-                 "edge_ids", "offset_m", "route_raw")
+    __slots__ = ("first", "kept_idx", "case", "dist_w", "valid", "route_w",
+                 "gc_w", "edge_ids", "offset_m", "route_raw")
 
-    def __init__(self, kept_idx, case, dist_w, valid, route_w, gc_w,
+    def __init__(self, first, kept_idx, case, dist_w, valid, route_w, gc_w,
                  edge_ids, offset_m, route_raw):
-        self.kept_idx = kept_idx
-        self.case = case
-        self.dist_w = dist_w          # (K,) f32, wire round-tripped
-        self.valid = valid            # (K,) bool
-        self.route_w = route_w        # (K,K) f32, wire round-tripped
-        self.gc_w = gc_w              # f32 scalar, wire round-tripped
-        self.edge_ids = edge_ids      # (K,) i32 (pruned)
-        self.offset_m = offset_m      # (K,) f32 (pruned)
-        self.route_raw = route_raw    # (K,K) f32 pre-wire | None (first)
+        self.first = first            # row 0 is the window's first point
+        self.kept_idx = kept_idx      # (S,) raw indices
+        self.case = case              # (S,) i32
+        self.dist_w = dist_w          # (S,K) f32, wire round-tripped
+        self.valid = valid            # (S,K) bool
+        self.route_w = route_w        # (S,K,K) f32, wire round-tripped
+        self.gc_w = gc_w              # (S,) f32, wire round-tripped
+        self.edge_ids = edge_ids      # (S,K) i32 (pruned)
+        self.offset_m = offset_m      # (S,K) f32 (pruned)
+        self.route_raw = route_raw    # (S,K,K) f32 pre-wire (row 0 unused
+        #                               at the window's first point)
+
+    def __len__(self) -> int:
+        return len(self.case)
 
 
 class CarriedState:
@@ -213,7 +229,7 @@ class CarriedState:
         K = self.K
         return (256 + len(self.has_cands)
                 + 40 * len(self.c_kept)
-                + sum(e.nbytes(K) for e in self.ring)
+                + len(self.ring) * _Ring.nbytes(K)
                 + 5 * 4 * K)
 
     # -- snapshot serde (state snapshot v3) --------------------------------
@@ -334,6 +350,61 @@ def _wire_roundtrip(arr: np.ndarray) -> np.ndarray:
     exactly what the device decode sees after the wire)."""
     with np.errstate(over="ignore"):
         return arr.astype(np.float16).astype(np.float32)
+
+
+def _round_inputs(rounds: int, rows: int, K: int) -> tuple:
+    """One dispatch's padded kernel inputs (dist, valid, route, gc, case,
+    live, prev) for ``rounds`` rounds of ``rows`` traces, every slot a
+    RESTART onto no candidate, and not live, until a trace's step fills
+    it."""
+    return (np.full((rounds, rows, K), PAD_DIST, dtype=np.float32),
+            np.zeros((rounds, rows, K), dtype=bool),
+            np.full((rounds, rows, K, K), UNREACHABLE, dtype=np.float32),
+            np.zeros((rounds, rows), dtype=np.float32),
+            np.full((rounds, rows), RESTART, dtype=np.int32),
+            np.zeros((rounds, rows), dtype=bool),
+            np.zeros((rows, K), dtype=np.float32))
+
+
+def _round_bucket(n: int) -> int:
+    """The rounds a dispatch of ``n`` (at most ``ROUNDS_MAX``) pads to."""
+    b = 1
+    while b < n:
+        b *= 4
+    return b
+
+
+def _row_bucket(n: int) -> int:
+    """The rows a dispatch of ``n`` traces pads to."""
+    return max(MIN_ROWS, 1 << max(n - 1, 0).bit_length())
+
+
+def _select_appended(lat, lon, idx, anchor, interpolation_distance):
+    """Raw indices of the appended candidate-bearing points ``idx`` that
+    enter the HMM: ``batchpad._select_kept``'s moving anchor continued
+    from ``anchor``, the (lat, lon) of the window's last kept point, or
+    from the first of ``idx`` where the window has none. A point within
+    ``interpolation_distance`` of the anchor is dropped, and a point
+    beyond it becomes the anchor. The distances from every possible
+    anchor to every point are one vector call; the walk reads them."""
+    if idx.size == 0:
+        return idx
+    kept = []
+    if anchor is None:
+        kept.append(int(idx[0]))
+        anchor = (lat[idx[0]], lon[idx[0]])
+        idx = idx[1:]
+    a_lat = np.concatenate([[anchor[0]], lat[idx]])
+    a_lon = np.concatenate([[anchor[1]], lon[idx]])
+    reach = np.atleast_2d(equirectangular_m(
+        a_lat[:, None], a_lon[:, None], lat[idx][None, :],
+        lon[idx][None, :]))
+    row = 0                   # the anchor's row: 0, or 1 + its column
+    for j, i in enumerate(idx.tolist()):
+        if reach[row, j] >= interpolation_distance:
+            kept.append(i)
+            row = j + 1
+    return np.asarray(kept, dtype=np.int64)
 
 
 class IncrementalTable:
@@ -470,28 +541,13 @@ class IncrementalTable:
                 # assembly is the O(window) report-emission cost the
                 # batch path pays identically
                 t_dec = time.perf_counter()
-                for i in range(len(tb)):
-                    uuid = tb.uuid(i)
-                    if not uuid:
-                        continue
-                    params = per_trace_params[i]
-                    lat, lon, times = tb.trace_columns(i)
-                    if len(times) == 0:
-                        continue
-                    try:
-                        state = self._state_for(uuid, params, times)
-                        steps = self._prep_appended(state, params, lat,
-                                                    lon, times)
-                    except _Fallback as fb:
-                        self.fallbacks += 1
-                        metrics.count("match.incremental.fallbacks")
-                        logger.debug("trace %s falls back to the batch "
-                                     "path (%s)", uuid, fb)
-                        self._drop(uuid)
-                        continue
-                    jobs.append([i, uuid, state, steps, params, True])
-
-                failures += self._run_rounds(jobs, lag)
+                with metrics.timer("match.incremental.prep"):
+                    for i in range(len(tb)):
+                        job = self._prep_job(tb, i, per_trace_params[i])
+                        if job is not None:
+                            jobs.append(job)
+                with metrics.timer("match.incremental.rounds"):
+                    failures += self._run_rounds(jobs, lag)
                 metrics.observe("match.incremental.decode",
                                 time.perf_counter() - t_dec)
 
@@ -527,6 +583,28 @@ class IncrementalTable:
                 _maybe_shadow(self.matcher, lat, lon, times, params,
                               results[i])
         return failures
+
+    def _prep_job(self, tb, i: int, params) -> Optional[list]:
+        """Host prep of trace ``i``'s appended points: its job
+        ``[i, uuid, state, steps, params, alive]``, or None where it has
+        no uuid or no points, or falls back to the batch path."""
+        uuid = tb.uuid(i)
+        if not uuid:
+            return None
+        lat, lon, times = tb.trace_columns(i)
+        if len(times) == 0:
+            return None
+        try:
+            state = self._state_for(uuid, params, times)
+            steps = self._prep_appended(state, params, lat, lon, times)
+        except _Fallback as fb:
+            self.fallbacks += 1
+            metrics.count("match.incremental.fallbacks")
+            logger.debug("trace %s falls back to the batch path (%s)",
+                         uuid, fb)
+            self._drop(uuid)
+            return None
+        return [i, uuid, state, steps, params, True]
 
     def _drop(self, uuid: str) -> None:
         """Lock-held eviction (fallback/error paths)."""
@@ -567,107 +645,116 @@ class IncrementalTable:
         return st
 
     def _prep_appended(self, state: CarriedState, params, lat, lon,
-                       times) -> List[_Step]:
-        """Host prep for raw points [state.n_raw, len(times)): kept-point
-        selection, candidate lookup + pruning, the route row from the
-        previous kept point — mirroring batchpad semantics exactly.
-        Mutates selection state as it goes (any later failure evicts)."""
+                       times) -> Optional[_Steps]:
+        """Host prep for raw points [state.n_raw, len(times)): one
+        candidate lookup for them all, kept-point selection, pruning and
+        the route rows from each kept point's predecessor — batchpad's
+        semantics, continued from the carried selection state (None
+        where no point is kept). Mutates selection state as it goes
+        (any later failure evicts)."""
         m = self.matcher
         K = state.K
-        lookup = m.runtime if m.runtime is not None else m.grid
-        margin = _route_prune_margin(params)
-        steps: List[_Step] = []
         n = len(times)
-        if state.n_raw == 0:
+        a = state.n_raw
+        if a == 0:
             state.t0 = float(times[0])
-        for j in range(state.n_raw, n):
-            row = lookup.candidates(lat[j:j + 1], lon[j:j + 1], K,
-                                    params.search_radius)
-            has = bool((row.edge_ids != PAD_EDGE).any())
-            state.has_cands.append(has)
-            state.n_raw = j + 1
-            state.last_time = float(times[j])
-            if not has:
-                state.tail_ok = False   # off-network tail: no dwell
-                continue
-            gc64 = None
-            if state.last_kept_raw >= 0:
-                gc64 = equirectangular_m(state.last_lat, state.last_lon,
-                                         float(lat[j]), float(lon[j]))
-                if gc64 < params.interpolation_distance:
-                    continue            # jitter drop; tail stays ok
-            if state.n_kept + 1 > LENGTH_BUCKETS[-1]:
-                # the batch path truncates at the largest bucket; that
-                # semantics is window-global, not per-step
-                raise _Fallback("window exceeds the largest bucket")
-            pruned = _prune_candidates(
-                CandidateSet(edge_ids=row.edge_ids, dist_m=row.dist_m,
-                             offset_m=row.offset_m, proj_x=row.proj_x,
-                             proj_y=row.proj_y), margin)
-            steps.append(self._make_step(state, params, pruned, gc64,
-                                         times, j))
-            state.last_kept_raw = j
-            state.last_lat = float(lat[j])
-            state.last_lon = float(lon[j])
-            state.tail_ok = True
-            state.prev_cand = (
-                np.ascontiguousarray(pruned.edge_ids[0]),
-                np.ascontiguousarray(pruned.dist_m[0]),
-                np.ascontiguousarray(pruned.offset_m[0]),
-                np.ascontiguousarray(pruned.proj_x[0]),
-                np.ascontiguousarray(pruned.proj_y[0]))
+        if a >= n:
+            return None
+        lookup = m.runtime if m.runtime is not None else m.grid
+        rows = lookup.candidates(lat[a:n], lon[a:n], K, params.search_radius)
+        has = (rows.edge_ids != PAD_EDGE).any(axis=1)
+        state.has_cands.extend(has.tolist())
+        state.n_raw = n
+        state.last_time = float(times[n - 1])
+        anchor = ((state.last_lat, state.last_lon)
+                  if state.last_kept_raw >= 0 else None)
+        kept = _select_appended(lat, lon, a + np.flatnonzero(has), anchor,
+                                params.interpolation_distance)
+        if kept.size == 0:
+            # an off-network point in the tail ends its dwell; jitter
+            # drops leave it as it was
+            state.tail_ok = state.tail_ok and bool(has.all())
+            return None
+        state.tail_ok = bool(has[int(kept[-1]) - a + 1:].all())
+        if state.n_kept + kept.size > LENGTH_BUCKETS[-1]:
+            # the batch path truncates at the largest bucket; that
+            # semantics is window-global, not per-step
+            raise _Fallback("window exceeds the largest bucket")
+        sel = kept - a
+        pruned = _prune_candidates(
+            CandidateSet(edge_ids=rows.edge_ids[sel], dist_m=rows.dist_m[sel],
+                         offset_m=rows.offset_m[sel],
+                         proj_x=rows.proj_x[sel], proj_y=rows.proj_y[sel]),
+            _route_prune_margin(params))
+        steps = self._make_steps(state, params, pruned, lat, lon, times,
+                                 kept)
+        j = int(kept[-1])
+        state.last_kept_raw = j
+        state.last_lat = float(lat[j])
+        state.last_lon = float(lon[j])
+        state.prev_cand = tuple(
+            np.ascontiguousarray(x[-1]) for x in (
+                pruned.edge_ids, pruned.dist_m, pruned.offset_m,
+                pruned.proj_x, pruned.proj_y))
         return steps
 
-    def _make_step(self, state, params, pruned, gc64, times, j) -> _Step:
-        """Route row + case code + wire cast for one appended kept point."""
+    def _make_steps(self, state, params, pruned, lat, lon, times,
+                    kept) -> _Steps:
+        """Route rows + case codes + wire casts of the appended kept
+        points ``kept`` (raw indices; ``pruned`` their candidates): the
+        route rows in one call over the chain from the window's previous
+        kept point, as the batch path's prep makes them."""
         m = self.matcher
         K = state.K
-        dist = np.ascontiguousarray(pruned.dist_m[0])
-        valid = pruned.edge_ids[0] != PAD_EDGE
-        if gc64 is None:        # first kept point of the window
-            case = RESTART
-            gc32 = np.float32(0.0)
-            route_raw = None
-            route_in = np.full((K, K), UNREACHABLE, dtype=np.float32)
+        S = kept.size
+        first = state.last_kept_raw < 0
+        dist = np.ascontiguousarray(pruned.dist_m)
+        valid = pruned.edge_ids != PAD_EDGE
+        case = np.full(S, NORMAL, dtype=np.int32)
+        gc32 = np.zeros(S, dtype=np.float32)
+        # a window's first kept point has no route in: its row stays
+        # all unreachable, as the RESTART it is
+        route_raw = np.full((S, K, K), UNREACHABLE, dtype=np.float32)
+        if first:
+            case[0] = RESTART
+            lo, chain = 1, pruned
+            p_lat, p_lon = lat[kept[:-1]], lon[kept[:-1]]
+            p_time = times[kept[:-1]]
         else:
-            gc32 = np.float32(gc64)
-            case = RESTART if gc32 > params.breakage_distance else NORMAL
+            lo = 0
             pe, pd, po, ppx, ppy = state.prev_cand
-            pair = CandidateSet(
-                edge_ids=np.stack([pe, pruned.edge_ids[0]]),
-                dist_m=np.stack([pd, dist]),
-                offset_m=np.stack([po, pruned.offset_m[0]]),
-                proj_x=np.stack([ppx, pruned.proj_x[0]]),
-                proj_y=np.stack([ppy, pruned.proj_y[0]]))
-            gc_arr = np.asarray([gc32], dtype=np.float32)
+            chain = CandidateSet(
+                edge_ids=np.concatenate([pe[None], pruned.edge_ids]),
+                dist_m=np.concatenate([pd[None], dist]),
+                offset_m=np.concatenate([po[None], pruned.offset_m]),
+                proj_x=np.concatenate([ppx[None], pruned.proj_x]),
+                proj_y=np.concatenate([ppy[None], pruned.proj_y]))
+            p_lat = np.concatenate([[state.last_lat], lat[kept[:-1]]])
+            p_lon = np.concatenate([[state.last_lon], lon[kept[:-1]]])
+            p_time = np.concatenate([[times[state.last_kept_raw]],
+                                     times[kept[:-1]]])
+        if S > lo:
+            gc32[lo:] = np.atleast_1d(equirectangular_m(
+                p_lat, p_lon, lat[kept[lo:]], lon[kept[lo:]]))
+            case[lo:] = np.where(gc32[lo:] > params.breakage_distance,
+                                 RESTART, NORMAL)
             dt = None
             if params.max_route_time_factor > 0:
-                dt = np.asarray(
-                    [times[j] - times[state.last_kept_raw]])
+                dt = times[kept[lo:]] - p_time
+            kw = dict(max_route_distance_factor=params
+                      .max_route_distance_factor,
+                      backward_tolerance_m=params.backward_tolerance_m,
+                      dt=dt,
+                      max_route_time_factor=params.max_route_time_factor,
+                      min_time_bound_s=params.min_time_bound_s,
+                      turn_penalty_factor=params.turn_penalty_factor)
             if m.runtime is not None:
-                route = m.runtime.route_matrices(
-                    pair, gc_arr,
-                    max_route_distance_factor=params
-                    .max_route_distance_factor,
-                    backward_tolerance_m=params.backward_tolerance_m,
-                    dt=dt,
-                    max_route_time_factor=params.max_route_time_factor,
-                    min_time_bound_s=params.min_time_bound_s,
-                    turn_penalty_factor=params.turn_penalty_factor)
+                route = m.runtime.route_matrices(chain, gc32[lo:], **kw)
             else:
                 route = candidate_route_matrices(
-                    m.net, pair, gc_arr,
-                    max_route_distance_factor=params
-                    .max_route_distance_factor,
-                    cache=m.route_cache,
-                    backward_tolerance_m=params.backward_tolerance_m,
-                    dt=dt,
-                    max_route_time_factor=params.max_route_time_factor,
-                    min_time_bound_s=params.min_time_bound_s,
-                    turn_penalty_factor=params.turn_penalty_factor)
-            route_raw = np.ascontiguousarray(route[0], dtype=np.float32)
-            route_in = route_raw
-        dist_w, route_w, gc_w = dist, route_in, gc32
+                    m.net, chain, gc32[lo:], cache=m.route_cache, **kw)
+            route_raw[lo:] = route
+        dist_w, route_w, gc_w = dist, route_raw, gc32
         if state.f16:
             # per-trace mirror of the pack_batches wire decision: a
             # finite value out of f16 range would flip the WHOLE window
@@ -675,84 +762,108 @@ class IncrementalTable:
             # f16 scores can't rewrite, so fall back instead
             fin_d = float(np.amax(dist, initial=0.0,
                                   where=dist < UNREACHABLE_THRESHOLD))
-            fin_r = float(np.amax(route_in, initial=0.0,
-                                  where=route_in < UNREACHABLE_THRESHOLD))
-            if max(fin_d, fin_r, float(gc32)) > WIRE_MAX_M:
+            fin_r = float(np.amax(route_raw, initial=0.0,
+                                  where=route_raw < UNREACHABLE_THRESHOLD))
+            if max(fin_d, fin_r, float(gc32.max())) > WIRE_MAX_M:
                 raise _Fallback("finite distance beyond the f16 wire")
             dist_w = _wire_roundtrip(dist)
-            route_w = _wire_roundtrip(route_in)
-            gc_w = _wire_roundtrip(np.asarray(gc32))[()]
-        return _Step(j, case, dist_w, valid, route_w, gc_w,
-                     np.ascontiguousarray(pruned.edge_ids[0]),
-                     np.ascontiguousarray(pruned.offset_m[0]),
-                     route_raw)
+            route_w = _wire_roundtrip(route_raw)
+            gc_w = _wire_roundtrip(gc32)
+        return _Steps(first, kept, case, dist_w, valid, route_w, gc_w,
+                      np.ascontiguousarray(pruned.edge_ids),
+                      np.ascontiguousarray(pruned.offset_m), route_raw)
 
     def _run_rounds(self, jobs, lag: int) -> int:
-        """Advance every job's queued steps through the batched kernel,
-        one dispatch per round (round r = each trace's r-th step); ring
-        rows pad to a power of two so the jit shape count stays
-        logarithmic. Returns per-round failure count."""
+        """Advance every job's queued steps through the batched kernel:
+        per group of jobs sharing the device scalars, one dispatch for
+        each ``ROUNDS_MAX`` rounds (round r = each trace's r-th step).
+        Returns per-round failure count."""
         from ..ops import incremental_step_batch
         failures = 0
-        r = 0
-        while True:
-            rows = [job for job in jobs
-                    if job[5] and r < len(job[3])]
-            if not rows:
-                break
-            # group rows by the device scalars (one kernel call each);
-            # the steady state is a single shared params object
-            groups: Dict[tuple, list] = {}
-            for job in rows:
-                p = job[4]
-                gkey = (float(p.effective_sigma), float(p.beta),
-                        int(p.max_candidates))
-                groups.setdefault(gkey, []).append(job)
-            for (sigma, beta, K), grp in groups.items():
-                self._round(grp, r, K, sigma, beta,
-                            incremental_step_batch, lag)
-            r += 1
+        # group rows by the device scalars (one kernel call each); the
+        # steady state is a single shared params object
+        groups: Dict[tuple, list] = {}
+        for job in jobs:
+            if job[3] is None:
+                continue
+            p = job[4]
+            gkey = (float(p.effective_sigma), float(p.beta),
+                    int(p.max_candidates))
+            groups.setdefault(gkey, []).append(job)
+        for (sigma, beta, K), grp in groups.items():
+            r0 = 0
+            while True:
+                rows = [job for job in grp if job[5] and len(job[3]) > r0]
+                if not rows:
+                    break
+                self._dispatch(rows, r0, K, sigma, beta,
+                               incremental_step_batch, lag)
+                r0 += ROUNDS_MAX
         return failures
 
-    def _round(self, grp, r, K, sigma, beta, kernel, lag) -> None:
-        n = len(grp)
-        rows = 1 << max(n - 1, 0).bit_length()   # pow2 pad
-        dist = np.full((rows, K), PAD_DIST, dtype=np.float32)
-        valid = np.zeros((rows, K), dtype=bool)
-        route = np.full((rows, K, K), UNREACHABLE, dtype=np.float32)
-        gc = np.zeros(rows, dtype=np.float32)
-        case = np.full(rows, RESTART, dtype=np.int32)
-        prev = np.zeros((rows, K), dtype=np.float32)
-        for b, job in enumerate(grp):
-            step = job[3][r]
-            st = job[2]
-            dist[b] = step.dist_w
-            valid[b] = step.valid
-            route[b] = step.route_w
-            gc[b] = step.gc_w
-            case[b] = step.case
+    def warm(self, max_rows: int) -> int:
+        """Build, before a measured stretch of work, the device advance
+        at every shape a dispatch of up to ``max_rows`` traces pads to
+        (rows: the powers of two from ``MIN_ROWS`` up to the one that
+        holds it; rounds: 1, 4, 16 and 64), at the matcher's K, sigma and
+        beta. Returns the shapes built."""
+        from ..ops import incremental_step_batch
+        p = self.matcher.params
+        K = int(p.max_candidates)
+        built = 0
+        rows = MIN_ROWS
+        while True:
+            rounds = 1
+            while rounds <= ROUNDS_MAX:
+                out = incremental_step_batch(
+                    *_round_inputs(rounds, rows, K),
+                    np.float32(p.effective_sigma), np.float32(p.beta))
+                np.asarray(out[0])
+                built += 1
+                rounds *= 4
+            if rows >= max_rows:
+                return built
+            rows *= 2
+
+    def _dispatch(self, grp, r0, K, sigma, beta, kernel, lag) -> None:
+        """Rounds [r0, r0 + ROUNDS_MAX) of the jobs in ``grp`` in one
+        kernel call, then each trace's ring and fixed-lag commits."""
+        spans = [(r0, min(len(job[3]), r0 + ROUNDS_MAX)) for job in grp]
+        rounds = _round_bucket(max(hi for _lo, hi in spans) - r0)
+        dist, valid, route, gc, case, live, prev = _round_inputs(
+            rounds, _row_bucket(len(grp)), K)
+        for b, (job, (lo, hi)) in enumerate(zip(grp, spans)):
+            s, st, k = job[3], job[2], hi - lo
+            dist[:k, b] = s.dist_w[lo:hi]
+            valid[:k, b] = s.valid[lo:hi]
+            route[:k, b] = s.route_w[lo:hi]
+            gc[:k, b] = s.gc_w[lo:hi]
+            case[:k, b] = s.case[lo:hi]
+            live[:k, b] = True
             if st.scores is not None:
                 prev[b] = st.scores
-        new_scores, bp, prev_best = kernel(
-            dist, valid, route, gc, case, prev,
+        scores, bp, prev_best = kernel(
+            dist, valid, route, gc, case, live, prev,
             np.float32(sigma), np.float32(beta))
-        new_scores = np.asarray(new_scores)
+        scores = np.asarray(scores)
         bp = np.asarray(bp)
         prev_best = np.asarray(prev_best)
-        metrics.count("match.incremental.steps", n)
-        for b, job in enumerate(grp):
-            step = job[3][r]
-            st = job[2]
-            first = st.scores is None
-            st.scores = new_scores[b].copy()
-            st.ring.append(_Ring(
-                step.kept_idx, step.case, step.edge_ids, step.offset_m,
-                None if first else bp[b].copy(),
-                0 if first else int(prev_best[b]),
-                None if first else step.route_raw))
+        metrics.count("match.incremental.steps",
+                      sum(hi - lo for lo, hi in spans))
+        for b, (job, (lo, hi)) in enumerate(zip(grp, spans)):
+            s, st = job[3], job[2]
             try:
-                while len(st.ring) > lag:
-                    self._commit_one(st)
+                for t in range(lo, hi):
+                    first = s.first and t == 0
+                    st.ring.append(_Ring(
+                        s.kept_idx[t], s.case[t], s.edge_ids[t],
+                        s.offset_m[t],
+                        None if first else bp[t - r0, b].copy(),
+                        0 if first else int(prev_best[t - r0, b]),
+                        None if first else s.route_raw[t]))
+                    while len(st.ring) > lag:
+                        self._commit_one(st)
+                st.scores = scores[b].copy()
             except _Fallback as fb:
                 self.fallbacks += 1
                 metrics.count("match.incremental.fallbacks")
@@ -767,16 +878,18 @@ class IncrementalTable:
         converged ancestor provably equals what the final backtrace
         will pick — whatever gets appended later enters ABOVE these
         steps, so the pointer chase below them never changes."""
-        K = st.K
-        cur = np.arange(K, dtype=np.int32)
+        cur = range(st.K)
         for e in reversed(st.ring[1:]):
             if e.case == RESTART:
-                cur = np.full(K, e.prev_best, dtype=np.int32)
+                cur = (e.prev_best,)
+            elif len(cur) == 1:
+                # converged: one ancestor from here back
+                cur = (int(e.bp[cur[0]]),)
             else:
-                cur = e.bp[cur]
-        c = int(cur[0])
-        if not bool((cur == c).all()):
+                cur = tuple({int(e.bp[i]) for i in cur})
+        if len(cur) != 1:
             raise _Fallback("lag window did not converge")
+        (c,) = cur
         faults.failpoint("match.incremental.commit")
         e0 = st.ring.pop(0)
         if st.c_kept and e0.route_in is not None:

@@ -764,6 +764,15 @@ class SegmentMatcher:
                               [None] * n, sub)
         return len(shapes)
 
+    def warm_incremental(self, max_traces: int) -> int:
+        """Build, before a measured stretch of work, every program the
+        carried-state advance (:meth:`match_incremental`) can form for
+        calls of up to ``max_traces`` traces: its device step at each
+        power-of-two row count a round pads to, at this matcher's K.
+        Returns the shapes built. :meth:`warm` builds the decode programs
+        of the windows it declines."""
+        return self.incremental_table.warm(max_traces)
+
     def match_incremental(self, traces) -> List[Optional[dict]]:
         """Match via carried per-trace decode state where possible.
 

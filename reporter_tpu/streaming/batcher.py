@@ -333,7 +333,9 @@ class PointBatcher:
     def _flush_due(self, due) -> None:
         """ONE batched submit for (uuid, batch) pairs -> forward the
         resulting segment pairs; bodies go columnar (TraceBatch), so the
-        in-process service path never builds a point dict.
+        in-process service path never builds a point dict. Timed as
+        ``stream.report_flush``; the windows answered count as
+        ``stream.windows``.
 
         Failure domain: a failed round trip (a whole-submit exception or
         a per-trace None) requeues the batch under the retry budget and
@@ -341,7 +343,8 @@ class PointBatcher:
         kill the stream thread nor silently lose the trace."""
         if not due:
             return
-        with obs_trace.span("batcher.flush", sessions=len(due)):
+        with metrics.timer("stream.report_flush"), \
+                obs_trace.span("batcher.flush", sessions=len(due)):
             tb = TraceBatch.concat([
                 batch.request_columns(uuid, self.options)
                 for uuid, batch in due])
@@ -363,6 +366,7 @@ class PointBatcher:
                 batch.retries = 0
                 self._retrying.pop(uuid, None)
                 self._forward_all(batch.apply_response(uuid, response))
+            metrics.count("stream.windows", len(due) - failures)
             # feed the backpressure sensors AFTER the retry bookkeeping
             # so the requeue depth reflects this flush's outcome
             self.governor.note_flush(len(due), elapsed, failures,

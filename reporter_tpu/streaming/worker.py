@@ -17,7 +17,13 @@ CLI options named after the reference's (Reporter.java:43-136):
   --privacy/-p --quantisation/-q --flush-interval/-i
   --source/-s --output-location/-o --duration/-d
 plus --input (flat file / '-' for stdin replay) or --bootstrap/-b with
---topics/-t for Kafka.
+--topics/-t for Kafka, and --punctuate: ``wall`` (the default) paces the
+report flush, the session-gap eviction and the tile flush by the wall
+clock; ``stream`` paces them by stream time, the largest probe time
+offered so far, as the reference's Kafka Streams processors are
+punctuated (BatchingProcessor.java:87-106,
+AnonymisingProcessor.java:223-266), so a backlog replayed at any speed
+forms the same report windows and tile flushes as the live stream did.
 """
 from __future__ import annotations
 
@@ -85,6 +91,11 @@ def inproc_submitter(service) -> Callable[[dict], Optional[dict]]:
     return submit
 
 
+#: punctuation clocks: the wall clock, or stream time (the largest probe
+#: time offered so far)
+PUNCTUATE = ("wall", "stream")
+
+
 class StreamWorker:
     """Wires formatter -> batcher -> anonymiser and drives punctuation."""
 
@@ -107,7 +118,10 @@ class StreamWorker:
                      Callable[[], Optional[dict]]] = None,
                  on_evict: Optional[Callable[[str], None]] = None,
                  datastore=None, compactor=None,
-                 map_version: Optional[str] = None):
+                 map_version: Optional[str] = None,
+                 punctuate: str = "wall"):
+        if punctuate not in PUNCTUATE:
+            raise ValueError(f"punctuate must be one of {PUNCTUATE}")
         self.formatter = formatter
         # content-derived identity of the graph this worker matches
         # against (graph/version.py), surfaced in the heartbeat so a
@@ -168,6 +182,19 @@ class StreamWorker:
             deadletter_dir=trace_deadletter, on_evict=on_evict)
         self.flush_interval_s = flush_interval_s
         self.session_gap_ms = session_gap_ms
+        # stream-time punctuation: ``now`` is the largest probe time
+        # offered so far (None before the first), and the first probe
+        # starts every punctuation schedule (:meth:`_advance`). An
+        # injected ``clock`` paces wall punctuation only; ``run``'s
+        # ``duration_s`` is read on it in either mode
+        if punctuate == "stream" and clock is not time.time:
+            raise ValueError("an injected clock paces wall punctuation; "
+                             "stream punctuation reads probe times")
+        self.punctuate = punctuate
+        self._stream_now: Optional[float] = None
+        self._wall_clock = clock
+        if punctuate == "stream":
+            clock = self._stream_clock
         self.clock = clock
         self.processed = 0
         self.parse_failures = 0
@@ -223,7 +250,8 @@ class StreamWorker:
             # stream position ("crash at the Nth offer") — one flag
             # check when off
             faults.failpoint("worker.offer")
-            now_ms = int(self.clock() * 1000)
+            stream = self.punctuate == "stream"
+            now_ms = 0 if stream else int(self.clock() * 1000)
             try:
                 uuid, point = self.formatter.format(message)
             except Exception:
@@ -235,6 +263,8 @@ class StreamWorker:
             if self.uuid_filter is not None and not self.uuid_filter(uuid):
                 self.skipped_other_host += 1
                 return
+            if stream:
+                now_ms = self._advance(point.time)
             self.batcher.process(uuid, point, now_ms)
             self.processed += 1
             if self.processed % 10000 == 0:
@@ -251,6 +281,20 @@ class StreamWorker:
                 metrics.count("backpressure.delays")
                 time.sleep(delay)
 
+    def _stream_clock(self) -> float:
+        return self._stream_now if self._stream_now is not None else 0.0
+
+    def _advance(self, t: float) -> int:
+        """Stream time past probe time ``t``; returns it in ms."""
+        t = float(t)
+        if self._stream_now is None:
+            self._last_flush = self._last_evict = t
+            self._last_report_flush = t
+            self._stream_now = t
+        elif t > self._stream_now:
+            self._stream_now = t
+        return int(self._stream_now * 1000)
+
     def maybe_punctuate(self, force: bool = False) -> None:
         now = self.clock()
         flushed = False
@@ -264,7 +308,8 @@ class StreamWorker:
             self._last_evict = now
             flushed = True
         if force or now - self._last_flush >= self.flush_interval_s:
-            self._flush_tiles()
+            with metrics.timer("stream.tiles"):
+                self._flush_tiles()
             self._last_flush = now
             flushed = True
         if self.state is not None:
@@ -408,7 +453,8 @@ class StreamWorker:
             # paused, not just drained: a maybe_punctuate from a late
             # caller must not re-enter the submit path or the sink
             self.drainer.pause()
-        self._flush_tiles()
+        with metrics.timer("stream.tiles"):
+            self._flush_tiles()
         if self.compactor is not None:
             # signal + JOIN after the final flush (its deltas were the
             # last pressure source): no compaction thread may outlive
@@ -425,18 +471,88 @@ class StreamWorker:
 
     def run(self, messages: Iterable[str],
             duration_s: Optional[float] = None) -> None:
-        deadline = self.clock() + duration_s if duration_s else None
-        try:
-            for message in messages:
-                self.offer(message)
-                if deadline is not None and self.clock() > deadline:
-                    break
-        except Exception as e:
-            # an unhandled exception is about to kill the stream: leave
-            # a postmortem naming the span that was in flight
-            flightrec.dump("worker.exception", {"error": repr(e)})
-            raise
-        self.drain()
+        """Offer every message, then drain. ``duration_s`` bounds the
+        run on the wall clock (or the injected one)."""
+        clock = self._wall_clock
+        deadline = clock() + duration_s if duration_s else None
+        offered = 0
+        with metrics.timer("stream.ingest"):
+            try:
+                for message in messages:
+                    self.offer(message)
+                    offered += 1
+                    if deadline is not None and clock() > deadline:
+                        break
+            except Exception as e:
+                # an unhandled exception is about to kill the stream:
+                # leave a postmortem naming the span that was in flight
+                flightrec.dump("worker.exception", {"error": repr(e)})
+                raise
+            finally:
+                metrics.count("stream.points", offered)
+            self.drain()
+
+
+def inprocess_worker(matcher, formatter: Formatter, anonymiser: Anonymiser,
+                     state_file: Optional[str] = None,
+                     state_interval: float = 30.0,
+                     **options) -> StreamWorker:
+    """A :class:`StreamWorker` that matches in this process, as the CLI
+    builds it without ``--reporter-url``: ``matcher`` behind a
+    ``ReporterService``; report-ready sessions through its
+    ``report_incremental`` (the carried-state advance, O(K) device work
+    per appended point), which sends every window it declines through
+    the windowed ``report_many`` in one batch; a session's carried state
+    dropped when the session gap evicts it; the breaker, open-domain and
+    carried-state probes for the heartbeat; the graph's map version; the
+    matcher's quarantine in this worker's trace spool; and, with
+    ``state_file``, a snapshot store that carries the incremental table.
+    ``options`` are :class:`StreamWorker`'s own keywords. The CLI and the
+    benchmark's stream cell both build their worker here."""
+    from ..service.server import ReporterService
+    service = ReporterService(matcher)
+
+    def incremental_probe():
+        t = matcher._incremental_table
+        return t.gauge() if t is not None else None
+
+    # session-gap eviction drops the session's carried decode state
+    # with it — AFTER its final relaxed-threshold report flushed
+    def on_evict(uuid):
+        t = matcher._incremental_table
+        if t is not None:
+            t.evict(uuid, "session gap")
+
+    state = None
+    if state_file:
+        from .state import StateStore
+        # snapshot v3 provider: restore must BUILD the table (frames in
+        # the snapshot need somewhere to live); save uses the property
+        # too — constructing the empty table is dict bookkeeping only
+        state = StateStore(state_file, interval_s=state_interval,
+                           incremental=lambda: matcher.incremental_table)
+    worker = StreamWorker(
+        formatter, inproc_submitter(service), anonymiser, state=state,
+        submit_many=service.report_incremental,
+        circuit_probe=lambda: matcher.circuit.state,
+        degraded_probe=matcher.open_domains,
+        incremental_probe=incremental_probe, on_evict=on_evict, **options)
+    # the heartbeat carries the graph's content-derived map version (an
+    # HTTP split can't know the remote matcher's build). The tee's
+    # ledger keys stay unversioned here: only a map-version owner (the
+    # city registry's swap machinery) stamps the store, so a single-map
+    # worker's crash-replay dedupe is byte-compatible with
+    # pre-versioning spools
+    try:
+        from ..graph.version import map_version
+        worker.map_version = map_version(matcher.net)
+    except Exception:
+        pass
+    # poisoned-trace quarantine lands in THIS worker's trace spool
+    # (explicit beats the last-writer-wins module global — see
+    # StreamWorker.__init__)
+    matcher.quarantine_spool = worker._trace_spool
+    return worker
 
 
 def resolve_uuid_filter(mode: str, bootstrap: Optional[str]):
@@ -505,6 +621,13 @@ def main(argv=None):
                              "batched report flush; a huge value makes "
                              "flush boundaries a pure function of the "
                              "stream (deterministic replays/chaos runs)")
+    parser.add_argument("--punctuate", choices=PUNCTUATE, default="wall",
+                        help="clock of the report flush, the session-gap "
+                             "eviction and the tile flush: wall, or "
+                             "stream time (the largest probe time "
+                             "offered so far), for backlog replays that "
+                             "must form the windows and tiles the live "
+                             "stream did")
     parser.add_argument("--datastore",
                         help="local histogram-store directory: every "
                              "flushed tile is ALSO aggregated in-process "
@@ -526,6 +649,8 @@ def main(argv=None):
                              "for remote); replay with: datastore ingest "
                              "--delete")
     args = parser.parse_args(argv)
+    if not args.reporter_url and not args.graph:
+        parser.error("--graph is required for in-process matching")
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(levelname)s %(message)s")
@@ -542,54 +667,6 @@ def main(argv=None):
     from ..parallel import init_multihost
     init_multihost()
     uuid_filter = resolve_uuid_filter(args.uuid_filter, args.bootstrap)
-
-    circuit_probe = None
-    degraded_probe = None
-    incremental_probe = None
-    incremental_provider = None
-    on_evict = None
-    if args.reporter_url:
-        submit = http_submitter(args.reporter_url)
-        submit_many = None  # HTTP path: one POST per trace (split deploy)
-    else:
-        from ..graph.network import RoadNetwork
-        from ..matcher import SegmentMatcher
-        from ..service.server import ReporterService
-        if not args.graph:
-            parser.error("--graph is required for in-process matching")
-        service = ReporterService(
-            SegmentMatcher(net=RoadNetwork.load(args.graph)))
-        submit = inproc_submitter(service)
-        # batched submit for eviction flushes: one dispatcher round trip
-        # -> one padded device batch. report_incremental routes report-
-        # ready sessions through the carried-state path (O(K) per
-        # appended point, ISSUE 19) and falls back to the windowed
-        # report_many per trace — kill switch REPORTER_TPU_INCREMENTAL
-        submit_many = service.report_incremental
-        circuit_probe = lambda: service.matcher.circuit.state  # noqa: E731
-        degraded_probe = service.matcher.open_domains
-
-        def incremental_probe(_m=service.matcher):
-            t = _m._incremental_table
-            return t.gauge() if t is not None else None
-
-        # session-gap eviction drops the session's carried decode state
-        # with it — AFTER its final relaxed-threshold report flushed
-        def on_evict(uuid, _m=service.matcher):
-            t = _m._incremental_table
-            if t is not None:
-                t.evict(uuid, "session gap")
-
-        # snapshot v3 provider: restore must BUILD the table (frames in
-        # the snapshot need somewhere to live); save uses the property
-        # too — constructing the empty table is dict bookkeeping only
-        incremental_provider = lambda: service.matcher.incremental_table  # noqa: E731
-
-    state = None
-    if args.state_file:
-        from .state import StateStore
-        state = StateStore(args.state_file, interval_s=args.state_interval,
-                           incremental=incremental_provider)
 
     tee = None
     datastore = None
@@ -634,38 +711,33 @@ def main(argv=None):
                                        max_delta_bytes=_b,
                                        ingest_key=ingest_key)
 
-    worker = StreamWorker(
-        Formatter.from_config(args.formatter), submit,
-        Anonymiser(TileSink(args.output_location,
-                            deadletter=args.deadletter),
-                   args.privacy,
-                   args.quantisation, mode=args.mode, source=args.source,
-                   tee=tee),
+    anonymiser = Anonymiser(
+        TileSink(args.output_location, deadletter=args.deadletter),
+        args.privacy, args.quantisation, mode=args.mode,
+        source=args.source, tee=tee)
+    options = dict(
         mode=args.mode, reports=args.reports, transitions=args.transitions,
-        flush_interval_s=args.flush_interval, state=state,
-        uuid_filter=uuid_filter, submit_many=submit_many,
+        flush_interval_s=args.flush_interval, uuid_filter=uuid_filter,
         report_flush_interval_s=args.report_flush_interval,
-        circuit_probe=circuit_probe, degraded_probe=degraded_probe,
-        incremental_probe=incremental_probe, on_evict=on_evict,
-        datastore=datastore, compactor=compactor)
-    if not args.reporter_url:
-        # in-process matching: the heartbeat carries the graph's
-        # content-derived map version (an HTTP split can't know the
-        # remote matcher's build). NOTE: the tee's ledger keys stay
-        # UNversioned here — only a map-version OWNER (the city
-        # registry's swap machinery, harnesses) stamps the store, so a
-        # single-map worker's crash-replay dedupe is byte-compatible
-        # with pre-versioning spools
-        try:
-            from ..graph.version import map_version as _map_version
-            worker.map_version = _map_version(service.matcher.net)
-        except Exception:
-            pass
-    if not args.reporter_url:
-        # poisoned-trace quarantine lands in THIS worker's trace spool
-        # (explicit beats the last-writer-wins module global — see
-        # StreamWorker.__init__)
-        service.matcher.quarantine_spool = worker._trace_spool
+        datastore=datastore, compactor=compactor,
+        punctuate=args.punctuate)
+    formatter = Formatter.from_config(args.formatter)
+    if args.reporter_url:
+        state = None
+        if args.state_file:
+            from .state import StateStore
+            state = StateStore(args.state_file,
+                               interval_s=args.state_interval)
+        # HTTP split: one POST per trace (the reference's only mode)
+        worker = StreamWorker(formatter, http_submitter(args.reporter_url),
+                              anonymiser, state=state, **options)
+    else:
+        from ..graph.network import RoadNetwork
+        from ..matcher import SegmentMatcher
+        worker = inprocess_worker(
+            SegmentMatcher(net=RoadNetwork.load(args.graph)), formatter,
+            anonymiser, state_file=args.state_file,
+            state_interval=args.state_interval, **options)
 
     # the flat-file input is opened under an ExitStack so the handle
     # closes on every exit path (drain, exception, --duration cut-off)

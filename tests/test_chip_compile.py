@@ -108,13 +108,15 @@ def test_decode_compiles(one_chip, backend, B, T):
 
 
 def test_incremental_step_compiles(one_chip):
+    from reporter_tpu.matcher.incremental import ROUNDS_MAX
     from reporter_tpu.ops.incremental import incremental_step_batch
-    B = 2048
-    args = (_spec((B, K), np.float32, one_chip),
-            _spec((B, K), bool, one_chip),
-            _spec((B, K, K), np.float32, one_chip),
-            _spec((B,), np.float32, one_chip),
-            _spec((B,), np.int32, one_chip),
+    R, B = ROUNDS_MAX, 512
+    args = (_spec((R, B, K), np.float32, one_chip),
+            _spec((R, B, K), bool, one_chip),
+            _spec((R, B, K, K), np.float32, one_chip),
+            _spec((R, B), np.float32, one_chip),
+            _spec((R, B), np.int32, one_chip),
+            _spec((R, B), bool, one_chip),
             _spec((B, K), np.float32, one_chip),
             _spec((), np.float32, one_chip), _spec((), np.float32, one_chip))
     _compile(incremental_step_batch.__wrapped__, *args)

@@ -116,6 +116,88 @@ class TestParity:
         assert matcher.incremental_table.resets > 0
 
 
+class TestBatchedAdvance:
+    """A report's appended points are prepped together and advanced
+    together: one vector selection, one route call, and one device
+    dispatch per ``ROUNDS_MAX`` rounds of a flush's traces."""
+
+    def test_rounds_equal_single_steps(self):
+        """R rounds in one call are bit-identical to R one-round calls;
+        a trace not live in a round keeps its scores there."""
+        from reporter_tpu.ops import incremental_step_batch
+        rng = np.random.default_rng(3)
+        R, N, K = 5, 3, 8
+        dist = rng.uniform(0, 40, (R, N, K)).astype(np.float32)
+        valid = rng.random((R, N, K)) < 0.8
+        route = rng.uniform(0, 400, (R, N, K, K)).astype(np.float32)
+        route[rng.random((R, N, K, K)) < 0.2] = inc.UNREACHABLE
+        gc = rng.uniform(0, 300, (R, N)).astype(np.float32)
+        case = np.where(rng.random((R, N)) < 0.2, inc.RESTART,
+                        inc.NORMAL).astype(np.int32)
+        live = np.ones((R, N), dtype=bool)
+        live[3:, 1] = False                  # a ragged trace
+        prev = rng.uniform(-50, 0, (N, K)).astype(np.float32)
+        sigma, beta = np.float32(4.07), np.float32(3.0)
+        scores, bp, anchor = (np.asarray(x) for x in incremental_step_batch(
+            dist, valid, route, gc, case, live, prev, sigma, beta))
+        carry = prev
+        for r in range(R):
+            one = [np.asarray(x) for x in incremental_step_batch(
+                dist[r:r + 1], valid[r:r + 1], route[r:r + 1], gc[r:r + 1],
+                case[r:r + 1], live[r:r + 1], carry, sigma, beta)]
+            np.testing.assert_array_equal(one[1][0][live[r]], bp[r][live[r]])
+            np.testing.assert_array_equal(one[2][0][live[r]],
+                                          anchor[r][live[r]])
+            carry = one[0]
+            if r == 2:
+                held = carry[1].copy()
+        np.testing.assert_array_equal(carry, scores)
+        np.testing.assert_array_equal(scores[1], held)
+        assert scores.dtype == np.float32
+
+    @pytest.mark.parametrize("cuts", [(), (1,), (5, 6, 30), (17, 40)])
+    def test_selection_matches_batchpad(self, cuts):
+        """Kept points selected report by report, from the carried
+        anchor, are the ones the batch path selects over the window."""
+        from reporter_tpu.matcher.batchpad import _select_kept
+        rng = np.random.default_rng(11)
+        n = 64
+        steps = rng.choice([0.5, 3.0, 12.0], size=n, p=[0.3, 0.3, 0.4])
+        lat = 40.0 + np.cumsum(steps) * 9e-6
+        lon = -75.0 + rng.normal(0, 2e-6, n)
+        has = rng.random(n) < 0.9
+        want = _select_kept(lat, lon, has, 5.0).tolist()
+        got, anchor = [], None
+        for lo, hi in zip((0, *cuts), (*cuts, n)):
+            kept = inc._select_appended(
+                lat, lon, lo + np.flatnonzero(has[lo:hi]), anchor, 5.0)
+            got += kept.tolist()
+            if got:
+                anchor = (float(lat[got[-1]]), float(lon[got[-1]]))
+        assert got == want
+
+    def test_long_append_spans_dispatches(self, city, matcher,
+                                          monkeypatch):
+        """Reports that append more points than one dispatch's rounds
+        advance over several dispatches, byte-equal to the batch path."""
+        import reporter_tpu.ops as ops
+        calls = []
+        real = ops.incremental_step_batch
+
+        def counting(*a):
+            calls.append(np.shape(a[0]))
+            return real(*a)
+
+        monkeypatch.setattr(ops, "incremental_step_batch", counting)
+        monkeypatch.setattr(inc, "ROUNDS_MAX", 4)
+        pts = list(make_trace(city, seed=77, noise=4.0).points)
+        served, windows = stream_parity(matcher, pts, "long-0", start=20,
+                                        step=9)
+        assert served == windows > 1
+        assert max(r for r, _n, _k in calls) == 4
+        assert len(calls) > windows
+
+
 class TestFixedLag:
     def test_report_inside_lag_window(self, city, matcher, monkeypatch):
         """A report whose whole window fits inside the lag bound decodes
