@@ -203,6 +203,15 @@ METRICS: Dict[str, str] = {
                                 "dead gather worker",
     "pipeline.match": "backfill stage 2 (timer)",
     "pipeline.report": "backfill stage 3 (timer)",
+    "pipeline.report.tiles": "stage 3 tile files written",
+    "pipeline.report.rows": "stage 3 rows the privacy cull kept, written",
+    "pipeline.tile_failures": "tile files lost to an error or a dead "
+                              "stage 3 worker",
+    "pipeline.worker_starts": "stage 1 and 3 worker processes started",
+    "pipeline.worker_starts.preloaded": "of those, the workers forked from "
+                                        "the preloaded fork server",
+    "pipeline.worker_boot": "a stage's worker start-up: its first start to "
+                            "its last worker entering its work (timer)",
     "pipeline.parse": "stage 2 shard blocks read, one (uuid, time) sort "
                       "and window cut (timer)",
     "pipeline.parse.probes": "stage 2 records read from shard blocks; "

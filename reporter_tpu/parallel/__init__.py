@@ -1,8 +1,9 @@
 from .multihost import host_uuid_filter, init_multihost, partition_for_host
 
 # the mesh and sharded-decode names import JAX, so they resolve on first
-# use (PEP 562): a process that needs only the uuid partition, such as a
-# spawned gather worker, never pays for a JAX import
+# use (PEP 562): a process that needs only the uuid partition, such as the
+# backfill's worker processes and the fork server they start from, never
+# pays for a JAX import
 _LAZY = {"make_mesh": "mesh", "sharded_viterbi": "sharded",
          "shard_batch": "sharded"}
 

@@ -91,23 +91,36 @@ def partition_for_host(uuids: Sequence[str], num_processes: int,
             if owned_by_host(u, num_processes, process_id)]
 
 
-def host_uuid_filter(num_processes: Optional[int] = None,
-                     process_id: Optional[int] = None):
-    """Ownership predicate for this host's uuids, or None for single-host.
+def host_share(num_processes: Optional[int] = None,
+               process_id: Optional[int] = None) -> tuple:
+    """``(num_processes, process_id)`` of this host's share of the uuid
+    space, ``(1, 0)`` for a single host.
 
     Defaults from the REPORTER_TPU_NUM_PROCESSES / REPORTER_TPU_PROCESS_ID
-    env. Entry points pass the result to their ingest stage so a shared
-    (unpartitioned) input stream is processed exactly once across a
-    multi-host job; with a uuid-keyed Kafka topic the broker already
-    partitions and this stays None.
+    env. Raises ValueError for a process id outside ``[0, num_processes)``.
     """
     if num_processes is None and os.environ.get(ENV_NUM_PROCESSES):
         num_processes = int(os.environ[ENV_NUM_PROCESSES])
     if process_id is None and os.environ.get(ENV_PROCESS_ID):
         process_id = int(os.environ[ENV_PROCESS_ID])
     if not num_processes or num_processes <= 1:
-        return None
+        return 1, 0
     if process_id is None or not 0 <= process_id < num_processes:
         raise ValueError(
             f"process_id {process_id} not in [0, {num_processes})")
+    return num_processes, process_id
+
+
+def host_uuid_filter(num_processes: Optional[int] = None,
+                     process_id: Optional[int] = None):
+    """Ownership predicate for this host's uuids, or None for single-host.
+
+    Defaults as :func:`host_share`. Entry points pass the result to their
+    ingest stage so a shared (unpartitioned) input stream is processed
+    exactly once across a multi-host job; with a uuid-keyed Kafka topic
+    the broker already partitions and this stays None.
+    """
+    num_processes, process_id = host_share(num_processes, process_id)
+    if num_processes == 1:
+        return None
     return lambda u: owned_by_host(u, num_processes, process_id)

@@ -63,12 +63,6 @@ import hashlib
 import logging
 import math
 import multiprocessing
-
-# worker processes are spawned, not forked: the parent may already run
-# JAX's (and jax.distributed's) native threads, and forking a
-# multi-threaded process can deadlock the children mid-mutex. Spawned
-# children re-import this module, which initialises no XLA backend.
-_MP = multiprocessing.get_context("spawn")
 import os
 import re
 import shutil
@@ -80,10 +74,30 @@ from typing import Callable, Iterable, List
 
 from ..core.osmlr import INVALID_SEGMENT_ID, tile_index, tile_level
 from ..core.types import Segment
-from ..parallel.multihost import host_uuid_filter
+from ..parallel.multihost import host_share, host_uuid_filter
 from ..utils import metrics
 
 logger = logging.getLogger("reporter_tpu.pipeline")
+
+# The stages' worker processes (_run_workers) are never forked from the
+# caller, which may already run JAX's (and jax.distributed's) native
+# threads: forking a multi-threaded process can deadlock the child
+# mid-mutex. Where the platform has one they are forked from a fork
+# server, a fresh interpreter (fork and exec, like spawn) that imports
+# this module, and numpy with it, once, so a worker starts in
+# milliseconds; elsewhere each is spawned and imports it itself. The
+# server preloads this module alone, not __main__, whose script may
+# import JAX at its top level: it initialises no XLA backend. It starts
+# with a process's first worker and serves every later stage and job.
+# The preload is named in full: run with -m, this module is __main__.
+if "forkserver" in multiprocessing.get_all_start_methods():
+    _MP = multiprocessing.get_context("forkserver")
+    _MP.set_forkserver_preload(["reporter_tpu.pipeline.simple_reporter"])
+else:
+    _MP = multiprocessing.get_context("spawn")
+# the process that imported this module: a worker forked from the
+# preloaded server finds the server's pid here, not its own
+_LOADED_IN = os.getpid()
 
 MAX_ACCURACY_M = 1000  # reference: simple_reporter.py:112
 
@@ -119,21 +133,17 @@ class _Block:
 # --------------------------------------------------------------------------
 
 def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
-                     bbox: List[float], dest_dir: str) -> tuple:
+                     bbox: List[float], dest_dir: str,
+                     uuid_filter: Callable | None = None) -> tuple:
     """Parse one downloaded part file into uuid-sharded trace files: one
-    columnar block appended to each shard file it has probes for.
+    columnar block appended to each shard file it has probes for. Only
+    the uuids ``uuid_filter`` keeps are gathered (all where it is None).
 
     Returns ``(lines, probes, uuid_hashes, hour_epochs)``: lines read,
     probes kept, distinct uuids hashed and hour epochs computed. A part
     holds thousands of lines per uuid and per hour, so a uuid's shard
     (and its host verdict) and an hour's epoch are computed once and
     looked up after that."""
-    # multi-host backfill: when REPORTER_TPU_NUM_PROCESSES/PROCESS_ID are
-    # set, each host keeps only its share of the uuid space, so N hosts
-    # pointed at the same --src partition the work instead of repeating it
-    # (the reference splits days across instances by hand,
-    # load-historical-data/README.md)
-    uuid_filter = host_uuid_filter()
     fast_time = time_pattern == "%Y-%m-%d %H:%M:%S"
     opener = gzip.open if path.endswith(".gz") else open
     shards: dict[str, _Block] = {}
@@ -207,14 +217,17 @@ def _parse_part_file(path: str, valuer: Callable, time_pattern: str,
 
 
 def _gather_worker(paths: List[str], valuer_src: str, time_pattern: str,
-                   bbox: List[float], dest_dir: str, results) -> None:
-    """Parse ``paths`` in turn, sending each part's tally (or None where
-    the part raised) down the ``results`` pipe."""
+                   bbox: List[float], dest_dir: str, hosts: tuple,
+                   results) -> None:
+    """Parse ``paths`` in turn, keeping the uuids of the host share
+    ``hosts`` (``(num_processes, process_id)``), and send each part's
+    tally (or None where the part raised) down the ``results`` pipe."""
     valuer = eval(valuer_src)  # user-supplied lambda, like the reference
+    uuid_filter = host_uuid_filter(*hosts)
     for path in paths:
         try:
             tally = _parse_part_file(path, valuer, time_pattern, bbox,
-                                     dest_dir)
+                                     dest_dir, uuid_filter)
             logger.info("Gathered %d probes from %s", tally[1], path)
         except Exception as e:
             tally = None
@@ -228,10 +241,18 @@ def gather_traces(src: str, key_regex: str, valuer_src: str,
     """Stage 1 driver. ``src`` is a local directory of part files, or an
     ``s3://bucket/prefix`` URL (requires boto3 + credentials).
 
+    Multi-host backfill: where REPORTER_TPU_NUM_PROCESSES/PROCESS_ID are
+    set, each host keeps only its share of the uuid space, so N hosts
+    pointed at the same ``src`` partition the work instead of repeating
+    it (the reference splits days across instances by hand,
+    load-historical-data/README.md). The share is read here, at the call,
+    and handed to the workers.
+
     Counts ``pipeline.gather.lines``, ``.probes``, ``.uuid_hashes`` and
     ``.hour_epochs`` over the parts, and ``pipeline.gather_failures`` for
     each part that raised or whose worker died before reporting it."""
     with metrics.timer("pipeline.gather"):
+        hosts = host_share()
         dest_dir = tempfile.mkdtemp(prefix="traces_", dir=".")
         if src.startswith("s3://"):
             paths = _download_s3(src, key_regex)
@@ -243,26 +264,8 @@ def gather_traces(src: str, key_regex: str, valuer_src: str,
                 if os.path.isfile(p) and rx.match(os.path.relpath(p, src)))
         logger.info("Gathering %d part files into %s", len(paths), dest_dir)
         chunks = [paths[i::concurrency] for i in range(concurrency)]
-        workers = []
-        for chunk in chunks:
-            if not chunk:
-                continue
-            recv, send = _MP.Pipe(duplex=False)
-            p = _MP.Process(
-                target=_gather_worker,
-                args=(chunk, valuer_src, time_pattern, bbox, dest_dir, send))
-            p.start()
-            send.close()  # the child's end: recv sees EOF once it exits
-            workers.append((p, recv))
-        tallies = []
-        for p, recv in workers:
-            with recv:
-                while True:
-                    try:
-                        tallies.append(recv.recv())
-                    except EOFError:
-                        break
-            p.join()
+        tallies = _run_workers(_gather_worker, chunks, valuer_src,
+                               time_pattern, bbox, dest_dir, hosts)
         done = [t for t in tallies if t is not None]
         lines, probes, hashes, hours = (
             [sum(col) for col in zip(*done)] or [0, 0, 0, 0])
@@ -272,6 +275,67 @@ def gather_traces(src: str, key_regex: str, valuer_src: str,
         metrics.count("pipeline.gather.hour_epochs", hours)
         metrics.count("pipeline.gather_failures", len(paths) - len(done))
     return dest_dir
+
+
+def _run_workers(target: Callable, chunks: List[list], *args) -> list:
+    """Run ``target(chunk, *args, results)`` in a worker process for each
+    non-empty chunk of ``chunks``; ``target`` sends one tally down the
+    one-way pipe ``results`` for each item of its chunk, in order.
+    Returns the tallies of all chunks, in order: one per item, None for
+    each item whose worker died before it reported it.
+
+    Counts ``pipeline.worker_starts`` and, of those, the workers forked
+    from the preloaded server (``pipeline.worker_starts.preloaded``), and
+    records ``pipeline.worker_boot``: from the first start to the last
+    worker entering ``target``."""
+    env = dict(os.environ)
+    workers = []
+    t_start = time.monotonic()
+    for chunk in chunks:
+        if not chunk:
+            continue
+        recv, send = _MP.Pipe(duplex=False)
+        p = _MP.Process(target=_enter,
+                        args=(target, (chunk, *args), env, send))
+        p.start()
+        send.close()  # the child's end: recv sees EOF once it exits
+        workers.append((p, recv, len(chunk)))
+    metrics.count("pipeline.worker_starts", len(workers))
+    tallies, entered, preloaded = [], [], 0
+    for p, recv, n in workers:
+        sent = []
+        with recv:
+            while True:
+                try:
+                    sent.append(recv.recv())
+                except EOFError:
+                    break
+        p.join()
+        if sent:
+            t_enter, from_server = sent.pop(0)
+            entered.append(t_enter)
+            preloaded += from_server
+        if len(sent) < n:
+            logger.error("A worker exited (code %s) before it reported %d "
+                         "of its %d items", p.exitcode, n - len(sent), n)
+        tallies += sent + [None] * (n - len(sent))
+    metrics.count("pipeline.worker_starts.preloaded", preloaded)
+    if entered:
+        metrics.observe("pipeline.worker_boot", max(entered) - t_start)
+    return tallies
+
+
+def _enter(target: Callable, args: tuple, env: dict, results) -> None:
+    """A worker's first steps: adopt the caller's environment, send the
+    time it enters ``target`` and whether it was forked from the
+    preloaded server, then run ``target(*args, results)``. A worker
+    forked from the server starts with the server's environment, as it
+    was when the server started; what the worker's code reads of it
+    (boto3's credential chain, urllib's proxies) must be the caller's."""
+    os.environ.clear()
+    os.environ.update(env)
+    results.send((time.monotonic(), _LOADED_IN != os.getpid()))
+    target(*args, results)
 
 
 def _download_s3(url: str, key_regex: str) -> List[str]:
@@ -617,65 +681,88 @@ def _emit_rows(rep: dict, trace: dict, quantisation: int, source: str,
 # --------------------------------------------------------------------------
 
 def _report_worker(files: List[str], match_dir: str, dest: str,
-                   privacy: int) -> None:
+                   privacy: int, results) -> None:
+    """Anonymise ``files`` in turn, sending each file's tally, ``(tiles
+    written, rows kept)``, or None where the file raised, down the
+    ``results`` pipe."""
     for path in files:
-        with open(path) as f:
-            rows = f.readlines()
-        rows.sort()
-        # cull rows below the privacy threshold on (segment, next) runs
-        kept: list[str] = []
-        i = 0
-        while i < len(rows):
-            ki = rows[i].split(",")[:2]
-            j = i
-            while j < len(rows) and rows[j].split(",")[:2] == ki:
-                j += 1
-            if j - i >= privacy:
-                kept.extend(rows[i:j])
-            i = j
-        rel = os.path.relpath(path, match_dir)
-        if not kept:
-            logger.info("No segments for %s after anonymising", rel)
-            continue
-        name = hashlib.sha1(path.encode()).hexdigest()
-        payload = Segment.column_layout() + "\n" + "".join(kept)
-        key = rel + "/" + name
-        logger.info("Writing %d segments to %s", len(kept), key)
-        if _is_remote(dest):
-            _put_remote(dest, key, payload)
-        else:
-            out_path = os.path.join(dest, key)
-            os.makedirs(os.path.dirname(out_path), exist_ok=True)
-            with open(out_path, "w") as f:
-                f.write(payload)
+        try:
+            rows = _report_file(path, match_dir, dest, privacy)
+            tally = (1 if rows else 0, rows)
+        except Exception as e:
+            tally = None
+            logger.error("%s was not reported: %s", path, e)
+        results.send(tally)
+
+
+def _report_file(path: str, match_dir: str, dest: str, privacy: int) -> int:
+    """One tile file of ``match_dir``, sorted and privacy-culled, to
+    ``dest``; returns the rows kept (0: nothing written)."""
+    with open(path) as f:
+        rows = f.readlines()
+    rows.sort()
+    # cull rows below the privacy threshold on (segment, next) runs
+    kept: list[str] = []
+    i = 0
+    while i < len(rows):
+        ki = rows[i].split(",")[:2]
+        j = i
+        while j < len(rows) and rows[j].split(",")[:2] == ki:
+            j += 1
+        if j - i >= privacy:
+            kept.extend(rows[i:j])
+        i = j
+    rel = os.path.relpath(path, match_dir)
+    if not kept:
+        logger.info("No segments for %s after anonymising", rel)
+        return 0
+    name = hashlib.sha1(path.encode()).hexdigest()
+    payload = Segment.column_layout() + "\n" + "".join(kept)
+    key = rel + "/" + name
+    logger.info("Writing %d segments to %s", len(kept), key)
+    if _is_remote(dest):
+        if not _put_remote(dest, key, payload):
+            raise RuntimeError(f"{dest} did not take {key}")
+    else:
+        out_path = os.path.join(dest, key)
+        os.makedirs(os.path.dirname(out_path), exist_ok=True)
+        with open(out_path, "w") as f:
+            f.write(payload)
+    return len(kept)
 
 
 def _is_remote(dest: str) -> bool:
     return dest.startswith(("s3://", "http://", "https://"))
 
 
-def _put_remote(dest: str, key: str, payload: str) -> None:
+def _put_remote(dest: str, key: str, payload: str) -> bool:
+    """One tile to a remote sink; returns whether the sink took it."""
     if not dest.startswith("s3://"):
         # signed PUT / boto3 for AWS endpoints, plain POST otherwise —
         # same routing as the streaming TileSink
         from ..utils import http as http_egress
-        http_egress.egress_tile(dest, key, payload)
-        return
+        return http_egress.egress_tile(dest, key, payload)
     try:
         import boto3
     except ImportError:
         logger.error("s3 destination requires boto3, which is unavailable")
-        return
+        return False
     bucket, _, prefix = dest[len("s3://"):].partition("/")
     full_key = (prefix.rstrip("/") + "/" + key) if prefix else key
     boto3.client("s3").put_object(Bucket=bucket, Key=full_key,
                                   Body=payload.encode())
+    return True
 
 
 def report_tiles(match_dir: str, dest: str, privacy: int,
                  concurrency: int) -> None:
     """Stage 3 driver: every tile file of ``match_dir``, anonymised, to
-    ``dest`` (a local directory or a remote sink)."""
+    ``dest`` (a local directory or a remote sink).
+
+    Counts ``pipeline.report.tiles`` and ``pipeline.report.rows``, the
+    tiles written and the rows they kept, and ``pipeline.tile_failures``
+    for each tile file that raised or whose worker died before reporting
+    it."""
     with metrics.timer("pipeline.report"):
         files = sorted(
             os.path.join(r, f)
@@ -684,17 +771,13 @@ def report_tiles(match_dir: str, dest: str, privacy: int,
         if not _is_remote(dest):
             os.makedirs(dest, exist_ok=True)
         chunks = [files[i::concurrency] for i in range(concurrency)]
-        procs = []
-        for chunk in chunks:
-            if not chunk:
-                continue
-            p = _MP.Process(
-                target=_report_worker,
-                args=(chunk, match_dir, dest, privacy))
-            p.start()
-            procs.append(p)
-        for p in procs:
-            p.join()
+        tallies = _run_workers(_report_worker, chunks, match_dir, dest,
+                               privacy)
+        done = [t for t in tallies if t is not None]
+        tiles, rows = [sum(col) for col in zip(*done)] or [0, 0]
+        metrics.count("pipeline.report.tiles", tiles)
+        metrics.count("pipeline.report.rows", rows)
+        metrics.count("pipeline.tile_failures", len(files) - len(done))
 
 
 # --------------------------------------------------------------------------
@@ -768,8 +851,9 @@ def main(argv=None):
         # joins a multi-host JAX job when a coordinator is configured;
         # single-host no-op otherwise. Deliberately AFTER the gather stage
         # (which needs no devices) so the coordinator rendezvous doesn't
-        # gate pure-IO work; worker processes are spawned (_MP above), so
-        # jax.distributed's threads are never inherited mid-state either.
+        # gate pure-IO work; worker processes are never forked from this
+        # one (_MP above), so jax.distributed's threads are never
+        # inherited mid-state either.
         from ..parallel import init_multihost
         from ..utils.runtime import ensure_backend
         ensure_backend()

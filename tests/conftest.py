@@ -29,6 +29,20 @@ assert jax.default_backend() == "cpu", (
     "tests must run on the CPU backend, got " + jax.default_backend())
 
 
+def pytest_configure(config):
+    """Build the native library once, before any test process loads it.
+    Test processes that each find it missing or stale run ``make`` at
+    once; one that loads the library while another relinks it reads a
+    partial file ("file too short"), its own ``make`` then sees that
+    file as new and builds nothing, and the process keeps the numpy
+    fallback for every later test: native tests skip, and those that
+    need native prep fail. An xdist worker has ``workerinput``; the
+    controller configures before it starts any worker."""
+    if not hasattr(config, "workerinput"):
+        from reporter_tpu import native
+        native.available()
+
+
 @pytest.fixture(autouse=True)
 def _racecheck_gate():
     """The witness-armed CI leg (REPORTER_TPU_LOCKCHECK=1): any RC

@@ -112,7 +112,7 @@ class TestPipelineEndToEnd:
 
 
     def test_report_workers_never_touch_a_backend(self, tmp_path):
-        """One process per chip: stage 3's spawned workers run after
+        """One process per chip: stage 3's worker processes run after
         the parent matched on the chip, so they must never initialise a
         JAX backend. A bogus platform makes any backend init raise in
         a worker, and its tile would then be missing."""
@@ -227,25 +227,34 @@ def day_archive(city, tmp_path_factory):
     return str(src)
 
 
-def _run_stages(archive, city, device_batch, dest, matcher=None):
-    """Stages 1-3 over ``archive`` -> (stage-2 dir, counter deltas,
-    timer count deltas)."""
+def _pipeline_deltas(fn):
+    """Run ``fn()``; return its result and the pipeline's counter and
+    timer-count deltas over it."""
     from reporter_tpu.utils import metrics
     before = metrics.default.snapshot()
-    trace_dir = gather_traces(archive, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
-                              [-90.0, -180.0, 90.0, 180.0], concurrency=2)
-    match_dir = match_traces(
-        trace_dir, matcher or SegmentMatcher(net=city), "auto", {0, 1, 2},
-        {0, 1, 2}, quantisation=3600, inactivity=120, source="test",
-        device_batch=device_batch)
-    report_tiles(match_dir, str(dest), privacy=1, concurrency=2)
+    out = fn()
     after = metrics.default.snapshot()
     counters = {k: v - before["counters"].get(k, 0)
                 for k, v in after["counters"].items()
                 if k.startswith("pipeline.")}
     timers = {k: v["count"] - before["timers"].get(k, {"count": 0})["count"]
               for k, v in after["timers"].items() if k.startswith("pipeline.")}
-    return match_dir, counters, timers
+    return out, counters, timers
+
+
+def _run_stages(archive, city, device_batch, dest, matcher=None):
+    """Stages 1-3 over ``archive`` -> (stage-2 dir, counter deltas,
+    timer count deltas)."""
+    def stages():
+        trace_dir = gather_traces(archive, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
+                                  [-90.0, -180.0, 90.0, 180.0], concurrency=2)
+        match_dir = match_traces(
+            trace_dir, matcher or SegmentMatcher(net=city), "auto",
+            {0, 1, 2}, {0, 1, 2}, quantisation=3600, inactivity=120,
+            source="test", device_batch=device_batch)
+        report_tiles(match_dir, str(dest), privacy=1, concurrency=2)
+        return match_dir
+    return _pipeline_deltas(stages)
 
 
 def _sorted_files(root):
@@ -472,6 +481,7 @@ class TestGather:
                                                   monkeypatch, hosts,
                                                   time_pattern):
         import functools
+        from reporter_tpu.parallel import host_uuid_filter
         from reporter_tpu.pipeline.simple_reporter import (
             DEFAULT_VALUER, _parse_part_file)
         if hosts == "two":
@@ -489,7 +499,7 @@ class TestGather:
         new.mkdir()
         _parse_part_file_per_line(part, valuer, time_pattern, bbox, str(old))
         n_lines, probes, hashes, hours = _parse_part_file(
-            part, valuer, time_pattern, bbox, str(new))
+            part, valuer, time_pattern, bbox, str(new), host_uuid_filter())
         want = {f: _text_records(old / f) for f in os.listdir(old)}
         got = {f: _block_records(new / f) for f in os.listdir(new)}
         assert want and got == want
@@ -520,7 +530,7 @@ class TestGather:
             "recv, send = multiprocessing.Pipe(duplex=False)\n"
             f"sr._gather_worker([{part!r}], sr.DEFAULT_VALUER, "
             f"'%Y-%m-%d %H:%M:%S', [-90.0, -180.0, 90.0, 180.0], "
-            f"{str(dest)!r}, send)\n"
+            f"{str(dest)!r}, (2, 1), send)\n"
             "tally = recv.recv()\n"
             "assert tally is not None and tally[1] > 0, tally\n"
             "assert 'jax' not in sys.modules\n"
@@ -576,6 +586,210 @@ class TestGather:
         assert c3["pipeline.gather_failures"] == 1
         assert c3["pipeline.gather.probes"] == n_lines
         assert rows3 == rows
+
+
+def _tile_dir(root, tiles):
+    """A stage-2 directory under ``root`` of one hour's tile files, one
+    for each name of ``tiles``, each a row of its own pair."""
+    hour = root / "0_3599" / "0"
+    hour.mkdir(parents=True)
+    for k, tile in enumerate(tiles):
+        with open(hour / tile, "w") as f:
+            f.write(f"{k},{k + 1},10,1,600,0,0,10,src,AUTO\n")
+    return str(root)
+
+
+def _fork_server_pid():
+    """The pid of this process's fork server, None before it starts."""
+    from multiprocessing import forkserver
+    return forkserver._forkserver._forkserver_pid
+
+
+class TestWorkerLaunch:
+    """Stages 1 and 3 start their workers through one launcher, from a
+    fork server that has imported the pipeline's module where the
+    platform offers one: the start method changes no output, the
+    workers see the caller's host share, nothing imports JAX, and a
+    worker that dies is counted."""
+
+    def test_spawned_and_preloaded_workers_write_the_same_files(
+            self, city, day_archive, tmp_path, monkeypatch):
+        import multiprocessing
+        from reporter_tpu.pipeline import simple_reporter as sr
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        monkeypatch.delenv("REPORTER_TPU_PROCESS_ID", raising=False)
+        contexts = {"spawn": multiprocessing.get_context("spawn"),
+                    "server": sr._MP}
+
+        def shards(trace_dir):
+            # a shard file is named by its worker's pid: compare each
+            # shard's files by their bytes
+            out = {}
+            for f in os.listdir(trace_dir):
+                with open(os.path.join(trace_dir, f), "rb") as fh:
+                    out.setdefault(f.split(".")[0], []).append(fh.read())
+            return {k: sorted(v) for k, v in out.items()}
+
+        def tiles(root):
+            out = {}
+            for r, _d, fs in os.walk(root):
+                for f in fs:
+                    with open(os.path.join(r, f), "rb") as fh:
+                        out[os.path.relpath(os.path.join(r, f), root)] = \
+                            fh.read()
+            return out
+
+        traces = {}
+        for name, ctx in contexts.items():
+            monkeypatch.setattr(sr, "_MP", ctx)
+            traces[name] = gather_traces(
+                day_archive, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
+                [-90.0, -180.0, 90.0, 180.0], concurrency=2)
+        assert shards(traces["spawn"]) == shards(traces["server"]) != {}
+        match_dir = match_traces(
+            traces["server"], SegmentMatcher(net=city), "auto", {0, 1, 2},
+            {0, 1, 2}, quantisation=3600, inactivity=120, source="test")
+        for name, ctx in contexts.items():
+            monkeypatch.setattr(sr, "_MP", ctx)
+            report_tiles(match_dir, str(tmp_path / name), privacy=1,
+                         concurrency=2)
+        assert tiles(tmp_path / "spawn") == tiles(tmp_path / "server") != {}
+
+    def test_a_worker_keeps_the_callers_host_share(self, day_archive,
+                                                   tmp_path, monkeypatch):
+        """The host share set after the fork server started reaches the
+        next gather's workers: the caller reads it, not the worker,
+        whose environment is the server's."""
+        from reporter_tpu.parallel import host_uuid_filter
+        from reporter_tpu.pipeline import simple_reporter as sr
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        monkeypatch.delenv("REPORTER_TPU_PROCESS_ID", raising=False)
+
+        def uuids():
+            out = gather_traces(day_archive, ".*", VALUER,
+                                "%Y-%m-%d %H:%M:%S",
+                                [-90.0, -180.0, 90.0, 180.0], concurrency=2)
+            return {rec[0] for f in os.listdir(out)
+                    for rec in _block_records(os.path.join(out, f))}
+
+        every = uuids()
+        server = _fork_server_pid()
+        monkeypatch.setenv("REPORTER_TPU_NUM_PROCESSES", "2")
+        monkeypatch.setenv("REPORTER_TPU_PROCESS_ID", "1")
+        kept = uuids()
+        owned = host_uuid_filter(2, 1)
+        assert kept == {u for u in every if owned(u)}
+        assert 0 < len(kept) < len(every)
+        if sr._MP.get_start_method() == "forkserver":
+            assert server is not None and _fork_server_pid() == server
+
+    def test_a_jax_callers_workers_import_no_jax_and_see_its_environment(
+            self, tmp_path):
+        """A caller that has imported JAX and run it starts workers from
+        a server that has not: a worker inherits the server's modules at
+        its fork, so a worker with no ``jax`` module shows a server with
+        none. A variable the caller sets once the server is up reaches
+        the next workers."""
+        import subprocess
+        import sys
+        (tmp_path / "probe.py").write_text(
+            "import os\n"
+            "import sys\n\n\n"
+            "def look(chunk, results):\n"
+            "    for _ in chunk:\n"
+            "        results.send((sorted(m for m in sys.modules\n"
+            "                             if m.split('.')[0] == 'jax'),\n"
+            "                      os.environ.get('PROBE_SET_LATE')))\n")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import os\n"
+            "import jax.numpy as jnp\n"
+            "jnp.zeros(4).block_until_ready()\n"
+            "import probe\n"
+            "from reporter_tpu.pipeline import simple_reporter as sr\n"
+            "from reporter_tpu.utils import metrics\n"
+            "print(sr._run_workers(probe.look, [[0], [1, 2]]))\n"
+            "os.environ['PROBE_SET_LATE'] = 'yes'\n"
+            "print(sr._run_workers(probe.look, [[0]]))\n"
+            "print(sr._MP.get_start_method(),\n"
+            "      metrics.default.counter('pipeline.worker_starts'),\n"
+            "      metrics.default.counter("
+            "'pipeline.worker_starts.preloaded'))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join([repo, str(tmp_path)]))
+        env.pop("PROBE_SET_LATE", None)
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-800:]
+        first, second, starts = proc.stdout.splitlines()
+        assert first == "[([], None), ([], None), ([], None)]"
+        assert second == "[([], 'yes')]"
+        method, n, preloaded = starts.split()
+        assert n == "3"
+        assert preloaded == ("3" if method == "forkserver" else "0")
+
+    def test_a_dead_tile_worker_is_counted(self, tmp_path, monkeypatch):
+        """The first of three stage-3 workers exits before it reports:
+        its tile counts one failure, and the other two are written."""
+        from reporter_tpu.pipeline import simple_reporter as sr
+
+        class FirstWorkerDies:
+            """The launcher's context, but its first worker exits at once."""
+
+            def __init__(self, ctx):
+                self.ctx = ctx
+                self.started = 0
+
+            def Pipe(self, duplex):
+                return self.ctx.Pipe(duplex=duplex)
+
+            def Process(self, target, args):
+                self.started += 1
+                if self.started == 1:
+                    return self.ctx.Process(target=os._exit, args=(9,))
+                return self.ctx.Process(target=target, args=args)
+
+        match_dir = _tile_dir(tmp_path / "matches", ["41", "42", "43"])
+        dest = tmp_path / "out"
+        monkeypatch.setattr(sr, "_MP", FirstWorkerDies(sr._MP))
+        _out, c, t = _pipeline_deltas(lambda: report_tiles(
+            match_dir, str(dest), privacy=1, concurrency=3))
+        assert c["pipeline.tile_failures"] == 1
+        assert c["pipeline.report.tiles"] == c["pipeline.report.rows"] == 2
+        assert c["pipeline.worker_starts"] == 3
+        written = sorted(os.path.relpath(r, dest)
+                         for r, _d, fs in os.walk(dest) if fs)
+        assert written == [os.path.join("0_3599", "0", "42"),
+                           os.path.join("0_3599", "0", "43")]
+
+    def test_both_stages_start_preloaded_workers_from_one_server(
+            self, day_archive, tmp_path, monkeypatch):
+        """Each stage starts a worker per non-empty chunk, forked from
+        the one preloaded server where the platform offers it, and
+        records its workers' start-up once."""
+        import multiprocessing
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.delenv("REPORTER_TPU_NUM_PROCESSES", raising=False)
+        _out, c1, t1 = _pipeline_deltas(lambda: gather_traces(
+            day_archive, ".*", VALUER, "%Y-%m-%d %H:%M:%S",
+            [-90.0, -180.0, 90.0, 180.0], concurrency=4))
+        server = _fork_server_pid()
+        match_dir = _tile_dir(tmp_path / "matches", ["41", "42", "43"])
+        _out, c3, t3 = _pipeline_deltas(lambda: report_tiles(
+            match_dir, str(tmp_path / "out"), privacy=1, concurrency=2))
+        assert c1["pipeline.worker_starts"] == 2  # two parts
+        assert c3["pipeline.worker_starts"] == 2
+        assert c3["pipeline.report.tiles"] == 3
+        assert not c1["pipeline.gather_failures"]
+        assert not c3["pipeline.tile_failures"]
+        assert t1["pipeline.worker_boot"] == t3["pipeline.worker_boot"] == 1
+        if "forkserver" in multiprocessing.get_all_start_methods():
+            for c in (c1, c3):
+                assert c["pipeline.worker_starts.preloaded"] \
+                    == c["pipeline.worker_starts"]
+            assert server is not None and _fork_server_pid() == server
 
 
 def _text_records(path):
